@@ -335,6 +335,12 @@ class Healer:
                             // max(fi.erasure.block_size, 1))
                 for b0 in range(0, n_blocks, group):
                     block_shards: list[list[np.ndarray | None]] = []
+                    # Every survivor frame of the group verifies in
+                    # ONE batched (device-eligible, counted) hash
+                    # dispatch — the read path's own entry — instead
+                    # of one uncounted host hash per frame.
+                    wants: list[bytes] = []
+                    datas: list[bytes] = []
                     for b in range(b0, min(b0 + group, n_blocks)):
                         blk_len = min(
                             fi.erasure.block_size,
@@ -343,11 +349,20 @@ class Healer:
                         shards: list[np.ndarray | None] = \
                             [None] * (k + m)
                         for j, stream in streams.items():
-                            data = bitrot.extract_block(
+                            want, data = bitrot.split_block(
                                 stream, b, chunk, shard_size, algo)
+                            if want:
+                                wants.append(want)
+                                datas.append(data)
                             shards[j] = np.frombuffer(data,
                                                       dtype=np.uint8)
                         block_shards.append(shards)
+                    if datas and not all(
+                            bitrot.verify_frames(datas, wants, algo)):
+                        raise bitrot.BitrotMismatch(
+                            f"heal {bucket}/{object_name}: content "
+                            f"hash mismatch in a survivor shard "
+                            f"(blocks {b0}..)")
                     acc = {j: bytearray() for j in missing_shards}
                     for full in codec.decode_all_blocks_batch(
                             block_shards):
@@ -357,10 +372,14 @@ class Healer:
                     # the part's final group, so per-group framing
                     # concatenates byte-identically to whole-part
                     # framing (pinned by tests/test_pipeline.py).
-                    yield part.number, {
-                        j: bitrot.encode_stream(bytes(acc[j]),
-                                                shard_size, algo)
-                        for j in missing_shards}
+                    # (encode_streams: ONE device-eligible hash
+                    # dispatch over every rebuilt shard's sub-blocks,
+                    # the same entry the PUT path frames through.)
+                    rebuilt = list(missing_shards)
+                    yield part.number, dict(zip(
+                        rebuilt, bitrot.encode_streams(
+                            [bytes(acc[j]) for j in rebuilt],
+                            shard_size, algo)))
 
         # Write regenerated shards to the bad disks group by group
         # (tmp append stream -> rename_data, same commit path as PUT;

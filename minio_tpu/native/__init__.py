@@ -21,13 +21,46 @@ _LIB: ctypes.CDLL | None = None
 _TRIED = False
 
 
+_CXXFLAGS = ["-O3", "-march=native", "-pthread", "-shared", "-fPIC"]
+
+
+def _host_cpu_id() -> str:
+    """What `-march=native` resolves against: the CPU model and its
+    feature flags. A .so built on another CPU gets another key."""
+    import platform
+    ident = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            seen = set()
+            for line in f:
+                key = line.split(":", 1)[0].strip()
+                if key in ("model name", "flags", "Features") \
+                        and key not in seen:
+                    seen.add(key)
+                    ident.append(line.strip())
+    except OSError:
+        ident.append(platform.processor())
+    return "\n".join(ident)
+
+
+def _build_key(srcs: list[str]) -> str:
+    """Content key of a build: the sources, the flags and the building
+    host's CPU — not mtimes, which tar/rsync/checkout rewrite."""
+    import hashlib
+    h = hashlib.sha256()
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_host_cpu_id().encode())
+    return h.hexdigest()[:16]
+
+
 def _compile(srcs: list[str], so: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp = so + ".tmp"
-    subprocess.run(
-        ["g++", "-O3", "-march=native", "-pthread", "-shared", "-fPIC",
-         "-o", tmp] + srcs,
-        check=True, capture_output=True, timeout=120)
+    tmp = f"{so}.{os.getpid()}.tmp"
+    subprocess.run(["g++", *_CXXFLAGS, "-o", tmp] + srcs,
+                   check=True, capture_output=True, timeout=120)
     os.replace(tmp, so)
 
 
@@ -35,19 +68,12 @@ def _build_and_load() -> ctypes.CDLL | None:
     srcs = [os.path.join(_HERE, "highwayhash.cc"),
             os.path.join(_HERE, "lzblock.cc"),
             os.path.join(_HERE, "rs.cc")]
-    so = os.path.join(_BUILD_DIR, "libminio_tpu_native.so")
     try:
-        if (not os.path.exists(so)
-                or any(os.path.getmtime(so) < os.path.getmtime(s)
-                       for s in srcs)):
+        so = os.path.join(
+            _BUILD_DIR, f"libminio_tpu_native-{_build_key(srcs)}.so")
+        if not os.path.exists(so):
             _compile(srcs, so)
         lib = ctypes.CDLL(so)
-        if not hasattr(lib, "rs_gf_apply_mt"):  # newest symbol
-            # Stale cached .so predating a source (mtime preserved by
-            # tar/rsync/docker-copy): rebuild rather than silently
-            # disabling EVERY native path on the missing-symbol error.
-            _compile(srcs, so)
-            lib = ctypes.CDLL(so)
         lib.hh256_hash.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
                                    ctypes.c_size_t, ctypes.c_char_p]
         lib.hh256_hash.restype = None
@@ -73,7 +99,19 @@ def _build_and_load() -> ctypes.CDLL | None:
                                        ctypes.c_size_t]
         lib.rs_gf_apply_mt.restype = None
         return lib
-    except Exception:
+    except Exception as exc:
+        # Every native lane is now off for the process: say so, and
+        # tell the health machine why (admin /kernel-health).
+        detail = getattr(exc, "stderr", b"") or b""
+        reason = (f"native library unavailable: {exc!r} "
+                  f"{detail[-300:].decode(errors='replace')}").strip()
+        import logging
+        logging.getLogger("minio_tpu.native").warning("%s", reason)
+        try:
+            from ..obs.kernprof import KERNPROF, NATIVE
+            KERNPROF.dispatch_failed(NATIVE, reason)
+        except Exception:
+            pass  # never let telemetry break the degrade path
         return None
 
 

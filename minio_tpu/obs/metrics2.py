@@ -359,6 +359,16 @@ METRICS2.register(
     "Bytes dispatched per kernel and dispatch backend "
     "(device/native/xla-cpu/host) — the timeline's GiB/s numerator.")
 METRICS2.register(
+    "minio_tpu_v2_hh256_mesh_dispatches_total", "counter",
+    "HighwayHash device dispatches on a multi-device serving mesh, by "
+    "placement: sharded (rows over every device) or single (a batch "
+    "that does not divide the mesh, whole on device 0).")
+METRICS2.register(
+    "minio_tpu_v2_jit_programs_total", "counter",
+    "Programs this process handed to the XLA backend, by result: "
+    "requested (every program) and cache_hit (those the persistent "
+    "compile cache answered); compilations = requested - cache_hit.")
+METRICS2.register(
     "minio_tpu_v2_kernel_backend_state", "gauge",
     "Dispatch backend health state (0=up, 1=degraded, 2=down), "
     "by backend.")

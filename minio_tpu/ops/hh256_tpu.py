@@ -327,13 +327,24 @@ def hash_chunks(chunks: np.ndarray, key: bytes = MAGIC_KEY) -> np.ndarray:
     from . import batching
     from ..obs.kernel_stats import HH256, KERNEL, timed
     m = batching.serving_mesh()
-    if m is not None and B % m.size == 0:
-        from ..parallel.mesh import rows_sharding
-        words = jax.device_put(words, rows_sharding(m, B, 3))
-        rem_packet = jax.device_put(rem_packet, rows_sharding(m, B, 2))
+    if m is not None:
+        # Rows shard over every mesh device when B divides it; a batch
+        # that does not stays whole on the default device (index 0).
+        from ..obs.metrics2 import METRICS2
+        sharded = B % m.size == 0
+        METRICS2.inc("minio_tpu_v2_hh256_mesh_dispatches_total",
+                     {"placement": "sharded" if sharded else "single"})
+        if sharded:
+            from ..parallel.mesh import rows_sharding
+            words = jax.device_put(words, rows_sharding(m, B, 3))
+            rem_packet = jax.device_put(rem_packet,
+                                        rows_sharding(m, B, 2))
     with timed() as t:
-        out = np.asarray(_hash_chunks_device(words, rem_packet, init,
-                                             n_full, rem))
+        # C-contiguous: a TPU result can come back in the device's own
+        # (column-major) layout, and the byte view below needs the
+        # last axis contiguous. On the CPU it always was.
+        out = np.ascontiguousarray(_hash_chunks_device(
+            words, rem_packet, init, n_full, rem))
     KERNEL.record(HH256, True, chunks.nbytes, t.s, blocks=B,
                   backend=batching.attempt_backend())
     return out.view(np.uint8).reshape(B, 32)

@@ -113,12 +113,12 @@ def _device_pinned(x: np.ndarray, device_index: int) -> "jnp.ndarray":
 #    Mesh-sharded 3-D batches run the Pallas kernel under shard_map
 #    (rs_pallas.gf_apply_sharded) — one local kernel per chip.
 
-_pallas_state: dict = {"enabled": None}
+_pallas_state: dict = {"enabled": None, "cause": ""}
 
 
 def _pallas_enabled() -> bool:
     """Pallas on a non-CPU platform, unless disabled by env or by a
-    prior compile failure. On a single device the kernel is called
+    prior kernel failure. On a single device the kernel is called
     directly; on a multi-device serving mesh it runs under shard_map
     (rs_pallas.gf_apply_sharded) — each chip applies the packed kernel
     to its local block, no collectives."""
@@ -127,30 +127,61 @@ def _pallas_enabled() -> bool:
     if st is False:
         return False
     if os.environ.get("MINIO_TPU_NO_PALLAS"):
+        _pallas_state["cause"] = "MINIO_TPU_NO_PALLAS is set"
         return False
     if st is None:
-        try:
-            import jax as _jax
-            ok = any(d.platform != "cpu" for d in _jax.devices())
-            if ok:
-                # Eager one-time smoke compile: a platform without Mosaic
-                # must fall back HERE, not at a caller's jit-compile.
-                from . import rs_pallas
+        from . import batching
+        st = batching.device_present()
+        if st:
+            # Eager one-time smoke compile at a serving-like shape: a
+            # kernel the platform's compiler refuses must fall back
+            # HERE, loudly, not at a caller's jit-compile.
+            from . import rs_pallas
+            try:
                 rs_pallas.smoke()
-        except Exception as exc:
-            _disable_pallas(exc)
-            return False
-        _pallas_state["enabled"] = ok
-        st = ok
+            except Exception as exc:
+                batching.device_dispatch_failed(
+                    f"pallas smoke compile failed: "
+                    f"{type(exc).__name__}: {exc}"[:600])
+                _disable_pallas(exc)
+                return False
+        else:
+            _pallas_state["cause"] = \
+                "no accelerator: jit lane is XLA on CPU"
+        _pallas_state["enabled"] = st
     return bool(st)
 
 
-def _disable_pallas(exc: BaseException) -> None:
+def _disable_pallas(cause: BaseException | str) -> None:
     import logging
     _pallas_state["enabled"] = False
+    _pallas_state["cause"] = cause if isinstance(cause, str) \
+        else repr(cause)
     logging.getLogger("minio_tpu.ops").warning(
-        "Pallas GF kernel unavailable on this platform; using the XLA "
-        "bit-plane path: %r", exc)
+        "Pallas GF kernel disabled; using the XLA bit-plane path: %s",
+        _pallas_state["cause"])
+
+
+def kernel_report() -> dict:
+    """Which GF kernel the jit lane runs (admin /codec-plan `rsKernel`
+    and the server's boot line): "pallas" once the smoke compile has
+    passed on an accelerator, "xla" otherwise, with the cause when the
+    Pallas kernel was refused or disabled."""
+    on = _pallas_enabled()
+    return {"kernel": "pallas" if on else "xla",
+            "cause": "" if on else _pallas_state.get("cause", "")}
+
+
+def _pallas_failed(exc: BaseException, big_m, x) -> None:
+    """The Pallas kernel was refused or failed at this shape: a KERNEL
+    failure, reported to kernprof with the shape in the cause (so
+    /kernel-health names it) before the XLA path takes over."""
+    from . import batching
+    cause = (f"pallas gf kernel failed at matrix="
+             f"{tuple(big_m.shape)} shards={tuple(x.shape)}: "
+             f"{type(exc).__name__}: {exc}")[:600]
+    batching.device_dispatch_failed(cause)
+    _disable_pallas(cause)
 
 
 def _unpack_bits(x: jnp.ndarray) -> jnp.ndarray:
@@ -181,9 +212,12 @@ def _gf_apply_xla(big_m: jnp.ndarray, shards: jnp.ndarray) -> jnp.ndarray:
 
 def _dispatch(pallas_fn, pallas_sharded_fn, xla_fn, big_m, x):
     """Pallas on TPU (direct on one device, shard_map'd over a serving
-    mesh), XLA otherwise. Input errors (ValueError: caller bug, same on
-    either path) propagate; anything else disables the Pallas path for
-    the process — loudly, once — and falls back.
+    mesh), XLA otherwise. Argument errors (rs_pallas.check_args: a caller
+    bug, the same on either path) are checked FIRST and propagate.
+    Whatever the kernel then raises — a lowering ValueError included —
+    is a kernel failure: it goes to kernprof with the shape in its
+    cause, disables the Pallas path for the process and the XLA path
+    answers this dispatch.
 
     Scope of the fallback: it protects EAGER callers, i.e. the whole
     serving path (batching, encode_batch). When gf_apply/encode_blocks
@@ -193,7 +227,8 @@ def _dispatch(pallas_fn, pallas_sharded_fn, xla_fn, big_m, x):
     failure surfaces THERE, by design — the driver's compile check must
     see it, not have it silently papered over."""
     if _pallas_enabled():
-        from . import batching
+        from . import batching, rs_pallas
+        rs_pallas.check_args(big_m, x)
         mesh = batching.serving_mesh()
         try:
             if mesh is None:
@@ -206,10 +241,8 @@ def _dispatch(pallas_fn, pallas_sharded_fn, xla_fn, big_m, x):
                     # the packed kernel there directly, no shard_map.
                     return pallas_fn(big_m, x)
                 return pallas_sharded_fn(mesh, big_m, x)
-        except ValueError:
-            raise
-        except Exception as exc:  # Mosaic compile/platform failure
-            _disable_pallas(exc)
+        except Exception as exc:  # lowering / Mosaic / runtime failure
+            _pallas_failed(exc, big_m, x)
     return xla_fn(big_m, x)
 
 

@@ -113,10 +113,7 @@ class DeviceAffinity:
 
     @staticmethod
     def n_devices() -> int:
-        try:
-            return len(jax.devices())
-        except Exception:
-            return 1
+        return len(jax.devices())
 
     def assign(self, owner: str) -> int | None:
         """Home device index for `owner` (idempotent); None on a

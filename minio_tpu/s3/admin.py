@@ -691,7 +691,7 @@ class AdminHandlers:
         machine (device/native/xla-cpu/host with fail streaks + last
         failure cause) and cumulative dispatch/byte mix.  ``?probe=
         true`` runs one recovery probe per backend first — the manual
-        'is the relay back yet?' lever (probes are tiny real
+        'is the device back yet?' lever (probes are tiny real
         dispatches; root-only surface, so no amplification risk)."""
         from ..obs.kernprof import KERNPROF
         out: dict = {}
@@ -715,12 +715,12 @@ class AdminHandlers:
             # Keyed apart from snapshot()'s boolean "probed" flag.
             out["probeResults"] = AUTOTUNE.probe_ladder()
         out.update(AUTOTUNE.snapshot())
-        try:
-            from ..parallel.mesh import MESH_AFFINITY
-            out["affinity"] = MESH_AFFINITY.snapshot()
-        except Exception:
-            out["affinity"] = {"nDevices": 1, "assignments": {},
-                               "dispatches": {}}
+        from ..ops import rs_tpu
+        from ..parallel.mesh import MESH_AFFINITY
+        # Which GF kernel the jit lane runs ("pallas" | "xla") and,
+        # when it is not the Pallas one, why.
+        out["rsKernel"] = rs_tpu.kernel_report()
+        out["affinity"] = MESH_AFFINITY.snapshot()
         return out
 
     def h_incidents(self, p, body):

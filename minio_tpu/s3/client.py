@@ -22,15 +22,18 @@ class S3ClientResponse:
 class S3Client:
     def __init__(self, host: str, port: int, access_key: str,
                  secret_key: str, region: str = "us-east-1",
-                 tls: "object | None" = None):
+                 tls: "object | None" = None, timeout: float = 60):
         """tls: an ssl.SSLContext (see utils.certs.client_context) to
-        speak HTTPS; None = plaintext."""
+        speak HTTPS; None = plaintext. timeout: socket timeout in
+        seconds (a multi-hundred-MiB PUT or a synchronous heal sweep
+        answers later than the default)."""
         self.host = host
         self.port = port
         self.access_key = access_key
         self.secret_key = secret_key
         self.region = region
         self.tls = tls
+        self.timeout = timeout
 
     def request(self, method: str, path: str, query: str = "",
                 body: bytes = b"",
@@ -44,10 +47,11 @@ class S3Client:
                                       self.region)
         if self.tls is not None:
             conn = http.client.HTTPSConnection(
-                self.host, self.port, timeout=60, context=self.tls)
+                self.host, self.port, timeout=self.timeout,
+                context=self.tls)
         else:
             conn = http.client.HTTPConnection(self.host, self.port,
-                                              timeout=60)
+                                              timeout=self.timeout)
         try:
             url = path + (f"?{query}" if query else "")
             conn.request(method, url, body=body, headers=hdrs)

@@ -203,7 +203,7 @@ ERR_OBJECT_CORRUPT = _e(
 # used to answer an opaque 500 InternalError — this map keeps the
 # 404/409/503 retry semantics instead. Lint rule R5 (tools/mtpu_lint)
 # enforces that every storage/errors.py exception class has an entry,
-# so the safety net stays total as the taxonomy grows. (storage/errors
+# so the safety net stays total as the set of error classes grows. (storage/errors
 # imports nothing, so this import cannot cycle.)
 from ..storage.errors import (DiskFull, DiskNotFound,  # noqa: E402
                               DriveQuarantined, FaultyDisk, FileCorrupt,

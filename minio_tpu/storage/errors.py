@@ -1,4 +1,4 @@
-"""Storage error taxonomy (ref cmd/storage-errors.go)."""
+"""Storage error classes (ref cmd/storage-errors.go)."""
 
 
 class StorageError(Exception):

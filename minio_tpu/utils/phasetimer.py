@@ -5,7 +5,7 @@ httpTrace's per-handler timing).
 
 Always on: cost is two perf_counter() calls per phase. `snapshot()`
 reports count/p50/total per phase; the bench publishes it so every
-BENCH_r*.json carries the split.
+bench record carries the split.
 """
 
 from __future__ import annotations

@@ -86,9 +86,8 @@ def test_static_policy_before_probe():
 def test_probe_ladder_measures_and_plans(ladder_results):
     """The ladder measures every reachable lane per rung with a
     known-answer check and the plan converges on the measured-fastest
-    lane — host-native on this box, the exact BENCH_r04/r05 lesson
-    (device runs silently collapsed to 0.016 GiB/s XLA-CPU while
-    host-native did 0.983)."""
+    lane — host-native on a box with no accelerator, where the jit
+    lane is XLA on the CPU and far slower."""
     res, _model, plan = ladder_results
     # Reachable lanes on a no-device box: native, xla-cpu, host.
     assert XLA_CPU in res and HOST in res and DEVICE not in res
@@ -143,8 +142,9 @@ def test_never_selects_a_down_lane(ladder_results):
 
 def test_fallback_prefers_host_over_xla_without_data():
     """No model data + static lane DOWN on a deviceless box: the last
-    resort is numpy host, never jit-on-CPU (BENCH_r04/r05 measured
-    xla-cpu ~8x slower than numpy — post-review regression)."""
+    resort is numpy host, never jit-on-CPU (the bit-plane matmul on
+    XLA's CPU backend is several times slower than numpy —
+    post-review regression)."""
     from minio_tpu.obs.kernprof import NATIVE as _N
     for _ in range(KERNPROF.DOWN_AFTER):
         KERNPROF.dispatch_failed(_N, RuntimeError("native broke"))
@@ -283,12 +283,12 @@ def test_probe_results_logged_with_cause(ladder_results):
 def test_reprobe_rebuilds_mesh_on_device_count_change(monkeypatch):
     """ISSUE 13 satellite fix: reprobe_device_present() must rebuild
     the serving mesh (and re-plan) when the device count changes — a
-    relay that comes back with a different census must not keep
+    device set that comes back with a different census must not keep
     dispatching over the stale mesh."""
     import minio_tpu.ops.batching as b
     b.device_present()  # populate the census (8 virtual devices)
     assert b._device_count == 8
-    # Simulate a stale census from a 4-device relay epoch.
+    # Simulate a stale census from a 4-device epoch.
     monkeypatch.setattr(b, "_device_count", 4)
     sentinel = object()
     monkeypatch.setattr(b, "_serving_mesh", sentinel)

@@ -184,3 +184,29 @@ def test_engine_shard_files_identical_with_and_without_device(tmp_path,
         return files
 
     assert put_and_slurp("dev", True) == put_and_slurp("host", False)
+
+
+def test_hash_chunks_takes_a_result_in_the_device_layout(monkeypatch):
+    """On the TPU the (B, 8) digest words came back column-major and
+    the uint8 view raised ('the last axis must be contiguous') AFTER
+    the dispatch was counted, so every such batch was hashed twice,
+    the second time on the host (found by chip_smoke.py, PR 21). The
+    CPU backend always answers C-contiguous, so fake the layout."""
+    import numpy as np
+
+    from minio_tpu.ops import hh256_tpu
+    from minio_tpu.ops.hh256 import MAGIC_KEY, HighwayHash256
+
+    real = hh256_tpu._hash_chunks_device
+
+    def column_major(*a, **kw):
+        return np.asfortranarray(np.asarray(real(*a, **kw)))
+
+    monkeypatch.setattr(hh256_tpu, "_hash_chunks_device", column_major)
+    chunks = np.random.default_rng(5).integers(
+        0, 256, (8, 100)).astype(np.uint8)
+    got = hh256_tpu.hash_chunks(chunks)
+    for i in range(8):
+        want = HighwayHash256(MAGIC_KEY).update(
+            chunks[i].tobytes()).digest()
+        assert got[i].tobytes() == want

@@ -39,16 +39,16 @@ def _clean_state():
 def test_degrade_down_and_streak_recovery():
     kp = KernelProfiler()
     assert kp.state_of(DEVICE) == UP and kp.allow(DEVICE)
-    kp.dispatch_failed(DEVICE, RuntimeError("relay hung"))
+    kp.dispatch_failed(DEVICE, RuntimeError("device hung"))
     assert kp.state_of(DEVICE) == DEGRADED
     assert kp.allow(DEVICE)  # degraded still dispatches
-    kp.dispatch_failed(DEVICE, RuntimeError("relay hung"))
-    kp.dispatch_failed(DEVICE, RuntimeError("relay hung"))
+    kp.dispatch_failed(DEVICE, RuntimeError("device hung"))
+    kp.dispatch_failed(DEVICE, RuntimeError("device hung"))
     assert kp.state_of(DEVICE) == DOWN
     assert not kp.allow(DEVICE)  # down: dispatch policy skips it
 
     # DEGRADED clears only after RECOVER_OK consecutive successes (one
-    # lucky dispatch amid a flapping relay must not flap the state).
+    # lucky dispatch amid a flapping device must not flap the state).
     kp2 = KernelProfiler()
     kp2.dispatch_failed(NATIVE, RuntimeError("bad rows"))
     for i in range(kp2.RECOVER_OK):

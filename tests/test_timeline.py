@@ -434,7 +434,7 @@ def test_backend_flip_visible_in_all_three_sinks(server, monkeypatch):
                   query="clear=true")
     assert r.status == 200
     # Force DOWN first so the probe path (not the ok-streak) recovers:
-    # that is the bounced-relay re-adoption contract.
+    # that is the device re-adoption contract.
     KERNPROF.dispatch_failed(backend, RuntimeError("x"))
     KERNPROF.dispatch_failed(backend, RuntimeError("x"))
     assert KERNPROF.state_of(backend) == "down"

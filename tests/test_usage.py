@@ -25,15 +25,27 @@ ACCESS, SECRET = "usageadmin", "usageadmin-secret"
 
 @pytest.fixture(autouse=True)
 def _clean_state():
+    from minio_tpu.obs.loopmon import LOOPMON
     USAGE.reset()
     USAGE.configure()
     WATCHDOG.reset()
     INCIDENTS.reset()
+    # The noisy_neighbor tests assert EXACT transition lists; a genuine
+    # machine-load stall on the process-wide rpc loop within the last
+    # 10 s makes the built-in loop_stall rule ride along (it did, in a
+    # whole-suite run of PR 21). Same guard as tests/test_watchdog.py.
+    prev_stall_ms = LOOPMON.stall_ms
+    LOOPMON.configure(stall_ms=60_000)
+    with LOOPMON._mu:
+        LOOPMON._stall_ring.clear()
     yield
     USAGE.reset()
     USAGE.configure()
     WATCHDOG.reset()
     INCIDENTS.reset()
+    LOOPMON.configure(stall_ms=prev_stall_ms)
+    with LOOPMON._mu:
+        LOOPMON._stall_ring.clear()
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,8 @@ flags
 
 - comparisons against size-threshold constants (names matching
   ``*MIN_BYTES`` / ``*THRESHOLD`` / large byte literals compared to a
-  size-ish operand) — a hardwired crossover is exactly what the bench
-  trajectory proved wrong (BENCH_r04/r05), and
+  size-ish operand) — a hardwired crossover is wrong on every box but
+  the one it was tuned on, and
 - kernprof lane-name string literals (``"device"`` / ``"native"`` /
   ``"xla-cpu"`` / ``"host"``) in comparisons — lane identity belongs
   to the planner and the state machine, not inline policy.  The

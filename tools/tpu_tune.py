@@ -4,8 +4,9 @@ Compares the Pallas packed-GF kernel vs the XLA bit-plane path on the
 north-star config (8+4, 1MiB blocks), sweeps lane-tile sizes, and
 measures device HighwayHash throughput. Prints one JSON line.
 
-Usage: python tools/tpu_tune.py   (requires a reachable accelerator;
-exits with an error JSON when only CPU is visible)
+Usage: python -m tools.tpu_tune   (the one process that owns the chip;
+exits nonzero with an error JSON when only CPU is visible). Timings are
+steady-state marginal cost: pipelined launches ending in a readback.
 """
 
 from __future__ import annotations
@@ -35,6 +36,8 @@ def run() -> dict:
     import jax
     import jax.numpy as jnp
 
+    from minio_tpu.utils import compile_cache
+    compile_cache.configure()
     if not any(d.platform != "cpu" for d in jax.devices()):
         raise RuntimeError("no accelerator visible")
 
