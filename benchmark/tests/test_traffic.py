@@ -1,0 +1,170 @@
+"""Traffic from a seed: the same twice, another from another seed, the
+same multiset of sizes whatever the seed; every committed mix parses,
+and so does what no cell uses yet (open loop, weights, zipf, faults)."""
+
+import glob
+import json
+import os
+import sys
+
+import pytest
+
+from harness import traffic
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+MIXES = sorted(glob.glob(os.path.join(os.path.dirname(HERE), "traffic",
+                                      "*.json")))
+
+
+def ops(seed, mix, n=60):
+    out = []
+    for s in traffic.streams(seed, mix):
+        for _ in range(n):
+            op = s.next()
+            if op.kind in traffic.WRITES:      # as if acknowledged
+                s.written[op.key] = op.size
+                s.last_put = op.key
+            out.append((s.group.name, s.client, op.kind, op.key, op.size,
+                        op.off))
+    return out
+
+
+@pytest.mark.parametrize("path", MIXES)
+@pytest.mark.parametrize("rehearse", [False, True])
+def test_committed_mixes(path, rehearse):
+    name = os.path.basename(path)[:-5]
+    mix = traffic.load(path, name, rehearse)
+    big = 3_000_000_019
+    assert ops(big, mix) == ops(big, mix)
+    assert ops(big, mix) != ops(big + 1, mix)
+    for g in mix.groups:
+        a = sorted(o[4] for o in ops(1, mix, 2 * len(g.sizes) * 4)
+                   if o[0] == g.name and o[2] == "PUT")
+        b = sorted(o[4] for o in ops(2, mix, 2 * len(g.sizes) * 4)
+                   if o[0] == g.name and o[2] == "PUT")
+        assert a == b                      # same sizes, another order
+
+
+def test_large_mix_is_the_cell_the_issue_names():
+    mix = traffic.load(os.path.join(os.path.dirname(HERE), "traffic",
+                                    "large_put_get.json"), "large_put_get")
+    (g,) = mix.groups
+    assert (g.clients, g.ring, g.sequence) == (8, 8, ["PUT", "GET"])
+    assert g.sizes == [10 << 20, 25 << 20, (50 << 20) + 12345]
+    s = traffic.ClientStream(9, g, 0)
+    put = s.next()
+    s.last_put, s.written[put.key] = put.key, put.size
+    get = s.next()
+    assert (put.kind, get.kind, get.key) == ("PUT", "GET", put.key)
+
+
+def test_what_no_cell_uses_yet_parses_and_generates():
+    doc = {"loop": "open", "timeout_s": 10, "groups": [{
+        "name": "mixed", "clients": 4, "rate_per_s": 200,
+        "arrivals": "poisson",
+        "weights": {"GET": 45, "HEAD": 30, "PUT": 15, "DELETE": 10},
+        "sizes": {"weighted": [[4096, 6], [1048576, 2]]},
+        "keys": {"ring": 32}, "read": {"zipf": 0.99}}],
+        "preload": {"per_client": 32},
+        "faults": {"remove_drive_copies": [2, 5]}}
+    mix = traffic.parse("warp_mixed_open", doc)
+    assert mix.loop == "open" and mix.preload_per_client == 32
+    assert mix.faults == {"remove_drive_copies": [2, 5]}
+    got = ops(11, mix, 200)
+    assert {o[2] for o in got} >= {"GET", "HEAD", "PUT"}
+    s = traffic.ClientStream(11, mix.groups[0], 0)
+    dues = [s.next().due_s for _ in range(400)]
+    assert dues == sorted(dues)
+    assert dues[-1] / 400 == pytest.approx(4 / 200, rel=0.25)
+
+
+@pytest.mark.parametrize("bad", [
+    {"groups": []},
+    {"loop": "sometimes", "groups": [{"clients": 1, "sequence": ["PUT"],
+                                      "sizes": {"cycle": [1]},
+                                      "keys": {"ring": 1}}]},
+    {"groups": [{"clients": 1, "sequence": ["FROB"],
+                 "sizes": {"cycle": [1]}, "keys": {"ring": 1}}]},
+    {"loop": "open", "groups": [{"clients": 1, "sequence": ["PUT"],
+                                 "sizes": {"cycle": [1]},
+                                 "keys": {"ring": 1}}]},
+])
+def test_a_malformed_mix_is_refused(bad):
+    with pytest.raises(traffic.TrafficError):
+        traffic.parse("bad", bad)
+
+
+def test_benchmark_json_finds_every_file():
+    root = os.path.dirname(os.path.dirname(HERE))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for c in bench["configs"]:
+        with open(os.path.join(root, c["file"])) as f:
+            assert json.load(f)["name"] == c["name"]
+    for w in bench["workloads"]:
+        assert os.path.exists(os.path.join(
+            root, "benchmark", "traffic", w["traffic"] + ".json"))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    import run
+    ends = {m["name"] for m in bench["end_to_end"]}
+    for m in bench["per_layer"]:
+        # Found by name; `<quantity>.<variant>` reads the quantity's file.
+        spec = run.metric_spec(m["name"])
+        assert m["name"].startswith(spec["name"])
+        for key in ("layer", "unit", "better", "source"):
+            assert spec[key] == m[key], (m["name"], key)
+        # Which end-to-end metric it moves and in which cells is said
+        # once, in BENCHMARK.json.
+        assert "moves" not in spec and "workloads" not in spec
+        assert m["moves"] in ends
+        assert os.path.exists(os.path.join(
+            root, "benchmark", "metrics", "readers", spec["reader"] + ".py"))
+
+
+def test_small_mix_is_the_cell_the_issue_names_plus_one_device_path_get():
+    mix = traffic.load(os.path.join(os.path.dirname(HERE), "traffic",
+                                    "small_put_get.json"), "small_put_get")
+    main, dev = mix.groups
+    assert (main.clients, main.ring, main.sequence, main.sizes) == \
+        (20, 64, ["PUT", "GET"], [1 << 20])
+    assert main.rate_per_s == 0
+    # One client, GETs only, a preloaded 5 MiB object every 4 s from the
+    # window's opening, whatever set-up drew from its stream before.
+    assert (dev.clients, dev.sequence, dev.sizes) == (1, ["GET"], [5 << 20])
+    assert mix.preload_per_client == 1
+    s = traffic.ClientStream(9, dev, 0)
+    first = s.next()                      # preload: nothing to read yet
+    assert first.kind == "PUT"
+    s.written[first.key] = first.size
+    assert s.next().kind == "GET"         # warm-up
+    s.open_window()
+    got = [s.next() for _ in range(13)]
+    assert {o.kind for o in got} == {"GET"}
+    assert {o.key for o in got} == {first.key}
+    assert [o.due_s for o in got] == [4.0 * i for i in range(1, 14)]
+    # 12 of them start inside a 50 s window; the last 3 s hold the one
+    # that is due at 48 s.
+    assert sum(1 for o in got if o.due_s < 50) == 12
+    assert mix.trace_slice_s == 3.0
+
+
+def test_a_cell_reads_the_metrics_that_list_it_or_move_what_it_reports():
+    import run
+    bench = {
+        "end_to_end": [{"name": "goodput", "workloads": ["big"]},
+                       {"name": "ops", "workloads": ["small"]},
+                       {"name": "setup_s"}],
+        "per_layer": [{"name": "a", "moves": "goodput"},
+                      {"name": "a.ops", "moves": "ops"},
+                      {"name": "b", "moves": "goodput", "workloads": ["big"]},
+                      {"name": "c", "moves": "setup_s"}]}
+
+    def names(cell, kind):
+        return [m["name"] for m in run.metrics_for(bench, cell, kind)]
+
+    assert names("big", "end_to_end") == ["goodput", "setup_s"]
+    assert names("big", "per_layer") == ["a", "b", "c"]
+    assert names("small", "per_layer") == ["a.ops", "c"]
+    # A cell a later PR adds, reporting goodput: `a` comes with it.
+    bench["end_to_end"][0]["workloads"].append("later")
+    assert names("later", "per_layer") == ["a", "c"]
