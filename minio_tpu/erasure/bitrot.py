@@ -14,7 +14,9 @@ sha256 (hashlib).
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
+import time
 
 from ..native import hh256_chunks_native, hh256_native
 from ..ops.hh256 import MAGIC_KEY, HighwayHash256
@@ -60,7 +62,10 @@ def digest_chunks(algo: str, data: bytes, chunk_size: int) -> list[bytes]:
     if algo in (HIGHWAYHASH256, HIGHWAYHASH256S):
         from ..obs.kernel_stats import HH256, KERNEL, timed
         from ..obs.kernprof import NATIVE
-        with timed() as t:
+        from ..obs.span import TRACER
+        with TRACER.span("kernel.hh256", backend=NATIVE,
+                         rows=ceil_frac(len(data), chunk_size),
+                         bytes=len(data)), timed() as t:
             native = hh256_chunks_native(data, chunk_size, MAGIC_KEY)
         if native is not None:
             KERNEL.record(HH256, False, len(data), t.s,
@@ -104,22 +109,26 @@ def _hash_rows_device(stacked, total_bytes: int, n_requests: int):
     way."""
     import numpy as np
 
+    from ..obs.span import TRACER
     from ..ops import batching
-    try:
-        from ..ops import hh256_tpu
-        B = stacked.shape[0]
-        cap = 1 << max(B - 1, 0).bit_length()
-        if cap != B:
-            stacked = np.concatenate(
-                [stacked,
-                 np.zeros((cap - B, stacked.shape[1]), np.uint8)])
-        digs = hh256_tpu.hash_chunks(stacked)[:B]
-        batching.HH_STATS.add(True, total_bytes, n_requests)
-        return digs
-    except Exception as exc:  # noqa: BLE001 - degrade loudly, don't fail IO
-        batching.device_dispatch_failed(exc)
-        batching.HH_STATS.add(False, total_bytes, n_requests)
-        return None
+    B = stacked.shape[0]
+    with TRACER.span("kernel.hh256", backend=batching.attempt_backend(),
+                     rows=B, bytes=total_bytes):
+        try:
+            from ..ops import hh256_tpu
+            t_prep = time.perf_counter()
+            cap = 1 << max(B - 1, 0).bit_length()
+            if cap != B:
+                stacked = np.concatenate(
+                    [stacked,
+                     np.zeros((cap - B, stacked.shape[1]), np.uint8)])
+            digs = hh256_tpu.hash_chunks(stacked, t_prep=t_prep)[:B]
+            batching.HH_STATS.add(True, total_bytes, n_requests)
+            return digs
+        except Exception as exc:  # noqa: BLE001 - degrade loudly, don't fail IO
+            batching.device_dispatch_failed(exc)
+            batching.HH_STATS.add(False, total_bytes, n_requests)
+            return None
 
 
 def digest_rows(algo: str, arr):
@@ -136,7 +145,9 @@ def digest_rows(algo: str, arr):
         from ..native import hh256_rows_native
         from ..obs.kernel_stats import HH256, KERNEL, timed
         from ..obs.kernprof import NATIVE
-        with timed() as t:
+        from ..obs.span import TRACER
+        with TRACER.span("kernel.hh256", backend=NATIVE, rows=B,
+                         bytes=arr.size), timed() as t:
             out = hh256_rows_native(arr, MAGIC_KEY)
         if out is not None:
             from ..ops import batching
@@ -375,11 +386,18 @@ def verify_frames(datas: list, wants: list[bytes],
             for row, i in enumerate(idxs):
                 ok[i] = digs[row].tobytes() == wants[i]
             continue
-        for i in idxs:
-            d = datas[i]
-            if not isinstance(d, (bytes, bytearray)):
-                d = bytes(d)
-            ok[i] = digest(algo, d) == wants[i]
+        # A few frames: one native call each (no stack copy).
+        from ..obs.kernprof import NATIVE
+        from ..obs.span import TRACER
+        with (TRACER.span("kernel.hh256", backend=NATIVE, rows=len(idxs),
+                          bytes=total)
+              if algo in (HIGHWAYHASH256, HIGHWAYHASH256S)
+              else contextlib.nullcontext()):
+            for i in idxs:
+                d = datas[i]
+                if not isinstance(d, (bytes, bytearray)):
+                    d = bytes(d)
+                ok[i] = digest(algo, d) == wants[i]
     return ok
 
 

@@ -468,7 +468,12 @@ class ErasureObjects:
 
     def _check_bucket(self, bucket: str) -> None:
         self._check_not_reserved(bucket)
-        if not self.bucket_exists(bucket):
+        # ec.meta: the drives' metadata before any data moves — this
+        # stat fan-out over every drive and the xl.meta quorum read.
+        from ..obs.span import TRACER
+        with TRACER.span("ec.meta", what="bucket"):
+            exists = self.bucket_exists(bucket)
+        if not exists:
             raise BucketNotFound(bucket)
 
     # ------------------------------------------------------------------
@@ -673,6 +678,8 @@ class ErasureObjects:
             # rename, not the body transfer.
             _t2 = time.perf_counter()
             with self.ns_lock.write_locked(bucket, object_name):
+                TRACER.record("lock.wait", TRACER.current(), _t2,
+                              time.perf_counter(), mode="write")
                 with TRACER.span("ec.commit") as _cs:
                     _, errs = parallel_map(
                         [lambda i=i: commit_one(i, _cs)
@@ -802,15 +809,19 @@ class ErasureObjects:
                     [e for e in disk_errs if e is not None])
 
         per = streams.batch_size(self.block_size, self.put_batch_bytes)
-        first = streams.read_exactly(reader, per)
-        if not first:
-            return 0, 0.0, 0.0
-        # One-byte lookahead: a stream of EXACTLY one full batch must
-        # also take the inline path — without it, an 8MiB part would
-        # spin up the worker for a single item. The probe blocks no
-        # longer than the next batch read would have.
-        probe = b"" if len(first) < per else streams.read_exactly(
-            reader, 1)
+        # door.recv: pulling a batch off the request body (socket wait
+        # and the stream's own md5/sha256). Where the pipeline overlaps
+        # it with ec.write the phase reduction takes the union.
+        with TRACER.span("door.recv", parent=root):
+            first = streams.read_exactly(reader, per)
+            if not first:
+                return 0, 0.0, 0.0
+            # One-byte lookahead: a stream of EXACTLY one full batch
+            # must also take the inline path — without it, an 8MiB part
+            # would spin up the worker for a single item. The probe
+            # blocks no longer than the next batch read would have.
+            probe = b"" if len(first) < per else streams.read_exactly(
+                reader, 1)
         if len(first) < per or not probe:
             # The whole stream fit in one batch: encode + write inline
             # on the request thread (no worker, no queue — a small PUT
@@ -823,7 +834,11 @@ class ErasureObjects:
 
         def produce():
             yield encode_one(first)
-            for batch in batches:
+            while True:
+                with TRACER.span("door.recv", parent=root):
+                    batch = next(batches, None)
+                if batch is None:
+                    return
                 yield encode_one(batch)
 
         with Prefetch(produce(), depth=self.pipeline_depth,
@@ -1077,9 +1092,14 @@ class ErasureObjects:
         # commit/delete must see before-or-after state, never the
         # mid-parallel-write mixture (ref getObjectInfo taking the
         # shared ns lock, cmd/erasure-object.go:383).
+        from ..obs.span import TRACER
+        _t_lock = time.perf_counter()
         with self.ns_lock.read_locked(bucket, object_name):
-            fi, _ = self._quorum_file_info(bucket, object_name,
-                                           version_id)
+            TRACER.record("lock.wait", TRACER.current(), _t_lock,
+                          time.perf_counter(), mode="read")
+            with TRACER.span("ec.meta"):
+                fi, _ = self._quorum_file_info(bucket, object_name,
+                                               version_id)
         if fi.deleted:
             if version_id:
                 raise MethodNotAllowed(f"{bucket}/{object_name}")
@@ -1121,11 +1141,16 @@ class ErasureObjects:
         self._check_bucket(bucket)
         # The read lock covers metadata + data so a concurrent overwrite
         # cannot swap the data dir between the two reads.
+        from ..obs.span import TRACER
         ctx = self.ns_lock.read_locked(bucket, object_name)
+        _t_lock = time.perf_counter()
         ctx.__enter__()
+        TRACER.record("lock.wait", TRACER.current(), _t_lock,
+                      time.perf_counter(), mode="read")
         try:
-            fi, agreed = self._quorum_file_info(bucket, object_name,
-                                                version_id)
+            with TRACER.span("ec.meta"):
+                fi, agreed = self._quorum_file_info(bucket, object_name,
+                                                    version_id)
             if fi.deleted:
                 if version_id:
                     raise MethodNotAllowed(f"{bucket}/{object_name}")
@@ -1269,7 +1294,8 @@ class ErasureObjects:
         budget_s = self.hedge_budget.budget()
         METRICS2.set_gauge("minio_tpu_v2_hedge_budget_ms", None,
                            round(budget_s * 1e3, 3))
-        pending = {submit(lambda j=j: fetch(j, win_off, n_cov, windows))
+        pending = {submit(lambda j=j: fetch(j, win_off, n_cov, windows,
+                                            parent_span))
                    for j in primary}
         hedge_futs: dict = {}
         deadline = time.monotonic() + budget_s
@@ -1289,7 +1315,8 @@ class ErasureObjects:
                 for j in fired:
                     def hedge(j=j):
                         with lane_scope(BACKGROUND):
-                            return fetch(j, win_off, n_cov, windows)
+                            return fetch(j, win_off, n_cov, windows,
+                                         parent_span)
                     hedge_futs[submit(hedge)] = j
                     METRICS2.inc("minio_tpu_v2_hedged_reads_total",
                                  {"result": "fired"})
@@ -1409,10 +1436,11 @@ class ErasureObjects:
         _read_parent = TRACER.current()
 
         def fetch(j: int, win_off: int, n_cov: int,
-                  windows: dict) -> bool:
+                  windows: dict, parent=None) -> bool:
             """Fetch shard j's window for one group; False if
             unavailable. Successful read durations feed the hedge
-            budget (the healthy-population percentile)."""
+            budget (the healthy-population percentile). `parent`: the
+            group's ec.fetch span (None = untraced)."""
             if j in windows:
                 return True
             if j in failed or by_shard[j] is None:
@@ -1422,12 +1450,12 @@ class ErasureObjects:
             rel = f"{fi.name}/{f.data_dir}/part.{part_number}"
             t0 = time.perf_counter()
             try:
-                if _read_parent is None:
+                if parent is None:
                     data = disk.read_file(fi.volume, rel, win_off,
                                           n_cov * stride)
                 else:
                     with TRACER.span("ec.shard_read",
-                                     parent=_read_parent, shard=j,
+                                     parent=parent, shard=j,
                                      endpoint=str(disk),
                                      bytes=n_cov * stride):
                         data = disk.read_file(fi.volume, rel, win_off,
@@ -1452,27 +1480,31 @@ class ErasureObjects:
             windows: dict[int, bytes] = {}
             order = [j for j in candidates if j not in failed]
             primary, spares = order[:k], order[k:]
-            if self.hedge_enabled and spares and len(primary) == k:
-                self._hedged_fetch(primary, spares, fetch, win_off,
-                                   n_cov, windows, k, _read_parent)
-            else:
-                parallel_map(
-                    [lambda j=j: fetch(j, win_off, n_cov, windows)
-                     for j in primary])
-            have = [j for j in candidates if j in windows]
-            # Known-dead shards (condemned in an earlier group, or
-            # with no mapped disk) would burn the first burst's slots
-            # on instant-False fetches — the burst must hold real
-            # parity reads.
-            rest = [j for j in candidates
-                    if j not in windows and j not in failed]
-            while len(have) < k and rest:
-                burst = rest[:k - len(have)]
-                rest = rest[len(burst):]
-                oks, _ = parallel_map(
-                    [lambda j=j: fetch(j, win_off, n_cov, windows)
-                     for j in burst])
-                have.extend(j for j, ok in zip(burst, oks) if ok)
+            with TRACER.span("ec.fetch", parent=_read_parent,
+                             blocks=n_cov) as _fs:
+                if self.hedge_enabled and spares and len(primary) == k:
+                    self._hedged_fetch(primary, spares, fetch, win_off,
+                                       n_cov, windows, k, _fs)
+                else:
+                    parallel_map(
+                        [lambda j=j: fetch(j, win_off, n_cov, windows,
+                                           _fs)
+                         for j in primary])
+                have = [j for j in candidates if j in windows]
+                # Known-dead shards (condemned in an earlier group, or
+                # with no mapped disk) would burn the first burst's
+                # slots on instant-False fetches — the burst must hold
+                # real parity reads.
+                rest = [j for j in candidates
+                        if j not in windows and j not in failed]
+                while len(have) < k and rest:
+                    burst = rest[:k - len(have)]
+                    rest = rest[len(burst):]
+                    oks, _ = parallel_map(
+                        [lambda j=j: fetch(j, win_off, n_cov, windows,
+                                           _fs)
+                         for j in burst])
+                    have.extend(j for j, ok in zip(burst, oks) if ok)
             if len(have) < k:
                 raise QuorumError(
                     f"read quorum not met: only {len(have)}/{k} "
@@ -1500,6 +1532,11 @@ class ErasureObjects:
             verified: set[int] = set()
 
             def verify_window(js: list[int]) -> None:
+                with TRACER.span("ec.verify", parent=_read_parent,
+                                 shards=len(js)):
+                    _verify_window(js)
+
+            def _verify_window(js: list[int]) -> None:
                 """Batch-verify all frames of windows js; populate
                 frame_ok, mark bad shards failed + heal-queued."""
                 datas, wants, keys = [], [], []
@@ -1553,8 +1590,12 @@ class ErasureObjects:
             for j in candidates:
                 if len(verified) >= k:
                     break
-                if j not in verified and fetch(j, win_off, n_cov,
-                                               windows):
+                if j in verified:
+                    continue
+                with TRACER.span("ec.fetch", parent=_read_parent,
+                                 blocks=n_cov, topup=True) as _fs:
+                    ok = fetch(j, win_off, n_cov, windows, _fs)
+                if ok:
                     verify_window([j])
 
             # (A vectorized group-gather fast path was tried here and
@@ -1606,23 +1647,31 @@ class ErasureObjects:
                 # Kernel child span: without it a degraded read's
                 # reconstruct math hides in root self-time and the
                 # slowlog blames client-stream instead of the codec.
-                with TRACER.span("kernel.rs_decode",
-                                 parent=_read_parent,
-                                 blocks=len(need)):
+                with TRACER.span("ec.decode", parent=_read_parent,
+                                 blocks=len(need)), \
+                        TRACER.span("kernel.rs_decode",
+                                    blocks=len(need)):
                     decoded = codec.decode_data_blocks_batch(
                         [gathered[i][2] for i in need])
                 for i, dec in zip(need, decoded):
                     gathered[i] = (gathered[i][0], gathered[i][1], dec)
 
             for b, blk_len, shards in gathered:
-                block_data = b"".join(
-                    shards[j].tobytes() for j in range(k))[:blk_len]
-                # Trim to the requested range within this block.
-                bstart = b * fi.erasure.block_size
-                lo = max(offset, bstart) - bstart
-                hi = min(want_end, bstart + blk_len) - bstart
-                if hi > lo:
-                    yield block_data[lo:hi]
+                # ec.join: the block's plaintext out of its k shard
+                # windows (two copies of the block), trimmed to the
+                # range. The span closes before the yield: the consumer
+                # owns the time the generator is suspended.
+                with TRACER.span("ec.join", parent=_read_parent,
+                                 bytes=blk_len):
+                    block_data = b"".join(
+                        shards[j].tobytes() for j in range(k))[:blk_len]
+                    # Trim to the requested range within this block.
+                    bstart = b * fi.erasure.block_size
+                    lo = max(offset, bstart) - bstart
+                    hi = min(want_end, bstart + blk_len) - bstart
+                    piece = block_data[lo:hi] if hi > lo else b""
+                if piece:
+                    yield piece
 
         group_starts = range(start_block, end_block + 1, group)
         if len(group_starts) <= 1:

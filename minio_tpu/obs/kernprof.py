@@ -375,7 +375,7 @@ def _probe_backend(backend: str) -> bool:
         from ..ops.gf256 import gf_matrix_to_bitplane
         bm = gf_matrix_to_bitplane(
             np.eye(2, dtype=np.uint8)).astype(np.float32)
-        out = np.asarray(rs_tpu._gf_apply_xla(jnp.asarray(bm),
+        out = np.asarray(rs_tpu.rs_gf_apply_xla(jnp.asarray(bm),
                                               jnp.asarray(data)))
         return bool((out == data).all())
     if backend == DEVICE:

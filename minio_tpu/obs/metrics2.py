@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import json
 import threading
+import time
+from bisect import bisect_left
 
 # Latency buckets in milliseconds (requests and phases share them; the
 # +Inf bucket is implicit).
@@ -27,6 +29,7 @@ LATENCY_BUCKETS_MS = (0.5, 1, 2.5, 5, 10, 25, 50, 100, 250, 500,
 # value into "_other" lands here (registered in __init__ so every
 # registry instance — including test-local ones — carries it).
 _OVERFLOW = "minio_tpu_v2_metrics_label_overflow_total"
+_PROCESS_CPU = "minio_tpu_v2_process_cpu_seconds_total"
 
 
 class MetricsV2:
@@ -155,21 +158,29 @@ class MetricsV2:
     def observe(self, name: str, labels: dict | None = None,
                 v: float = 0.0) -> None:
         with self._mu:
-            _, _, buckets = self._spec(name, ("histogram",))
-            series = self._data[name]
-            key = self._key(self._guard(name, labels))
-            h = series.get(key)
-            if h is None:
-                h = series[key] = [[0] * (len(buckets) + 1), 0.0, 0]
-            counts, _, _ = h
-            for i, ub in enumerate(buckets):
-                if v <= ub:
-                    counts[i] += 1
-                    break
-            else:
-                counts[-1] += 1
-            h[1] += v
-            h[2] += 1
+            self._observe(name, labels, v)
+
+    def observe_each(self, name: str, labels: dict, label: str,
+                     values: dict[str, float]) -> None:
+        """One observation per (value of `label`, v) in `values`, all
+        under ONE acquisition of the registry lock: a request's phases
+        at root finish (a dozen separate acquisitions by every request
+        thread at once convoy on the lock)."""
+        with self._mu:
+            for val, v in values.items():
+                self._observe(name, {**labels, label: val}, v)
+
+    def _observe(self, name: str, labels: dict | None, v: float) -> None:
+        _, _, buckets = self._spec(name, ("histogram",))
+        series = self._data[name]
+        key = self._key(self._guard(name, labels))
+        h = series.get(key)
+        if h is None:
+            h = series[key] = [[0] * (len(buckets) + 1), 0.0, 0]
+        # First bucket with v <= upper bound; past the last = +Inf.
+        h[0][bisect_left(buckets, v)] += 1
+        h[1] += v
+        h[2] += 1
 
     def get(self, name: str, labels: dict | None = None):
         """Current value: number (counter/gauge) or (sum, count) for a
@@ -185,6 +196,10 @@ class MetricsV2:
 
     def snapshot(self) -> dict:
         with self._mu:
+            if _PROCESS_CPU in self._specs:
+                # Read at scrape, not recorded: the process's own clock.
+                self._data[_PROCESS_CPU][self._key(None)] = \
+                    time.process_time()
             out = {}
             for name, (mtype, help_text, buckets) in self._specs.items():
                 series = []
@@ -337,9 +352,6 @@ METRICS2.register(
     "Bytes encoded/decoded/verified by the kernels, "
     "by kernel and device.")
 METRICS2.register(
-    "minio_tpu_v2_kernel_wall_seconds_total", "counter",
-    "Kernel wall-clock seconds, by kernel and device.")
-METRICS2.register(
     "minio_tpu_v2_kernel_batch_blocks_total", "counter",
     "Blocks carried by kernel batches (occupancy numerator).")
 METRICS2.register(
@@ -349,6 +361,18 @@ METRICS2.register(
     "minio_tpu_v2_kernel_dispatch_ms", "histogram",
     "Per-dispatch kernel latency in milliseconds, by kernel, dispatch "
     "backend (device/native/xla-cpu/host) and batch-size bucket.")
+METRICS2.register(
+    "minio_tpu_v2_kernel_dispatch_phase_ms", "histogram",
+    "Host phases of one device dispatch in milliseconds, by kernel, "
+    "backend and phase: prep (host-side packing), enqueue (device_puts "
+    "and the jitted call until it returns), wait (from the call's "
+    "return until the result is on the host: device queue + execution "
+    "+ D2H). Clock reads only; no synchronisation added.")
+METRICS2.register(
+    "minio_tpu_v2_kernel_dispatch_depth", "histogram",
+    "Device dispatches of this process already in flight when one "
+    "entered, by kernel and backend (sum/count = mean queue depth).",
+    buckets=(0, 1, 2, 3, 4, 6, 8, 12, 16, 32, 64))
 METRICS2.register(
     "minio_tpu_v2_kernel_queue_wait_ms", "histogram",
     "Time a request's encode batch waited in the coalescer window "
@@ -399,8 +423,19 @@ METRICS2.register(
     "Coalesced encode windows fanned out as parallel per-device "
     "dispatches, by device count.")
 METRICS2.register(
+    "minio_tpu_v2_process_cpu_seconds_total", "counter",
+    "User + system CPU seconds of this server process, read at "
+    "scrape; its rate is the cores the process keeps busy (near 1.0 "
+    "under load = GIL-bound).")
+METRICS2.register(
     "minio_tpu_v2_traces_completed_total", "counter",
     "Completed request traces.")
+METRICS2.register(
+    "minio_tpu_v2_request_phase_ms", "histogram",
+    "Per-request time in each phase, by api and phase: the union of "
+    "the request's depth-1 spans of that name (obs/span.py PHASES; "
+    "any other name is phase=other), observed when the request's root "
+    "span finishes; phase=unattributed is what no depth-1 span covers.")
 METRICS2.register(
     "minio_tpu_v2_cluster_nodes", "gauge",
     "Nodes contributing to a cluster metrics scrape.")

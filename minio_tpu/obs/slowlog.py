@@ -57,9 +57,11 @@ def _bucket_for(name: str) -> str | None:
         # client-stream — the disk/decode spans BELOW select.scan
         # still re-bucket to their own layers.
         return BLAME_SCAN
-    if (name.startswith("kernel.") or name == "ec.encode"
-            or name.startswith("bitrot")):
+    if (name.startswith("kernel.") or name.startswith("bitrot")
+            or name in ("ec.encode", "ec.verify", "ec.decode")):
         return BLAME_ENCODE
+    if name == "qos.wait":
+        return BLAME_ADMISSION
     return None
 
 
@@ -72,7 +74,13 @@ def blame_layers(tree: dict | None,
     keep their full durations — over-attribution to a bucket is exactly
     the signal wanted (the quorum waited on that layer)."""
     totals = dict.fromkeys(BLAME_LAYERS, 0.0)
-    totals[BLAME_ADMISSION] = max(0.0, admission_wait_ms)
+    # A tree that carries the wait as its own `qos.wait` child span
+    # blames it through the walk; the caller's number stands in only
+    # for trees without one.
+    if tree is None or not any(
+            isinstance(c, dict) and c.get("name") == "qos.wait"
+            for c in tree.get("children", ())):
+        totals[BLAME_ADMISSION] = max(0.0, admission_wait_ms)
 
     def walk(node: dict, inherited: str, deduct: float = 0.0) -> None:
         if not isinstance(node, dict):
@@ -93,7 +101,8 @@ def blame_layers(tree: dict | None,
         # The admission wait elapsed INSIDE the root span (route_qos
         # blocks under it with no child span), so deduct it from the
         # root's self-time: without this, client-stream >= admission
-        # always and a QoS-queuing-dominated request misblames.
+        # always and a QoS-queuing-dominated request misblames. (A
+        # `qos.wait` child is deducted as any child is.)
         walk(tree, BLAME_CLIENT, deduct=totals[BLAME_ADMISSION])
     return totals
 

@@ -21,7 +21,9 @@ Cost discipline (acceptance: <= 5% on the bench PUT path):
 from __future__ import annotations
 
 import contextvars
+import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -33,8 +35,72 @@ _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
 # without bound — the tail is dropped and counted in `dropped`.
 MAX_CHILDREN = 64
 
+# A ROOT holds the request's phases (depth 1), several per streamed
+# group (ec.fetch, ec.verify, door.hop x2, door.send): its cap is
+# wider, so objects up to ~40 groups reduce without a dropped phase.
+MAX_ROOT_CHILDREN = 256
+
 # Per-span event cap (QoS shed/deadline markers): same bounding rule.
 MAX_EVENTS = 16
+
+
+# The per-request PHASES: span names that, opened at depth 1 under a
+# request's root, are reduced to minio_tpu_v2_request_phase_ms{api,
+# phase} when the root finishes (reduce_phases). A fixed tuple
+# keeps the label set bounded; any other depth-1 name folds into
+# "other", and what no depth-1 span covers is "unattributed".
+PHASES = ("door.hop", "door.recv", "auth.sigv4", "qos.wait", "lock.wait",
+          "ec.meta", "ec.fetch", "ec.verify", "ec.decode", "ec.join",
+          "ec.encode", "ec.write", "ec.commit", "door.send")
+_PHASE_SET = frozenset(PHASES)
+
+
+def annotation(name: str, **kw):
+    """A jax.profiler.TraceAnnotation of `name`: the program's own span
+    on the profiler's clock, beside `XLA Modules`, while a profiler
+    session runs; an atomic load otherwise. A no-op context where this
+    process never imported JAX."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NOOP
+    return jax.profiler.TraceAnnotation(name, **kw)
+
+
+def _union_s(intervals: list) -> float:
+    """Length of the union of (start, end) intervals."""
+    total, end = 0.0, float("-inf")
+    for lo, hi in sorted(intervals):
+        if hi > end:
+            total += hi - max(lo, end)
+            end = hi
+    return total
+
+
+def reduce_phases(root: "Span") -> dict[str, float]:
+    """{phase: ms} of one finished request: per PHASES name the union of
+    its depth-1 spans' intervals on the spans' monotonic clock (a
+    streamed PUT overlaps ec.encode with ec.write, so lengths are
+    unions, not sums), plus "unattributed" = root duration minus the
+    union of ALL depth-1 spans inside the root's interval. Grafted
+    remote dicts and deeper spans are not read."""
+    t0 = root._t0
+    t1 = t0 + root.duration_ms / 1e3
+    by: dict[str, list] = {}
+    inside = []
+    for c in root.children[:MAX_ROOT_CHILDREN]:
+        if isinstance(c, dict) or not c._done:
+            continue
+        lo, hi = c._t0, c._t0 + c.duration_ms / 1e3
+        by.setdefault(c.name if c.name in _PHASE_SET else "other",
+                      []).append((lo, hi))
+        # door.hop ends where the root starts: a phase, but no part of
+        # the root's own interval.
+        if hi > t0 and lo < t1:
+            inside.append((max(lo, t0), min(hi, t1)))
+    out = {name: _union_s(ivs) * 1e3 for name, ivs in by.items()}
+    out["unattributed"] = max(
+        0.0, root.duration_ms - _union_s(inside) * 1e3)
+    return out
 
 
 class _Noop:
@@ -62,17 +128,13 @@ class Span:
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "start",
                  "duration_ms", "tags", "children", "events", "dropped",
-                 "_t0", "_token", "_tracer", "_done")
+                 "_t0", "_token", "_tracer", "_done", "_ann")
 
-    _seq = 0
-    _seq_mu = threading.Lock()
+    _ids = itertools.count(1)    # next() is atomic: no lock on the hot path
 
     def __init__(self, name: str, trace_id: str, parent_id: str = "",
                  tags: dict | None = None, tracer: "Tracer | None" = None):
-        with Span._seq_mu:
-            Span._seq += 1
-            seq = Span._seq
-        self.span_id = f"{seq:x}"
+        self.span_id = f"{next(Span._ids):x}"
         self.trace_id = trace_id
         self.parent_id = parent_id
         self.name = name
@@ -86,6 +148,7 @@ class Span:
         self._token = None
         self._tracer = tracer
         self._done = False
+        self._ann = None
 
     # -- tree assembly -------------------------------------------------
 
@@ -94,10 +157,13 @@ class Span:
         list.append is GIL-atomic, safe from parallel_map workers; the
         length check here is advisory under concurrency (two workers
         may both pass it) — to_dict() enforces the cap exactly."""
-        if len(self.children) >= MAX_CHILDREN:
+        if len(self.children) >= self._cap():
             self.dropped += 1
             return
         self.children.append(child)
+
+    def _cap(self) -> int:
+        return MAX_CHILDREN if self.parent_id else MAX_ROOT_CHILDREN
 
     def add_event(self, name: str, **attrs) -> None:
         """Record a point-in-time marker on this span (admission shed,
@@ -123,9 +189,10 @@ class Span:
             d["events"] = [dict(e) for e in self.events[:MAX_EVENTS]]
         kids = self.children
         dropped = self.dropped
-        if len(kids) > MAX_CHILDREN:  # racy appends past the cap
-            dropped += len(kids) - MAX_CHILDREN
-            kids = kids[:MAX_CHILDREN]
+        cap = self._cap()
+        if len(kids) > cap:  # racy appends past the cap
+            dropped += len(kids) - cap
+            kids = kids[:cap]
         if kids:
             d["children"] = [c if isinstance(c, dict) else c.to_dict()
                              for c in kids]
@@ -137,6 +204,12 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        if self.name in _PHASE_SET or self.name.startswith("kernel."):
+            # Mirror phases and kernel.* spans (with their tags) onto
+            # the profiler's clock, so an idle gap of the device reads
+            # `ec.write`, not a JAX internal.
+            self._ann = annotation(self.name, **self.tags)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -164,6 +237,9 @@ class Span:
             _current.reset(self._token)
             self._token = None
         self.duration_ms = (time.perf_counter() - self._t0) * 1e3
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if not self.parent_id and self._tracer is not None:
             return self._tracer._complete(self)
         return None
@@ -205,6 +281,23 @@ class Tracer:
         parent.add_child(child)
         return child
 
+    @staticmethod
+    def record(name: str, parent: Span | None, t0: float, t1: float,
+               **tags) -> None:
+        """Attach an already-CLOSED child [t0, t1] (perf_counter
+        seconds) to `parent`: a wait measured by two clock reads at a
+        boundary (worker-pool hop, admission queue, namespace lock),
+        where entering a context would cost more than the wait."""
+        if parent is None:
+            return
+        child = Span(name, parent.trace_id, parent.span_id,
+                     tags=tags or None)
+        child.start = parent.start + (t0 - parent._t0)
+        child._t0 = t0
+        child.duration_ms = max(0.0, t1 - t0) * 1e3
+        child._done = True
+        parent.add_child(child)
+
     # -- completed traces ----------------------------------------------
 
     def _complete(self, root: Span) -> dict:
@@ -213,6 +306,9 @@ class Tracer:
             self._ring.append(tree)
         from .metrics2 import METRICS2
         METRICS2.inc("minio_tpu_v2_traces_completed_total")
+        METRICS2.observe_each("minio_tpu_v2_request_phase_ms",
+                              {"api": root.name}, "phase",
+                              reduce_phases(root))
         return tree
 
     def recent(self, n: int = 32) -> list[dict]:

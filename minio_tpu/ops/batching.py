@@ -228,12 +228,17 @@ def _device_reconstruct(stack: np.ndarray, k: int, m: int,
                         avail: tuple[int, ...], missing: tuple[int, ...],
                         affinity: int | None = None) -> np.ndarray:
     from . import rs_tpu
-    from ..obs.kernel_stats import KERNEL, RS_DECODE, timed
-    bm = rs_tpu._placed_any_decode(k, m, avail, missing, serving_mesh(),
-                                   batch_home_device(stack, affinity))
-    with timed() as t:
-        out = np.asarray(rs_tpu.gf_apply(
-            bm, device_put_batch(stack, affinity)))
+    from ..obs.kernel_stats import KERNEL, RS_DECODE, dispatch, timed
+    with dispatch(RS_DECODE, rows=stack.shape[0],
+                  nbytes=stack.nbytes) as ph:
+        bm = rs_tpu._placed_any_decode(
+            k, m, avail, missing, serving_mesh(),
+            batch_home_device(stack, affinity))
+        ph.phase("enqueue")
+        with timed() as t:
+            dev = rs_tpu.gf_apply(bm, device_put_batch(stack, affinity))
+            ph.phase("wait")
+            out = np.asarray(dev)
     KERNEL.record(RS_DECODE, True, stack.nbytes, t.s,
                   blocks=stack.shape[0], backend=attempt_backend())
     return out

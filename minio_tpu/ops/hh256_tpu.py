@@ -230,8 +230,11 @@ def _rot32_halves(w, c: int):
 
 
 @partial(jax.jit, static_argnames=("n_packets", "rem"))
-def _hash_chunks_device(words, rem_packet, init, n_packets: int, rem: int):
-    """words: (B, n_packets, 8) u32 (little-endian 64-bit lane pairs);
+def hh256_rows(words, rem_packet, init, n_packets: int, rem: int):
+    """The device program, under a name that says what it is: a trace's
+    device line reads `jit_hh256_rows(<fingerprint>)`.
+
+    words: (B, n_packets, 8) u32 (little-endian 64-bit lane pairs);
     rem_packet: (B, 8) u32 pre-packed remainder packet (ignored when
     rem == 0); init: 8 x (4,) u32 from _init_state_np.
     Returns (B, 8) u32 digests."""
@@ -301,50 +304,58 @@ def _pack_remainder(tail: np.ndarray, rem: int) -> np.ndarray:
     return packet.view(np.uint32)
 
 
-def hash_chunks(chunks: np.ndarray, key: bytes = MAGIC_KEY) -> np.ndarray:
+def hash_chunks(chunks: np.ndarray, key: bytes = MAGIC_KEY,
+                t_prep: float | None = None) -> np.ndarray:
     """Hash B equal-length chunks on the device.
 
     chunks: (B, L) uint8, L > 0 (any length — the remainder step is
     in-kernel). Returns (B, 32) uint8 HighwayHash-256 digests,
-    byte-identical to ops/hh256.HighwayHash256.
+    byte-identical to ops/hh256.HighwayHash256. `t_prep`: when the
+    caller's own packing for this dispatch began (perf_counter), so the
+    dispatch's prep phase holds it too.
     """
     if chunks.ndim != 2:
         raise ValueError("chunks must be (B, L)")
     B, L = chunks.shape
     if L == 0:
         raise ValueError("chunk length must be positive")
-    n_full, rem = divmod(L, 32)
-    chunks = np.ascontiguousarray(chunks)
-    words = chunks[:, :n_full * 32].copy().view(np.uint32).reshape(
-        B, n_full, 8)
-    if rem:
-        rem_packet = _pack_remainder(chunks[:, n_full * 32:], rem)
-    else:
-        rem_packet = np.zeros((B, 8), dtype=np.uint32)
-    init = _init_state_np(key)
-    # Spread independent chunks across the serving mesh; the hash chain
-    # is per-row, so no cross-device collectives.
     from . import batching
-    from ..obs.kernel_stats import HH256, KERNEL, timed
-    m = batching.serving_mesh()
-    if m is not None:
-        # Rows shard over every mesh device when B divides it; a batch
-        # that does not stays whole on the default device (index 0).
-        from ..obs.metrics2 import METRICS2
-        sharded = B % m.size == 0
-        METRICS2.inc("minio_tpu_v2_hh256_mesh_dispatches_total",
-                     {"placement": "sharded" if sharded else "single"})
-        if sharded:
-            from ..parallel.mesh import rows_sharding
-            words = jax.device_put(words, rows_sharding(m, B, 3))
-            rem_packet = jax.device_put(rem_packet,
-                                        rows_sharding(m, B, 2))
-    with timed() as t:
-        # C-contiguous: a TPU result can come back in the device's own
-        # (column-major) layout, and the byte view below needs the
-        # last axis contiguous. On the CPU it always was.
-        out = np.ascontiguousarray(_hash_chunks_device(
-            words, rem_packet, init, n_full, rem))
+    from ..obs.kernel_stats import HH256, KERNEL, dispatch, timed
+    with dispatch(HH256, rows=B, nbytes=chunks.nbytes,
+                  t_prep=t_prep) as ph:
+        n_full, rem = divmod(L, 32)
+        chunks = np.ascontiguousarray(chunks)
+        words = chunks[:, :n_full * 32].copy().view(np.uint32).reshape(
+            B, n_full, 8)
+        if rem:
+            rem_packet = _pack_remainder(chunks[:, n_full * 32:], rem)
+        else:
+            rem_packet = np.zeros((B, 8), dtype=np.uint32)
+        init = _init_state_np(key)
+        ph.phase("enqueue")
+        # Spread independent chunks across the serving mesh; the hash
+        # chain is per-row, so no cross-device collectives.
+        m = batching.serving_mesh()
+        if m is not None:
+            # Rows shard over every mesh device when B divides it; a
+            # batch that does not stays whole on the default device
+            # (index 0).
+            from ..obs.metrics2 import METRICS2
+            sharded = B % m.size == 0
+            METRICS2.inc("minio_tpu_v2_hh256_mesh_dispatches_total",
+                         {"placement": "sharded" if sharded else "single"})
+            if sharded:
+                from ..parallel.mesh import rows_sharding
+                words = jax.device_put(words, rows_sharding(m, B, 3))
+                rem_packet = jax.device_put(rem_packet,
+                                            rows_sharding(m, B, 2))
+        with timed() as t:
+            dev = hh256_rows(words, rem_packet, init, n_full, rem)
+            ph.phase("wait")
+            # C-contiguous: a TPU result can come back in the device's
+            # own (column-major) layout, and the byte view below needs
+            # the last axis contiguous. On the CPU it always was.
+            out = np.ascontiguousarray(dev)
     KERNEL.record(HH256, True, chunks.nbytes, t.s, blocks=B,
                   backend=batching.attempt_backend())
     return out.view(np.uint8).reshape(B, 32)

@@ -94,7 +94,7 @@ def _kernel(r: int, k: int, dtype, m_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit,
                    static_argnames=("r", "k", "interpret", "with_data"))
-def _apply_jit(big_m: jnp.ndarray, shards: jnp.ndarray, r: int, k: int,
+def rs_gf_apply(big_m: jnp.ndarray, shards: jnp.ndarray, r: int, k: int,
                interpret: bool = False,
                with_data: bool = False) -> jnp.ndarray:
     """One fused dispatch: permute matrix, lane-pad, pallas_call,
@@ -138,6 +138,10 @@ def _apply_jit(big_m: jnp.ndarray, shards: jnp.ndarray, r: int, k: int,
             bytes_accessed=B * k * Sp + B * r * Sp,
             transcendentals=0),
         interpret=interpret,
+        # Stable names: the trace's device line reads
+        # jit_rs_gf_apply(<fingerprint>) and the kernel rs_gf_apply,
+        # whatever the private functions here are called.
+        name="rs_gf_apply",
     )(mperm, x)
     if pad:
         out = out[:, :, :S]
@@ -175,13 +179,13 @@ def gf_apply(big_m, shards, *, interpret: bool = False) -> jnp.ndarray:
     Returns (..., r, S) uint8, byte-identical to the XLA path.
     """
     big_m, shards, r, k = _norm(big_m, shards)
-    return _apply_jit(big_m, shards, r, k, interpret=interpret)
+    return rs_gf_apply(big_m, shards, r, k, interpret=interpret)
 
 
 def encode_blocks(big_m, data, *, interpret: bool = False) -> jnp.ndarray:
     """(..., k, S) data -> (..., k+m, S) all shards (parity appended)."""
     big_m, data, r, k = _norm(big_m, data)
-    return _apply_jit(big_m, data, r, k, interpret=interpret,
+    return rs_gf_apply(big_m, data, r, k, interpret=interpret,
                       with_data=True)
 
 
@@ -214,7 +218,7 @@ def _apply_sharded(mesh, big_m, x, *, interpret: bool,
     B, _, S = x.shape
     spec = batch_sharding(mesh, B, S).spec
     fn = _shard_map(
-        functools.partial(_apply_jit, r=r, k=k, interpret=interpret,
+        functools.partial(rs_gf_apply, r=r, k=k, interpret=interpret,
                           with_data=with_data),
         mesh, (P(None, None), spec), spec)
     return fn(big_m, x)

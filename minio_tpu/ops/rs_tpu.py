@@ -107,7 +107,7 @@ def _device_pinned(x: np.ndarray, device_index: int) -> "jnp.ndarray":
 # Two implementations of the same bit-plane linear map:
 #  - rs_pallas.gf_apply: Pallas/Mosaic kernel that keeps the 16x bit-plane
 #    inflation in VMEM (bytes-only HBM traffic) — the fast path on TPU.
-#  - _gf_apply_xla below: plain XLA fallback (materializes the planes) —
+#  - rs_gf_apply_xla below: plain XLA fallback (materializes the planes) —
 #    used on CPU, for non-batched (2-D) inputs on a mesh, and when
 #    Mosaic is unavailable on the platform (disabled loudly, once).
 #    Mesh-sharded 3-D batches run the Pallas kernel under shard_map
@@ -202,7 +202,7 @@ def _pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
 
 
 @jax.jit
-def _gf_apply_xla(big_m: jnp.ndarray, shards: jnp.ndarray) -> jnp.ndarray:
+def rs_gf_apply_xla(big_m: jnp.ndarray, shards: jnp.ndarray) -> jnp.ndarray:
     bits = _unpack_bits(shards)
     acc = jnp.matmul(big_m.astype(jnp.bfloat16), bits,
                      preferred_element_type=jnp.float32)
@@ -259,12 +259,12 @@ def gf_apply(big_m: jnp.ndarray, shards: jnp.ndarray) -> jnp.ndarray:
     """
     from . import rs_pallas
     return _dispatch(rs_pallas.gf_apply, rs_pallas.gf_apply_sharded,
-                     _gf_apply_xla, big_m, shards)
+                     rs_gf_apply_xla, big_m, shards)
 
 
 @jax.jit
-def _encode_blocks_xla(big_m: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
-    parity = _gf_apply_xla(big_m, data)
+def rs_encode_blocks_xla(big_m: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
+    parity = rs_gf_apply_xla(big_m, data)
     return jnp.concatenate([data, parity], axis=-2)
 
 
@@ -272,7 +272,7 @@ def encode_blocks(big_m: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
     """Batched encode: (..., k, S) data shards -> (..., k+m, S) all shards."""
     from . import rs_pallas
     return _dispatch(rs_pallas.encode_blocks,
-                     rs_pallas.encode_blocks_sharded, _encode_blocks_xla,
+                     rs_pallas.encode_blocks_sharded, rs_encode_blocks_xla,
                      big_m, data)
 
 
@@ -288,18 +288,22 @@ def encode_batch(data: np.ndarray, k: int, m: int,
     dispatch lands in the metrics-v2 kernel counters
     (invocations/bytes/wall/occupancy)."""
     from . import batching
-    from ..obs.kernel_stats import KERNEL, RS_ENCODE, timed
-    home = (batching.batch_home_device(data, affinity)
-            if data.ndim == 3 else None)
-    bm = _placed_parity(k, m, batching.serving_mesh(), home)
-    with timed() as t:
-        if data.ndim == 3:
-            placed = batching.device_put_batch(data, affinity)
-        else:
-            placed = jnp.asarray(data)
-        out = np.asarray(encode_blocks(bm, placed))
-    KERNEL.record(RS_ENCODE, True, data.nbytes, t.s,
-                  blocks=data.shape[0] if data.ndim == 3 else 1,
+    from ..obs.kernel_stats import KERNEL, RS_ENCODE, dispatch, timed
+    blocks = data.shape[0] if data.ndim == 3 else 1
+    with dispatch(RS_ENCODE, rows=blocks, nbytes=data.nbytes) as ph:
+        home = (batching.batch_home_device(data, affinity)
+                if data.ndim == 3 else None)
+        bm = _placed_parity(k, m, batching.serving_mesh(), home)
+        ph.phase("enqueue")
+        with timed() as t:
+            if data.ndim == 3:
+                placed = batching.device_put_batch(data, affinity)
+            else:
+                placed = jnp.asarray(data)
+            dev = encode_blocks(bm, placed)
+            ph.phase("wait")
+            out = np.asarray(dev)
+    KERNEL.record(RS_ENCODE, True, data.nbytes, t.s, blocks=blocks,
                   backend=batching.attempt_backend())
     return out
 

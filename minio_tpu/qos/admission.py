@@ -214,8 +214,13 @@ class AdmissionController:
             self._record_shed(api_class, shed.reason)
             raise
         finally:
-            self._observe(api_class, gate,
-                          (time.perf_counter() - t0) * 1e3)
+            t1 = time.perf_counter()
+            self._observe(api_class, gate, (t1 - t0) * 1e3)
+            # The same wait as a closed child of the request's span: a
+            # phase of its timeline (request_phase_ms{phase=qos.wait}).
+            from ..obs.span import TRACER
+            TRACER.record("qos.wait", TRACER.current(), t0, t1,
+                          api_class=api_class)
         return _Admitted(self, api_class)
 
     def _release(self, api_class: str) -> None:

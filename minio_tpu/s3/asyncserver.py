@@ -345,6 +345,10 @@ class _AsyncTxn:
         self.close_after = False
         self.detached = False
         self._pending_head: bytes | None = None
+        # Span-clock stamps of the loop side (set by _dispatch): head
+        # parsed, handed to the pool. _serve_one turns them into the
+        # request's door.recv / door.hop phases.
+        self.t_head = self.t_dispatch = None
 
     # -- body hygiene --------------------------------------------------
 
@@ -419,7 +423,11 @@ class _AsyncTxn:
         copied context, so a slow reader parks this connection — not
         the worker thread that built the response.  Returns True
         (detached); the drain task owns finish_fn from here."""
+        from ..obs.span import TRACER
+        t0 = time.perf_counter()
         self.flush_head()
+        t_handoff = time.perf_counter()
+        TRACER.record("door.send", root_span, t0, t_handoff, head=True)
         ctx = contextvars.copy_context()
         # This pooled worker thread is about to return to the pool:
         # clear the root span's contextvar token HERE (same thread
@@ -430,18 +438,23 @@ class _AsyncTxn:
             root_span.detach_context()
         self.detached = True
         self.conn.start_drain_threadsafe(resp.body, raw_path, finish_fn,
-                                         ctx, self.close_after)
+                                         ctx, self.close_after,
+                                         root_span, t_handoff)
         return True
 
 
 def _next_chunk(it):
     """One producer step, run on the worker pool under the request's
-    copied context; None marks exhaustion (StopIteration must not
-    cross the executor boundary)."""
+    copied context; a None chunk marks exhaustion (StopIteration must
+    not cross the executor boundary). Returns (chunk, started, ended)
+    on the spans' clock, so the drain can tell the step from the two
+    thread hops around it."""
+    t0 = time.perf_counter()
     try:
-        return next(it)
+        chunk = next(it)
     except StopIteration:
-        return None
+        chunk = None
+    return chunk, t0, time.perf_counter()
 
 
 class _HttpConn(asyncio.Protocol):
@@ -458,6 +471,7 @@ class _HttpConn(asyncio.Protocol):
         self._state = "head"          # head | body | stream | wait
         self._head: tuple | None = None  # (method, path, query, headers)
         self._need = 0                # buffered-body bytes still wanted
+        self._t_head = 0.0            # span clock when the head was parsed
         self._bridge: BodyBridge | None = None
         self._body_left = 0           # wire bytes of the current body
         self._chunked: _ChunkedTEParser | None = None
@@ -615,6 +629,7 @@ class _HttpConn(asyncio.Protocol):
     def _parse_head(self, head: bytes) -> bool:
         """Parse one request head from `head`; returns False when the
         connection was rejected."""
+        self._t_head = time.perf_counter()
         try:
             text = head.decode("latin-1")
             lines = text.split("\r\n")
@@ -808,6 +823,8 @@ class _HttpConn(asyncio.Protocol):
         self._in_flight = True
         txn = _AsyncTxn(self, method, raw_path, query, headers, body,
                         bridge, cl)
+        txn.t_head = self._t_head
+        txn.t_dispatch = time.perf_counter()
         pool = (self.front.rpc_pool
                 if raw_path.startswith("/minio-tpu/rpc/")
                 else self.front.pool)
@@ -875,12 +892,13 @@ class _HttpConn(asyncio.Protocol):
         self.request_complete(close)
 
     def start_drain_threadsafe(self, body_iter, raw_path, finish_fn,
-                               ctx, close_after) -> None:
+                               ctx, close_after, root_span=None,
+                               t_handoff=None) -> None:
         self._finish_cb = finish_fn
         try:
             self._loop.call_soon_threadsafe(
                 self._spawn_drain, body_iter, raw_path, finish_fn, ctx,
-                close_after)
+                close_after, root_span, t_handoff)
         except RuntimeError:
             # Loop gone: account the request here; connection is dead.
             self._finish_cb = None
@@ -975,13 +993,16 @@ class _HttpConn(asyncio.Protocol):
             self._process_buf()
 
     def _spawn_drain(self, body_iter, raw_path, finish_fn, ctx,
-                     close_after) -> None:
+                     close_after, root_span=None,
+                     t_handoff=None) -> None:
         task = self._loop.create_task(self._drain_response(
-            body_iter, raw_path, finish_fn, ctx, close_after))
+            body_iter, raw_path, finish_fn, ctx, close_after,
+            root_span, t_handoff))
         self.front.track_task(task)
 
     async def _drain_response(self, body_iter, raw_path, finish_fn,
-                              ctx, close_after) -> None:
+                              ctx, close_after, root_span=None,
+                              t_handoff=None) -> None:
         # `finish_fn` ownership: this task and connection_lost's
         # safety net both run on THIS loop, so whoever still finds
         # self._finish_cb set owns the accounting call — exactly one
@@ -995,13 +1016,28 @@ class _HttpConn(asyncio.Protocol):
         loop = self._loop
         ok = True
         pending = None
+        # Phases of the request that only this task can see (closed
+        # children of its root): door.hop = a producer step waiting for
+        # a stream-pool thread, and this loop waiting to be woken with
+        # its result; door.send = the chunk into the transport and the
+        # wait for the socket to take it.
+        from ..obs.span import TRACER
+        if t_handoff is not None:
+            # The worker handed the body over; this task's first line
+            # is the loop taking it up.
+            TRACER.record("door.hop", root_span, t_handoff,
+                          time.perf_counter())
         try:
             while True:
+                t_sub = time.perf_counter()
                 pending = loop.run_in_executor(
                     self.front.stream_pool, ctx.run, _next_chunk,
                     body_iter)
-                chunk = await pending
+                chunk, t_run, t_ran = await pending
                 pending = None
+                t_back = time.perf_counter()
+                TRACER.record("door.hop", root_span, t_sub, t_run)
+                TRACER.record("door.hop", root_span, t_ran, t_back)
                 if chunk is None:
                     break
                 if not chunk:
@@ -1010,6 +1046,8 @@ class _HttpConn(asyncio.Protocol):
                     raise ConnectionResetError("connection closed")
                 self.transport.write(chunk)
                 await self._wait_writable()
+                TRACER.record("door.send", root_span, t_back,
+                              time.perf_counter(), bytes=len(chunk))
         except (BrokenPipeError, ConnectionResetError):
             ok = False
         except asyncio.CancelledError:

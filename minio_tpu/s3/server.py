@@ -3843,6 +3843,20 @@ class S3Server:
                 method=command, path=raw_path)
             if root_span is not None:
                 root_span.__enter__()
+                # What happened to the request before this thread had
+                # it (async front door): a body buffered on the loop,
+                # then the wait for a pool worker. Both END where the
+                # root starts, so they are phases of the request but no
+                # part of the root's (or api_request_duration_ms's)
+                # interval.
+                t_disp = getattr(txn, "t_dispatch", None)
+                if t_disp is not None:
+                    if body:
+                        TRACER.record("door.recv", root_span,
+                                      txn.t_head, t_disp,
+                                      bytes=len(body))
+                    TRACER.record("door.hop", root_span, t_disp,
+                                  root_span._t0)
             try:
                 resp = server.route_qos(req)
             except APIError as e:
@@ -4456,10 +4470,13 @@ class _ThreadedTxn:
         — never detaches; finish_fn runs here and again (idempotent)
         in the core's finally."""
         h = self.h
+        from ..obs.span import TRACER
         try:
             for chunk in resp.body:
                 if chunk:
-                    h.wfile.write(chunk)
+                    with TRACER.span("door.send", parent=root_span,
+                                     bytes=len(chunk)):
+                        h.wfile.write(chunk)
         except (BrokenPipeError, ConnectionResetError):
             raise
         except Exception as e:  # noqa: BLE001

@@ -49,9 +49,26 @@ def pytest_sessionfinish(session, exitstatus):
 # package exists (the server itself boots without it and serves plain
 # objects — crypto/sse.py gates the import).
 import importlib.util  # noqa: E402
+import sys  # noqa: E402
 
 import pytest  # noqa: E402
 
 needs_crypto = pytest.mark.skipif(
     importlib.util.find_spec("cryptography") is None,
     reason="needs the optional cryptography package")
+
+
+@pytest.fixture(autouse=True)
+def _join_codec_probe():
+    """The first in-process server starts the process-wide
+    `codec-autotune-probe` thread, whose ladder logs to stderr for ~20 s.
+    A line written between two tests, while pytest's capture is
+    suspended, lands in the middle of a line of dots and the driver's
+    pass count then under-reads. Joining the thread in the teardown of
+    the test that started it keeps every line inside that test's
+    capture."""
+    yield
+    autotune = sys.modules.get("minio_tpu.ops.autotune")
+    t = autotune and autotune.AUTOTUNE._probe_thread
+    if t is not None and t.is_alive():
+        t.join(120)

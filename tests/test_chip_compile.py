@@ -66,7 +66,7 @@ def _compile_rs(sharding, B, k, r, S, with_data):
     bm = jax.ShapeDtypeStruct((8 * r, 8 * k), jnp.float32,
                               sharding=sharding)
     x = jax.ShapeDtypeStruct((B, k, S), jnp.uint8, sharding=sharding)
-    c = rs_pallas._apply_jit.lower(bm, x, r=r, k=k,
+    c = rs_pallas.rs_gf_apply.lower(bm, x, r=r, k=k,
                                    with_data=with_data).compile()
     assert "tpu_custom_call" in c.as_text()  # the Mosaic kernel is in
     return c
@@ -151,6 +151,6 @@ def test_hh256_compiles_for_v5e(one_chip, B, L):
     init = tuple(jax.ShapeDtypeStruct((4,), jnp.uint32,
                                       sharding=one_chip)
                  for _ in range(8))
-    c = hh256_tpu._hash_chunks_device.lower(
+    c = hh256_tpu.hh256_rows.lower(
         w, rp, init, n_packets=n, rem=rem).compile()
     assert c.memory_analysis().argument_size_in_bytes >= B * n * 32

@@ -110,7 +110,7 @@ def test_pallas_lowering_error_reaches_kernprof_with_its_shape(
         0, 256, (B, k, S)).astype(np.uint8)
     bm = jnp.asarray(rs_tpu.parity_bitplane(k, m))
     out = np.asarray(rs_tpu._dispatch(refused, refused,
-                                      rs_tpu._gf_apply_xla, bm,
+                                      rs_tpu.rs_gf_apply_xla, bm,
                                       jnp.asarray(data)))
     for b in range(B):  # the XLA path answered, byte-exact
         assert np.array_equal(
@@ -129,7 +129,7 @@ def test_pallas_lowering_error_reaches_kernprof_with_its_shape(
     # runs and without touching the health machine.
     monkeypatch.setitem(rs_tpu._pallas_state, "enabled", True)
     with pytest.raises(ValueError, match="sublane dim"):
-        rs_tpu._dispatch(refused, refused, rs_tpu._gf_apply_xla, bm,
+        rs_tpu._dispatch(refused, refused, rs_tpu.rs_gf_apply_xla, bm,
                          jnp.asarray(data[:, :3]))
     assert fresh_kernprof.snapshot()["backends"][
         batching.attempt_backend()]["failures"] == 1

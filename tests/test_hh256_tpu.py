@@ -197,12 +197,12 @@ def test_hash_chunks_takes_a_result_in_the_device_layout(monkeypatch):
     from minio_tpu.ops import hh256_tpu
     from minio_tpu.ops.hh256 import MAGIC_KEY, HighwayHash256
 
-    real = hh256_tpu._hash_chunks_device
+    real = hh256_tpu.hh256_rows
 
     def column_major(*a, **kw):
         return np.asfortranarray(np.asarray(real(*a, **kw)))
 
-    monkeypatch.setattr(hh256_tpu, "_hash_chunks_device", column_major)
+    monkeypatch.setattr(hh256_tpu, "hh256_rows", column_major)
     chunks = np.random.default_rng(5).integers(
         0, 256, (8, 100)).astype(np.uint8)
     got = hh256_tpu.hash_chunks(chunks)

@@ -157,7 +157,8 @@ import re
 from minio_tpu.erasure.engine import ErasureObjects as _EO
 from minio_tpu.obs import metrics2 as m2
 from minio_tpu.obs.kernel_stats import KERNEL
-from minio_tpu.obs.span import MAX_CHILDREN, TRACER, Span
+from minio_tpu.obs.span import (MAX_CHILDREN, MAX_ROOT_CHILDREN, TRACER,
+                                Span)
 
 
 def _walk(node, depth=0, out=None):
@@ -324,7 +325,12 @@ def test_kernel_counters_monotonic():
         "minio_tpu_v2_kernel_invocations_total", lbl_enc) >= mid_inv
     snap = KERNEL.snapshot()
     assert snap["rs_encode/host"]["invocations"] >= 1
-    assert snap["rs_encode/host"]["wall_seconds"] > 0
+    # Wall time is kernel_dispatch_ms (per kernel x backend); the
+    # duplicate kernel_wall_seconds_total counter is gone.
+    assert "wall_seconds" not in snap["rs_encode/host"]
+    walls = m2.METRICS2.snapshot()["minio_tpu_v2_kernel_dispatch_ms"]
+    assert sum(s["sum"] for s in walls["series"]
+               if s["labels"]["kernel"] == "rs_encode") > 0
 
 
 def test_metrics2_rejects_unregistered_names():
@@ -480,12 +486,19 @@ def test_trace_ring_and_children_bounded():
     # Child cap: a pathological span fan-out drops the tail, counted.
     root = TRACER.begin("cap.test", "CAP")
     root.__enter__()
-    for _ in range(MAX_CHILDREN + 25):
+    # (A root holds the request's phases and has the wider cap.)
+    for _ in range(MAX_ROOT_CHILDREN + 25):
         with TRACER.span("child"):
             pass
+    with TRACER.span("fan") as fan:
+        for _ in range(MAX_CHILDREN + 7):
+            with TRACER.span("leaf"):
+                pass
     tree = root.finish()
-    assert len(tree["children"]) == MAX_CHILDREN
-    assert tree["droppedChildren"] == 25
+    assert len(tree["children"]) == MAX_ROOT_CHILDREN
+    assert tree["droppedChildren"] == 25 + 1   # `fan` came too late
+    assert len(fan.to_dict()["children"]) == MAX_CHILDREN
+    assert fan.to_dict()["droppedChildren"] == 7
 
 
 def test_span_noop_without_active_trace():

@@ -55,7 +55,7 @@ def run() -> dict:
 
     # XLA bit-plane path
     def launch_xla():
-        return rs_tpu._gf_apply_xla(bm, data)
+        return rs_tpu.rs_gf_apply_xla(bm, data)
 
     def sync(o):
         np.asarray(o[0, 0, 0])
@@ -68,7 +68,7 @@ def run() -> dict:
     for tile in (1024, 2048, 4096, 8192):
         try:
             rs_pallas._MAX_TILE = tile
-            rs_pallas._apply_jit.clear_cache()
+            rs_pallas.rs_gf_apply.clear_cache()
 
             def launch_p():
                 return rs_pallas.gf_apply(bm, data)
@@ -81,7 +81,7 @@ def run() -> dict:
 
     # correctness spot-check at the final tile setting
     got = np.asarray(rs_pallas.gf_apply(bm, data[:2]))
-    want = np.asarray(rs_tpu._gf_apply_xla(bm, data[:2]))
+    want = np.asarray(rs_tpu.rs_gf_apply_xla(bm, data[:2]))
     out["pallas_matches_xla"] = bool(np.array_equal(got, want))
 
     # device HighwayHash throughput (batch of shard sub-blocks)
