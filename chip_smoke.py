@@ -461,11 +461,11 @@ class Run:
                 objs[f"big/{i}"] = body
                 if self.tiny or i > 1:
                     continue
-                # HH256's fori_loop runs one iteration per 32-byte
-                # packet. Where that makes the run impractically slow,
-                # say so with the number and shrink THIS phase only —
-                # never the assertions. Rated over the second PUT: the
-                # first one pays the compiles.
+                # Where the device HH256 lane makes the run
+                # impractically slow (a per-packet loop outside the
+                # kernel did), say so with the number and shrink THIS
+                # phase only — never the assertions. Rated over the
+                # second PUT: the first one pays the compiles.
                 cn = counters(c)
                 if warm is None:
                     warm = cn
@@ -601,6 +601,7 @@ class Run:
         dev = health["backends"]["device"]
         say(f"  kernel-health device: {json.dumps(dev)}")
         say(f"  rs kernel: {json.dumps(plan['rsKernel'])}")
+        say(f"  hh kernel: {json.dumps(plan['hhKernel'])}")
         self.info["rs_kernel"] = plan["rsKernel"]
 
         # The assertions (reported, and fatal to "ok", never skipped).
@@ -617,6 +618,9 @@ class Run:
         self.check(plan["rsKernel"]["kernel"] == "pallas",
                    f"the RS kernel that ran is the Pallas one "
                    f"({json.dumps(plan['rsKernel'])})")
+        self.check(plan["hhKernel"]["kernel"] == "pallas",
+                   f"the HH256 packet loop that ran is the Pallas "
+                   f"kernel ({json.dumps(plan['hhKernel'])})")
         for kern in RS_KERNELS + ("hh256",):
             rose = after["bytes"][kern, "device"] \
                 - before["bytes"][kern, "device"]
@@ -787,6 +791,9 @@ class Run:
         self.check(plan["rsKernel"]["kernel"] == "pallas",
                    f"the RS kernel that ran is the Pallas one "
                    f"({json.dumps(plan['rsKernel'])})")
+        self.check(plan["hhKernel"]["kernel"] == "pallas",
+                   f"the HH256 packet loop that ran is the Pallas "
+                   f"kernel ({json.dumps(plan['hhKernel'])})")
         m = metrics(c)
         for placement in ("sharded", "single"):
             say(f"  hh256 dispatches {placement}: "

@@ -388,6 +388,11 @@ METRICS2.register(
     "placement: sharded (rows over every device) or single (a batch "
     "that does not divide the mesh, whole on device 0).")
 METRICS2.register(
+    "minio_tpu_v2_hh256_kernel_info", "gauge",
+    "1 for the form this process's device HighwayHash programs were "
+    "built in, by impl: pallas (the packet loop inside one TPU "
+    "kernel) or xla (a fori_loop, no TPU). Set at the first dispatch.")
+METRICS2.register(
     "minio_tpu_v2_jit_programs_total", "counter",
     "Programs this process handed to the XLA backend, by result: "
     "requested (every program) and cache_hit (those the persistent "

@@ -720,6 +720,11 @@ class AdminHandlers:
         # Which GF kernel the jit lane runs ("pallas" | "xla") and,
         # when it is not the Pallas one, why.
         out["rsKernel"] = rs_tpu.kernel_report()
+        # Which form of the HighwayHash packet loop the device
+        # programs were built in ("pallas" | "xla", "" before the
+        # first dispatch).
+        from ..ops import hh256_tpu
+        out["hhKernel"] = hh256_tpu.kernel_report()
         out["affinity"] = MESH_AFFINITY.snapshot()
         return out
 

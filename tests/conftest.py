@@ -72,3 +72,29 @@ def _join_codec_probe():
     t = autotune and autotune.AUTOTUNE._probe_thread
     if t is not None and t.is_alive():
         t.join(120)
+
+
+@pytest.fixture(autouse=True, scope="session")
+def _probe_thread_logs_to_the_ring_only():
+    """The join above cannot cover the moment between the SETUP and the
+    CALL phase of the test whose fixture started the thread: capture is
+    suspended there too, and the ladder's first line (a few ms after
+    the start) landed in the dots once in PR 26's runs (1124 passed,
+    1105 dots counted). Lines from that thread go to the log ring
+    only, which is where the tests read them."""
+    import threading
+    import time
+
+    from minio_tpu.logger import logger as lg
+    emit = lg.Logger._emit
+
+    def ring_only(self, level, message, source="", **fields):
+        if threading.current_thread().name != "codec-autotune-probe":
+            return emit(self, level, message, source, **fields)
+        self.ring.add(lg.LogEntry(level=level, time=time.time(),
+                                  message=message, source=source,
+                                  fields=dict(fields)))
+
+    lg.Logger._emit = ring_only
+    yield
+    lg.Logger._emit = emit

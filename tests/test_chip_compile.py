@@ -135,22 +135,56 @@ def test_rs_shard_map_compiles_on_2x2(mesh, B, k, m):
         assert coll not in txt
 
 
+def _hh256_args(sharding_of, B, L):
+    n, rem = divmod(L, 32)
+    shapes = (((B, n, 8), 3), ((B, 8), 2), ((16, 2), 0))
+    return n, rem, [jax.ShapeDtypeStruct(shape, jnp.uint32,
+                                         sharding=sharding_of(rows))
+                    for shape, rows in shapes]
+
+
+def _assert_packet_loop_is_one_kernel(txt: str, n: int):
+    """The Mosaic kernel is in, and no HLO loop runs once per packet:
+    the only `while` left is the ten finalisation rounds."""
+    assert "tpu_custom_call" in txt
+    assert txt.count(" while(") <= 1
+    assert f"constant({n})" not in txt  # the parent's loop bound
+
+
 @pytest.mark.parametrize("B,L", [(16, _shard_len(8)),
                                  (16, _shard_len(12)),
                                  (128, MiB // 8),
-                                 (64, -(-MiB // 12))])
+                                 (64, -(-MiB // 12)),
+                                 (8, _shard_len(4)),
+                                 (256, MiB // 8),
+                                 (1, 32 * 3)])
 def test_hh256_compiles_for_v5e(one_chip, B, L):
     """Device HighwayHash at real bitrot sub-block lengths: 8+4's
     1310720 (len % 32 == 0) and 12+4's 873814 (len % 32 == 22, the
-    in-kernel remainder packet) at the 10 MiB block; 131072 and 87382
-    (len % 32 == 22) at the 1 MiB block."""
+    remainder packet; 27306 packets, not a multiple of the block) at
+    the 10 MiB block; 131072 and 87382 (len % 32 == 22) at the 1 MiB
+    block; 4+2's 2621440 (81920 packets); 256 rows (two lane tiles);
+    one row of fewer packets than the unroll. Each build holds the
+    Pallas kernel and no per-packet `while`."""
     from minio_tpu.ops import hh256_tpu
-    n, rem = divmod(L, 32)
-    w = jax.ShapeDtypeStruct((B, n, 8), jnp.uint32, sharding=one_chip)
-    rp = jax.ShapeDtypeStruct((B, 8), jnp.uint32, sharding=one_chip)
-    init = tuple(jax.ShapeDtypeStruct((4,), jnp.uint32,
-                                      sharding=one_chip)
-                 for _ in range(8))
-    c = hh256_tpu.hh256_rows.lower(
-        w, rp, init, n_packets=n, rem=rem).compile()
+    n, rem, args = _hh256_args(lambda rows: one_chip, B, L)
+    c = hh256_tpu.hh256_rows.lower(*args, n_packets=n, rem=rem).compile()
     assert c.memory_analysis().argument_size_in_bytes >= B * n * 32
+    _assert_packet_loop_is_one_kernel(c.as_text(), n)
+
+
+def test_hh256_shard_map_compiles_on_2x2(mesh):
+    """The serving-mesh form of hash_chunks: rows over all four chips,
+    one local kernel each, no collectives."""
+    from minio_tpu.ops import hh256_tpu
+    from minio_tpu.parallel.mesh import replicated, rows_sharding
+    B = 16
+    n, rem, args = _hh256_args(
+        lambda rows: rows_sharding(mesh, B, rows) if rows
+        else replicated(mesh), B, _shard_len(12))
+    txt = hh256_tpu.hh256_rows.lower(
+        *args, n_packets=n, rem=rem, mesh=mesh).compile().as_text()
+    _assert_packet_loop_is_one_kernel(txt, n)
+    for coll in ("all-gather", "all-reduce", "collective-permute",
+                 "all-to-all"):
+        assert coll not in txt

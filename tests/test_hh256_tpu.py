@@ -49,6 +49,68 @@ def test_kernel_unaligned_lengths(L):
     assert np.array_equal(got, want)
 
 
+def _kernel_digests(monkeypatch, chunks, key):
+    """Digests through hh256_rows' own pipeline with the Pallas kernel
+    (interpret mode) standing where the platform's form would: what a
+    TPU runs, minus Mosaic. Blocks of 8 packets, so a few hundred
+    bytes cross block edges."""
+    import functools
+
+    import jax
+    monkeypatch.setattr(hh256_tpu, "_BLOCK_PACKETS", 8)
+    monkeypatch.setattr(hh256_tpu, "_absorb_xla", functools.partial(
+        hh256_tpu._absorb_pallas, interpret=True))
+    B, L = chunks.shape
+    n, rem = divmod(L, 32)
+    words = chunks[:, :n * 32].copy().view(np.uint32).reshape(B, n, 8)
+    rem_packet = (hh256_tpu._pack_remainder(chunks[:, n * 32:], rem)
+                  if rem else np.zeros((B, 8), np.uint32))
+    fn = jax.jit(functools.partial(hh256_tpu._digest_rows,
+                                   n_packets=n, rem=rem))
+    out = np.ascontiguousarray(
+        fn(words, rem_packet, hh256_tpu._init_state_np(key)))
+    return out.view(np.uint8).reshape(B, 32)
+
+
+# (rows, n_packets, rem) with blocks of 8 packets and an unroll of 4:
+# fewer packets than one block (3, 5), exactly one packet, exactly one
+# block (8), two whole blocks (16), blocks + a tail that is a multiple
+# of the unroll (12 = 8 + 4), + an odd tail (21 = 16 + 4 + 1; 9 = 8 + 1);
+# 130 rows is two lane tiles, the second nearly empty.
+@pytest.mark.parametrize("B,n,rem", [
+    (1, 3, 0), (4, 8, 22), (8, 21, 31), (12, 16, 0), (16, 21, 22),
+    (128, 5, 31), (130, 9, 0), (16, 1, 0), (4, 12, 31)])
+def test_pallas_kernel_digests_match_reference(monkeypatch, B, n, rem):
+    rng = np.random.default_rng(B * 10007 + n * 101 + rem)
+    chunks = rng.integers(0, 256, (B, n * 32 + rem)).astype(np.uint8)
+    got = _kernel_digests(monkeypatch, chunks, MAGIC_KEY)
+    for b in range(B):
+        assert got[b].tobytes() == hh256(chunks[b].tobytes()), b
+
+
+def test_pallas_kernel_magic_key_vector(monkeypatch):
+    """The reference's own golden vector (cmd/bitrot.go:31): HH-256 of
+    the first 100 decimals of pi under the zero key IS the magic key.
+    100 bytes = 3 packets in the kernel + a 4-byte remainder."""
+    from minio_tpu.ops.hh256 import PI_100_DECIMALS
+    data = np.frombuffer(PI_100_DECIMALS.encode(), np.uint8)[None, :]
+    got = _kernel_digests(monkeypatch, data, b"\x00" * 32)
+    assert got[0].tobytes() == MAGIC_KEY
+
+
+def test_kernel_info_is_set_at_the_first_dispatch(monkeypatch):
+    """`hh256_kernel_info{impl}` and kernel_report() say which form of
+    the packet loop the process built: "xla" off the TPU."""
+    from minio_tpu.obs.metrics2 import METRICS2
+    monkeypatch.setattr(hh256_tpu, "_kernel_impl", [])
+    assert hh256_tpu.kernel_report() == {"kernel": ""}
+    hh256_tpu.hash_chunks(np.zeros((2, 64), np.uint8))
+    assert hh256_tpu.kernel_report() == {"kernel": "xla"}
+    assert METRICS2.get("minio_tpu_v2_hh256_kernel_info",
+                        {"impl": "xla"}) == 1
+    assert "minio_tpu_v2_hh256_kernel_info" in METRICS2.snapshot()
+
+
 def test_kernel_rejects_empty():
     with pytest.raises(ValueError):
         hh256_tpu.hash_chunks(np.zeros((2, 0), np.uint8))
