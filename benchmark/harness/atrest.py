@@ -1,13 +1,22 @@
-"""What the window's PUTs left on the drives, against the plain
-reference: every shard file of a sample of objects, frame by frame
-([32-byte HighwayHash-256][sub-block] per stripe block), data and
-parity alike. A healthy GET never reads parity and trusts the stored
-digests, so only this sees a wrong encode or a wrong digest.
+"""What the window's PUTs (or, where it writes nothing, the preload's)
+left on the drives, against the plain reference: every shard file of a
+sample of objects, frame by frame ([32-byte HighwayHash-256][sub-block]
+per stripe block), data and parity alike. A healthy GET never reads
+parity and trusts the stored digests, so only this sees a wrong encode
+or a wrong digest.
 
 Reads the drives as files, after the server has stopped. From the
 program's xl.meta it takes only where it put things (the data
 directory and which shard index a drive holds); every byte compared
-comes from the reference."""
+comes from the reference.
+
+Drives come in `sets` erasure sets of equal size, in launch order; an
+object lives in one of them, and a copy of it in another is a copy
+that should not be (counted as missing, as a hole in its own set is).
+On a drive the traffic mix's fault took out (`lost`, positions in
+`drives`) a copy is expected absent: one found there is
+`lost_copies_present`, and the drive is left out of
+`shard_files_missing`."""
 
 from __future__ import annotations
 
@@ -32,29 +41,57 @@ def sample(objects: list[tuple[str, int, int]], n: int, seed: int
     return [biggest] + rng.sample(rest, n - 1)
 
 
+def _placed(drive: str, bucket: str, key: str) -> tuple[int, str] | None:
+    """(1-based shard index, data directory) of the newest version in
+    this drive's xl.meta of the key, or None."""
+    try:
+        with open(os.path.join(drive, bucket, key, "xl.meta"), "rb") as f:
+            ver = json.load(f)["versions"][0]
+        return int(ver["erasure"]["index"]), str(ver["dataDir"])
+    except (OSError, KeyError, IndexError, ValueError, TypeError):
+        return None
+
+
 def _stored(drive: str, bucket: str, key: str, part: int
             ) -> tuple[int, bytes] | None:
     """(1-based shard index, shard file) this drive holds, or None."""
-    base = os.path.join(drive, bucket, key)
-    try:
-        with open(os.path.join(base, "xl.meta"), "rb") as f:
-            ver = json.load(f)["versions"][0]
-        with open(os.path.join(base, ver["dataDir"], f"part.{part}"),
-                  "rb") as f:
-            return int(ver["erasure"]["index"]), f.read()
-    except (OSError, KeyError, IndexError, ValueError):
+    placed = _placed(drive, bucket, key)
+    if placed is None:
         return None
+    try:
+        with open(os.path.join(drive, bucket, key, placed[1],
+                               f"part.{part}"), "rb") as f:
+            return placed[0], f.read()
+    except OSError:
+        return None
+
+
+def lost_data_shards(drives: list[str], bucket: str, key: str, k: int,
+                     lost: frozenset[int]) -> int:
+    """How many of an object's k data shards sat on the `lost` drives:
+    the indices 1..k that no kept drive's xl.meta names (0 where no kept
+    drive knows the key)."""
+    if not lost:
+        return 0
+    kept = {placed[0] for i, d in enumerate(drives) if i not in lost
+            and (placed := _placed(d, bucket, key)) is not None}
+    return sum(1 for idx in range(1, k + 1) if idx not in kept) \
+        if kept else 0
 
 
 def check(drives: list[str], bucket: str,
           objects: list[tuple[str, int, int]], body_of, k: int, m: int,
-          block: int, part_size: int = 0) -> dict:
+          block: int, part_size: int = 0, sets: int = 1,
+          lost: frozenset[int] = frozenset()) -> dict:
     """Counts of what differs from the reference over `objects`.
     `body_of(size, off)` gives an object's bytes; `part_size` > 0 for
-    objects written as multipart uploads of that part size."""
+    objects written as multipart uploads of that part size; `lost` the
+    positions in `drives` the mix's fault took out."""
     out = {"objects_checked": 0, "shard_files_checked": 0,
            "frames_checked": 0, "shard_files_missing": 0,
-           "shard_frames_differ": 0, "digest_frames_differ": 0}
+           "shard_frames_differ": 0, "digest_frames_differ": 0,
+           "lost_copies_present": 0}
+    per_set = len(drives) // sets
     parts_of, blocks_of = [], []
     for key, size, off in objects:
         body = body_of(size, off)
@@ -66,9 +103,19 @@ def check(drives: list[str], bucket: str,
     digests_of = reference.digests_for(blocks_of)
     for (key, pn), blocks, digests in zip(parts_of, blocks_of, digests_of):
         want = reference.shard_files(blocks, digests)
+        stored = [_stored(d, bucket, key, pn) for d in drives]
+        held = [sum(1 for i in range(s * per_set, (s + 1) * per_set)
+                    if i not in lost and stored[i] is not None)
+                for s in range(sets)]
+        home = held.index(max(held))
         seen: set[int] = set()
-        for d in drives:
-            got = _stored(d, bucket, key, pn)
+        for i, got in enumerate(stored):
+            if i in lost:
+                out["lost_copies_present"] += got is not None
+                continue
+            if i // per_set != home:
+                out["shard_files_missing"] += got is not None
+                continue
             if got is None or not 1 <= got[0] <= k + m or got[0] in seen:
                 out["shard_files_missing"] += 1
                 continue

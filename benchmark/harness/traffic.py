@@ -16,12 +16,16 @@ File (all sizes in bytes):
                   # > 0: this group offers that many operations a second
                   # whatever the loop (due times from the window's opening)
         "range_bytes": 65536, "part_size": 5242880}],
-     "preload": {"per_client": 0},       # ring slots filled before warm-up
+     "preload": {"per_client": 0},       # operations of its stream a client
+                                         # runs before the warm-up; a group
+                                         # that never writes fills that many
+                                         # ring slots with them
      "verify": {"at_rest_sample": 6},
      "trace_slice_s": 0.5,               # the traced slice, the window's
                                          # last (harness/trace_reduce.py
                                          # says what a second of it costs)
-     "faults": {"remove_drive_copies": [2, 5], "wipe_drive": n, "heal": true},
+     "faults": {"offline_drives": [2, 5]},   # FAULTS below; run.py's
+                                         # apply_faults says what each does
      "rehearse": {...}}                  # overrides for --rehearse
 
 Operations: PUT GET HEAD DELETE RANGE MULTIPART. Every seed gives each
@@ -38,6 +42,9 @@ from dataclasses import dataclass, field
 
 OPS = ("PUT", "GET", "HEAD", "DELETE", "RANGE", "MULTIPART")
 WRITES = ("PUT", "MULTIPART")
+FAULTS = ("offline_drives", "remove_object_copies", "remove_drive_copies",
+          "wipe_drive", "heal")   # drives are numbered from 1, as d<i>
+LOST_DRIVE_FAULTS = FAULTS[:2]    # leave a drive without copies for good
 OFFSET_SPAN = 1 << 20      # bodies are base[off:off+size], off < this
 
 
@@ -63,6 +70,10 @@ class Group:
     def kinds(self) -> list[str]:
         return list(dict.fromkeys(self.sequence or self.weights))
 
+    @property
+    def writes(self) -> bool:
+        return any(k in WRITES for k in self.kinds)
+
 
 @dataclass
 class Traffic:
@@ -78,6 +89,12 @@ class Traffic:
     @property
     def max_size(self) -> int:
         return max(max(g.sizes) for g in self.groups)
+
+    @property
+    def writes(self) -> bool:
+        """Whether the window itself can write (a read with nothing to
+        read turns into a PUT, but a preload leaves none such)."""
+        return any(g.writes for g in self.groups)
 
 
 def _need(cond: bool, what: str) -> None:
@@ -135,13 +152,16 @@ def parse(name: str, doc: dict, rehearse: bool = False) -> Traffic:
             _need(grp.rate_per_s > 0, "an open loop needs rate_per_s")
         groups.append(grp)
     _need(bool(groups), "a traffic mix needs at least one group")
+    faults = dict(doc.get("faults", {}))
+    for key in faults:
+        _need(key in FAULTS, f"unknown fault {key}: one of {FAULTS}")
     return Traffic(
         name=name, loop=loop, timeout_s=float(doc.get("timeout_s", 120)),
         groups=groups,
         preload_per_client=int(doc.get("preload", {}).get("per_client", 0)),
         at_rest_sample=int(doc.get("verify", {}).get("at_rest_sample", 6)),
         trace_slice_s=float(doc.get("trace_slice_s", 0.5)),
-        faults=dict(doc.get("faults", {})))
+        faults=faults)
 
 
 def load(path: str, name: str, rehearse: bool = False) -> Traffic:
@@ -185,11 +205,15 @@ class _Zipf:
 class ClientStream:
     """The operations of one client, in order, from (seed, group,
     client). `written` is the driver's record of which ring slots hold
-    an object (preload and acknowledged PUTs); reads pick from it."""
+    an object (preload and acknowledged PUTs); reads pick from it.
+    `fill`: in a group that never writes, a read turns into the PUT of
+    the next ring slot until that many slots hold an object (the
+    preload of a read-only window)."""
 
-    def __init__(self, seed: int, group: Group, client: int):
+    def __init__(self, seed: int, group: Group, client: int, fill: int = 0):
         self.group = group
         self.client = client
+        self._fill = 0 if group.writes else min(fill, group.ring)
         self.rng = random.Random(sub_seed(seed, group.name, client))
         self._sizes: list[int] = []
         self._slot = 0
@@ -245,7 +269,7 @@ class ClientStream:
             return Op(kind, key, self.next_size(),
                       self.rng.randrange(OFFSET_SPAN), self._due)
         key = self._read_key()
-        if key is None:
+        if key is None or len(self.written) < self._fill:
             # Nothing to read yet: write first, as a client would.
             key = self.key(self._slot % g.ring)
             self._slot += 1
@@ -255,7 +279,7 @@ class ClientStream:
 
 
 def streams(seed: int, traffic: Traffic) -> list[ClientStream]:
-    return [ClientStream(seed, g, c)
+    return [ClientStream(seed, g, c, traffic.preload_per_client)
             for g in traffic.groups for c in range(g.clients)]
 
 

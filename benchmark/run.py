@@ -8,8 +8,9 @@ It boots the real server as a child that owns the chip
 (harness/server_child.py), waits for the codec plan's probe, makes the
 bucket, preloads and warms up (set-up), opens the window for --seconds,
 lets the operations in flight finish, reads the child's counters, stops
-the child (rc 0), compares what the window's PUTs left on the drives
-with the plain reference, and prints one JSON object as its last line.
+the child (rc 0), compares what the window's PUTs (the preload's, where
+the window writes nothing) left on the drives with the plain reference,
+and prints one JSON object as its last line.
 
 Everything a cell is made of is data found by name from BENCHMARK.json:
 configs/<config>.json, traffic/<traffic>.json, metrics/<metric>.json
@@ -45,6 +46,8 @@ GiB = 1 << 30
 BUCKET = "bench"
 DEVICE_SOURCES = ("device_trace",)
 DEVICE_BYTES = "minio_tpu_v2_kernel_backend_bytes_total"
+PHASE_COUNT = "minio_tpu_v2_request_phase_ms_count"
+DECODED = {"api": "GET-object", "phase": "ec.decode"}
 
 
 def say(msg: str) -> None:
@@ -102,16 +105,47 @@ def cpu_seconds() -> float:
     return ru.ru_utime + ru.ru_stime
 
 
+def lost_drives(faults: dict) -> frozenset[int]:
+    """Positions (0-based) of the drives on which the mix's faults leave
+    a copy expected ABSENT until the child stops."""
+    return frozenset(int(d) - 1 for key in traffic_mod.LOST_DRIVE_FAULTS
+                     for d in faults.get(key, []))
+
+
 def apply_faults(faults: dict, srv: Server) -> None:
-    """Set-up faults of a traffic mix: drive copies removed, a drive
-    wiped. A timed admin heal needs the recovery-time end-to-end metric
-    (a later benchmark PR); asking for it here is an error, not a
-    silent skip."""
-    if not faults:
-        return
+    """Set-up faults of a traffic mix (harness/traffic.py FAULTS), after
+    the preload and before the warm-up.
+
+    offline_drives        the drive's root moved aside, a regular file
+                          in its place: every storage call on it fails
+                          as a dead drive's does, nothing can be healed
+                          onto it. A lost drive.
+    remove_object_copies  every entry under <drive>/<bucket>/ removed,
+                          the bucket volume kept: the server's new-disk
+                          monitor sees nothing to heal. A lost drive.
+    remove_drive_copies   <drive>/<bucket> removed, volume and all: the
+                          state of a REPLACED drive, which the server's
+                          new-disk monitor heals within its interval
+                          (10 s) plus the rebuild. Not a lost drive.
+    wipe_drive            everything but .minio.sys removed. Likewise.
+
+    A timed admin heal needs the recovery-time end-to-end metric (a
+    later benchmark PR); asking for it here is an error, not a silent
+    skip."""
     if faults.get("heal"):
         raise traffic_mod.TrafficError(
             "faults.heal needs the recovery-time metric: not built yet")
+    for d in faults.get("offline_drives", []):
+        root = srv.drive(int(d))
+        aside = os.path.join(srv.work, "offline")
+        os.makedirs(aside, exist_ok=True)
+        os.rename(root, os.path.join(aside, os.path.basename(root)))
+        with open(root, "wb"):
+            pass
+    for d in faults.get("remove_object_copies", []):
+        vol = os.path.join(srv.drive(int(d)), BUCKET)
+        for name in os.listdir(vol):
+            shutil.rmtree(os.path.join(vol, name))
     for d in faults.get("remove_drive_copies", []):
         shutil.rmtree(os.path.join(srv.drive(int(d)), BUCKET),
                       ignore_errors=True)
@@ -120,6 +154,21 @@ def apply_faults(faults: dict, srv: Server) -> None:
         for name in os.listdir(root):
             if name != ".minio.sys":
                 shutil.rmtree(os.path.join(root, name), ignore_errors=True)
+
+
+def degraded_reads(log, drives: list[str], k: int,
+                   lost: frozenset[int]) -> int:
+    """The window's successful GETs of objects that lost a data shard
+    to the fault: each had to reconstruct."""
+    hit: dict[str, bool] = {}
+    had_to = 0
+    for r in log if lost else ():
+        if r.ok and r.kind in ("GET", "RANGE"):
+            if r.key not in hit:
+                hit[r.key] = atrest.lost_data_shards(
+                    drives, BUCKET, r.key, k, lost) > 0
+            had_to += hit[r.key]
+    return had_to
 
 
 def trace_slice(srv: Server, scrape, trace_dir: str, seconds: float, mix):
@@ -175,6 +224,7 @@ def run(args, child: str = CHILD) -> int:
     summary: dict = {}
     ctx: dict = {}
     at_rest: dict = {}
+    lost = lost_drives(mix.faults)
     fault = ""
     try:
         # -- set-up ------------------------------------------------------
@@ -265,11 +315,16 @@ def run(args, child: str = CHILD) -> int:
             slice_rec["stop"] = stop
             say(f"trace stop answered after "
                 f"{time.monotonic() - slice_rec['t_stop_sent']:.1f} s")
+        # The child closes a request's span tree just after the client has
+        # its last byte: the count of GETs that decoded is read here, some
+        # admin calls later, not in the scrape that ends the window.
+        settled = scrape()
         admin.close()
         record.update(plan_after=plan_after, memory=mem,
                       plan_changed=plan_after != record["plan"])
         ctx = {
-            "before": before, "after": after, "summary": summary,
+            "before": before, "after": after, "settled": settled,
+            "summary": summary,
             "config": config, "device": dev, "notes": {},
             "slice": ({"before": slice_rec["before"],
                        "after": slice_rec["after"],
@@ -306,7 +361,10 @@ def run(args, child: str = CHILD) -> int:
 
     # -- after the window: the drives against the reference ---------------
     t0 = time.monotonic()
-    candidates = [(k, *expect.last[k]) for k in sorted(expect.in_window)
+    # What the window wrote; where it writes nothing, what its reads can
+    # reach (the preload's objects).
+    keys = expect.in_window if mix.writes else expect.last
+    candidates = [(k, *expect.last[k]) for k in sorted(keys)
                   if expect.last.get(k) is not None]
     chosen = atrest.sample(candidates, mix.at_rest_sample, args.seed)
     drives = [srv.drive(i) for i in range(1, config["drives"] + 1)]
@@ -314,7 +372,13 @@ def run(args, child: str = CHILD) -> int:
     at_rest = atrest.check(drives, BUCKET, chosen, expect.body,
                            config["data"], config["parity"],
                            config["block_size"],
-                           part_size=multipart.pop() if multipart else 0)
+                           part_size=multipart.pop() if multipart else 0,
+                           sets=config.get("sets", 1), lost=lost)
+    # One-sided: a hedged read may reconstruct where it need not.
+    at_rest["degraded_reads"] = degraded_reads(log, drives, config["data"],
+                                               lost)
+    at_rest["reads_decoded"] = int(prom.delta(
+        ctx["before"], ctx["settled"], PHASE_COUNT, DECODED))
     at_rest["seconds"] = time.monotonic() - t0
     at_rest["bytes"] = sum(o[1] for o in chosen)
     say(f"at rest: {json.dumps(at_rest)}")
@@ -379,6 +443,9 @@ def run(args, child: str = CHILD) -> int:
         "shard_files_missing": [at_rest["shard_files_missing"], 0],
         "shard_frames_differ": [at_rest["shard_frames_differ"], 0],
         "digest_frames_differ": [at_rest["digest_frames_differ"], 0],
+        "lost_copies_present": [at_rest["lost_copies_present"], 0],
+        "degraded_reads_not_decoded": [
+            max(0, at_rest["degraded_reads"] - at_rest["reads_decoded"]), 0],
         "at_rest_objects_unchecked": [
             max(0, wanted - at_rest["objects_checked"])
             + (0 if wanted else 1), 0],

@@ -11,6 +11,12 @@ which decodes perfectly well against itself), a digest under another
 key, a digest truncated to the cheaper 64-bit HighwayHash and padded.
 Run on the chip at the cells' own sizes by sets of
 `python benchmark/tests/test_control.py <cell> <seed>...` (PERF.md §2).
+
+A cell with lost drives (`ec8p4_get_2lost`) adds a GET by the reference
+from the drives that are left: with data shards among the lost, the one
+read path on which a parity matrix of another construction also gives
+wrong BYTES (`get_wrong_bytes`), since the reader rebuilds the data with
+the reference's matrix from parity made with another.
 """
 
 import json
@@ -32,10 +38,40 @@ def cauchy_rows(k, m):
             for i in range(m)]
 
 
+def degraded_get(drives, bucket, key, size, k, m, block, lost):
+    """The object's bytes as the reference reads them from the drives
+    not in `lost` (positions): the first k shard files left, frames
+    stripped, the data rows rebuilt with the reference's own matrix."""
+    full = [[int(i == j) for j in range(k)] for i in range(k)] \
+        + reference.parity_rows(k, m)
+    have = {}
+    for i, d in enumerate(drives):
+        got = None if i in lost else atrest._stored(d, bucket, key, 1)
+        if got is not None and len(have) < k:
+            have[got[0] - 1] = got[1]
+    keep = sorted(have)
+    inv = reference._mat_inv([full[i] for i in keep])
+    out, pos = [], 0
+    for lo in range(0, size, block):
+        n = -(-min(block, size - lo) // k)
+        rows = [np.frombuffer(have[i], np.uint8, n, pos + 32) for i in keep]
+        pos += 32 + n
+        data = np.zeros((k, n), np.uint8)
+        for r in range(k):
+            for c in range(k):
+                if inv[r][c]:
+                    data[r] ^= reference._mul_table(inv[r][c])[rows[c]]
+        out.append(data.reshape(-1)[:min(block, size - lo)].tobytes())
+    return b"".join(out)
+
+
 def write_tree(root, bucket, key, body, k, m, block, broken=None,
                skip_drive=None):
     """The drive tree a PUT leaves, made by the reference (optionally
-    with one guarantee broken): drive d<i> holds shard index i."""
+    with one guarantee broken): drive d<i> holds shard index i.
+    `skip_drive`: a drive number, or several, left without its copy."""
+    skip = {skip_drive} if isinstance(skip_drive, int) else \
+        set(skip_drive or ())
     rows = cauchy_rows(k, m) if broken == "cauchy_parity" else None
     view = memoryview(body)
     blocks = [reference.rs_encode_block(view[o:o + block], k, m, rows)
@@ -53,7 +89,7 @@ def write_tree(root, bucket, key, body, k, m, block, broken=None,
     for i, data in enumerate(files, start=1):
         d = os.path.join(root, f"d{i}")
         drives.append(d)
-        if i == skip_drive:
+        if i in skip:
             os.makedirs(d, exist_ok=True)
             continue
         base = os.path.join(d, bucket, key)
@@ -66,15 +102,28 @@ def write_tree(root, bucket, key, body, k, m, block, broken=None,
     return drives
 
 
-def read_control(tmp, k, m, block, sizes, seed, broken, skip_drive=None):
+def read_control(tmp, k, m, block, sizes, seed, broken, skip_drive=None,
+                 lost=()):
+    """`lost`: drive NUMBERS the cell's fault takes out. The tree is
+    written without them (unless `skip_drive` says otherwise: the fault
+    stated and not applied), the comparison is told of them, and every
+    object is read back degraded."""
     base = traffic.base_buffer(seed, max(sizes))
     objects = [(f"o{i}", n, 17 * i + 3) for i, n in enumerate(sizes)]
+    if lost and skip_drive is None:
+        skip_drive = lost
     for key, n, off in objects:
         drives = write_tree(tmp, "bench", key, base[off:off + n], k, m,
                             block, broken, skip_drive)
-    return atrest.check(drives, "bench", objects,
-                        lambda n, off: memoryview(base)[off:off + n],
-                        k, m, block)
+    gone = frozenset(d - 1 for d in lost)
+    got = atrest.check(drives, "bench", objects,
+                       lambda n, off: memoryview(base)[off:off + n],
+                       k, m, block, lost=gone)
+    if lost:
+        got["get_wrong_bytes"] = sum(
+            degraded_get(drives, "bench", key, n, k, m, block, gone)
+            != base[off:off + n] for key, n, off in objects)
+    return got
 
 
 SMALL = dict(k=4, m=2, block=1 << 16, sizes=[1 << 16, 150001, 40])
@@ -114,6 +163,34 @@ def test_missing_copy_is_not_correct(tmp_path):
     assert got["shard_files_missing"] == 3
 
 
+LOST = dict(SMALL, lost=(2, 5))      # 4+2: both lost shards are data
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3_000_000_019])
+def test_lost_drives_reference_in_the_programs_place_is_correct(tmp_path,
+                                                                seed):
+    got = read_control(str(tmp_path), seed=seed, broken=None, **LOST)
+    assert got["shard_files_checked"] == 12
+    assert [got[n] for n in ("shard_files_missing", "shard_frames_differ",
+                             "digest_frames_differ", "lost_copies_present",
+                             "get_wrong_bytes")] == [0] * 5
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3_000_000_019])
+def test_lost_drives_cauchy_parity_returns_wrong_bytes(tmp_path, seed):
+    got = read_control(str(tmp_path), seed=seed, broken="cauchy_parity",
+                       **LOST)
+    assert got["shard_frames_differ"] > 0
+    assert got["get_wrong_bytes"] == 3      # every object, read degraded
+
+
+def test_lost_drives_that_kept_their_copies_are_not_correct(tmp_path):
+    got = read_control(str(tmp_path), seed=5, broken=None, skip_drive=(),
+                       **LOST)
+    assert got["lost_copies_present"] == 6
+    assert got["shard_files_missing"] == 0
+
+
 if __name__ == "__main__":
     # On the chip's machine, at a cell's own sizes: the control's readings.
     import tempfile
@@ -123,7 +200,10 @@ if __name__ == "__main__":
                  sizes=[10485760, 26214400, 52441145]),
              "ec4p2_small_put_get": dict(
                  k=4, m=2, block=10 << 20,
-                 sizes=[1048576] * 8 + [5242880])}[cell]
+                 sizes=[1048576] * 8 + [5242880]),
+             "ec8p4_get_2lost": dict(
+                 k=8, m=4, block=10 << 20, sizes=[26214400] * 6,
+                 lost=(2, 5))}[cell]
     for seed in seeds:
         for broken in (None, "cauchy_parity", "zero_key_digest",
                        "short_digest"):

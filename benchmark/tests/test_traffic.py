@@ -70,12 +70,54 @@ def test_what_no_cell_uses_yet_parses_and_generates():
     mix = traffic.parse("warp_mixed_open", doc)
     assert mix.loop == "open" and mix.preload_per_client == 32
     assert mix.faults == {"remove_drive_copies": [2, 5]}
+    assert mix.writes
     got = ops(11, mix, 200)
     assert {o[2] for o in got} >= {"GET", "HEAD", "PUT"}
     s = traffic.ClientStream(11, mix.groups[0], 0)
     dues = [s.next().due_s for _ in range(400)]
     assert dues == sorted(dues)
     assert dues[-1] / 400 == pytest.approx(4 / 200, rel=0.25)
+
+
+def test_fault_keys_and_a_window_that_writes_nothing():
+    doc = {"groups": [{"clients": 2, "sequence": ["GET"],
+                       "sizes": {"cycle": [4096]}, "keys": {"ring": 2},
+                       "read": "ring"}],
+           "preload": {"per_client": 2}}
+    for key in traffic.FAULTS:
+        mix = traffic.parse("m", dict(doc, faults={key: [2, 5]}))
+        assert mix.faults == {key: [2, 5]} and not mix.writes
+    with pytest.raises(traffic.TrafficError, match="unknown fault"):
+        traffic.parse("m", dict(doc, faults={"unplug_drive": [2]}))
+
+
+def test_degraded_mix_is_the_cell_the_issue_names():
+    mix = traffic.load(os.path.join(os.path.dirname(HERE), "traffic",
+                                    "get_2lost.json"), "get_2lost")
+    (g,) = mix.groups
+    assert (g.clients, g.ring, g.sequence, g.read) == (4, 4, ["GET"], "ring")
+    assert g.sizes == [25 << 20] and mix.preload_per_client == 4
+    assert mix.faults == {"remove_object_copies": [2, 5]}
+    assert not mix.writes and mix.at_rest_sample == 6
+    # The preload of a group that never writes fills its ring: 4 PUTs of
+    # 4 slots a client, 16 objects, and reads only from then on.
+    import zlib
+    split = [0, 0, 0]
+    for s in traffic.streams(7, mix):
+        puts = [s.next() for _ in range(mix.preload_per_client)]
+        assert [o.kind for o in puts] == ["PUT"] * 4
+        assert [o.key for o in puts] == [s.key(i) for i in range(4)]
+        for o in puts:
+            s.written[o.key] = o.size
+        assert {s.next().kind for _ in range(40)} == {"GET"}
+        # Key names do not depend on the seed, and shard indices rotate
+        # by crc32 of bucket/key: the split of the 16 objects by data
+        # shards lost to drives 2 and 5 is the same on every seed.
+        for key in s.written:
+            start = zlib.crc32(f"bench/{key}".encode()) % 12
+            order = [1 + (start + i) % 12 for i in range(1, 13)]
+            split[sum(order[d - 1] <= 8 for d in (2, 5))] += 1
+    assert split == [1, 8, 7]
 
 
 @pytest.mark.parametrize("bad", [
