@@ -794,10 +794,21 @@ class Run:
         self.check(plan["hhKernel"]["kernel"] == "pallas",
                    f"the HH256 packet loop that ran is the Pallas "
                    f"kernel ({json.dumps(plan['hhKernel'])})")
+        # The census counts once: what the devices hold in all is the
+        # batches' bytes plus what an axis left replicated repeats.
         m = metrics(c)
-        for placement in ("sharded", "single"):
-            say(f"  hh256 dispatches {placement}: "
-                f"{int(msum(m, 'minio_tpu_v2_hh256_mesh_dispatches_total', placement=placement))}")
+        held = msum(m, "minio_tpu_v2_mesh_device_bytes_total")
+        sent = msum(m, "minio_tpu_v2_mesh_dispatch_bytes_total")
+        repeated = msum(m, "minio_tpu_v2_mesh_dispatch_bytes_total",
+                        placement="replicated")
+        for kernel, census in sorted(aff["kernels"].items()):
+            say(f"  {kernel} placements: "
+                f"{json.dumps(census['placements'])}")
+        self.check(sent <= held <= sent + 3 * repeated and sent > 0,
+                   f"the devices hold the dispatched bytes once, plus "
+                   f"at most 3 copies of what was left replicated "
+                   f"(held {int(held)}, dispatched {int(sent)}, "
+                   f"replicated {int(repeated)})")
 
 
 class NoAccelerator(Exception):

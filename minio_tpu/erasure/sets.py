@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import uuid as uuidlib
 
+from ..obs.metrics2 import METRICS2
+from ..obs.span import TRACER
 from ..parallel.quorum import parallel_map
 from ..storage.interface import StorageAPI
 from ..utils.siphash import sip_hash_mod
@@ -32,6 +34,39 @@ def fan_out_bucket_op(targets: list, op_name: str, benign: type,
         raise real[0]
     if errs and all(isinstance(e, benign) for e in errs):
         raise errs[0]
+
+
+SET_BYTES = "minio_tpu_v2_erasure_set_bytes_total"
+
+
+class _CountedStream:
+    """A GET's chunk iterator that adds the bytes it handed on to the
+    set's count once it is exhausted (a stream cut short counts
+    nothing); close() passes through to the engine's locked stream."""
+
+    def __init__(self, stream, labels: dict):
+        self._stream = stream
+        self._labels = labels
+        self._n = 0
+
+    def __iter__(self):
+        return self
+
+    def __next__(self) -> bytes:
+        try:
+            chunk = next(self._stream)
+        except StopIteration:
+            if self._labels is not None:
+                METRICS2.inc(SET_BYTES, self._labels, self._n)
+                self._labels = None
+            raise
+        self._n += len(chunk)
+        return chunk
+
+    def close(self) -> None:
+        close = getattr(self._stream, "close", None)
+        if close is not None:
+            close()
 
 
 class ErasureSets:
@@ -60,6 +95,14 @@ class ErasureSets:
 
     def set_for(self, object_name: str) -> ErasureObjects:
         return self.sets[self.set_index(object_name)]
+
+    def _route(self, object_name: str, op: str,
+               ) -> tuple[ErasureObjects, dict]:
+        """The set a PUT body or a GET stream goes to, named on the
+        request's root span, and the labels its bytes count under."""
+        idx = self.set_index(object_name)
+        TRACER.tag_root(set=idx)
+        return self.sets[idx], {"set": str(idx), "op": op}
 
     def shutdown(self) -> None:
         """Stop every set's background daemons (see
@@ -100,23 +143,31 @@ class ErasureSets:
                    versioned: bool = False,
                    parity_shards: int | None = None,
                    algorithm: str | None = None) -> ObjectInfo:
-        return self.set_for(object_name).put_object(
+        target, labels = self._route(object_name, "put")
+        info = target.put_object(
             bucket, object_name, data, metadata=metadata,
             versioned=versioned, parity_shards=parity_shards,
             algorithm=algorithm)
+        METRICS2.inc(SET_BYTES, labels, info.size)
+        return info
 
     def get_object(self, bucket: str, object_name: str, offset: int = 0,
                    length: int = -1, version_id: str = ""):
-        return self.set_for(object_name).get_object(
+        target, labels = self._route(object_name, "get")
+        data, info = target.get_object(
             bucket, object_name, offset=offset, length=length,
             version_id=version_id)
+        METRICS2.inc(SET_BYTES, labels, len(data))
+        return data, info
 
     def get_object_stream(self, bucket: str, object_name: str,
                           offset: int = 0, length: int = -1,
                           version_id: str = ""):
-        return self.set_for(object_name).get_object_stream(
+        target, labels = self._route(object_name, "get")
+        info, stream = target.get_object_stream(
             bucket, object_name, offset=offset, length=length,
             version_id=version_id)
+        return info, _CountedStream(stream, labels)
 
     def get_object_info(self, bucket: str, object_name: str,
                         version_id: str = "") -> ObjectInfo:

@@ -50,6 +50,9 @@ class MetricsV2:
         self._cap_labels: dict[str, dict[str, int]] = {}
         # (name, label) -> distinct values admitted so far
         self._cap_seen: dict[tuple[str, str], set] = {}
+        # name -> fn() -> [(labels, value)]: series read from their
+        # owner at scrape (see collect()).
+        self._collectors: dict = {}
         self._specs[_OVERFLOW] = (
             "counter",
             "Capped-label values folded into _other by the "
@@ -83,6 +86,15 @@ class MetricsV2:
                 raise ValueError(f"unregistered metric {name!r}")
             self._cap_labels.setdefault(name, {})[label] = \
                 max(1, int(cap))
+
+    def collect(self, name: str, fn) -> None:
+        """The series of `name` are READ at every snapshot from
+        `fn() -> [(labels, value), ...]`, not recorded here: the owner
+        keeps the one count (the mesh census, parallel/mesh.py), and
+        what it does not hold is not exported."""
+        with self._mu:
+            self._spec(name, ("counter", "gauge"))
+            self._collectors[name] = fn
 
     def registered_names(self) -> set[str]:
         with self._mu:
@@ -196,6 +208,13 @@ class MetricsV2:
 
     def snapshot(self) -> dict:
         with self._mu:
+            collectors = list(self._collectors.items())
+        # Outside the registry lock: an owner takes its own.
+        collected = {name: fn() for name, fn in collectors}
+        with self._mu:
+            for name, series in collected.items():
+                self._data[name] = {self._key(labels): v
+                                    for labels, v in series}
             if _PROCESS_CPU in self._specs:
                 # Read at scrape, not recorded: the process's own clock.
                 self._data[_PROCESS_CPU][self._key(None)] = \
@@ -383,10 +402,22 @@ METRICS2.register(
     "Bytes dispatched per kernel and dispatch backend "
     "(device/native/xla-cpu/host) — the timeline's GiB/s numerator.")
 METRICS2.register(
-    "minio_tpu_v2_hh256_mesh_dispatches_total", "counter",
-    "HighwayHash device dispatches on a multi-device serving mesh, by "
-    "placement: sharded (rows over every device) or single (a batch "
-    "that does not divide the mesh, whole on device 0).")
+    "minio_tpu_v2_mesh_device_bytes_total", "counter",
+    "Bytes each device of the serving mesh held for device dispatches, "
+    "by kernel and device index: a batch sharded n ways adds an n-th "
+    "to each device, an axis left replicated adds the whole to each, a "
+    "pinned batch the whole to one. Absent on a single device.")
+METRICS2.register(
+    "minio_tpu_v2_mesh_dispatch_bytes_total", "counter",
+    "Bytes of the batches dispatched onto the serving mesh, each "
+    "counted once, by kernel and placement: sharded (every axis that "
+    "is spread divides), pinned (whole on one device), replicated (an "
+    "axis left whole on several devices, which repeat each other's "
+    "work). Absent on a single device.")
+METRICS2.register(
+    "minio_tpu_v2_erasure_set_bytes_total", "counter",
+    "User bytes of successful PUT bodies and GET streams, by the "
+    "erasure set the object's key hashes to and op (put/get).")
 METRICS2.register(
     "minio_tpu_v2_hh256_kernel_info", "gauge",
     "1 for the form this process's device HighwayHash programs were "

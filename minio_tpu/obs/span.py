@@ -26,6 +26,7 @@ import os
 import sys
 import threading
 import time
+import weakref
 from collections import deque
 
 _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
@@ -128,7 +129,8 @@ class Span:
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "start",
                  "duration_ms", "tags", "children", "events", "dropped",
-                 "_t0", "_token", "_tracer", "_done", "_ann")
+                 "root", "_t0", "_token", "_tracer", "_done", "_ann",
+                 "__weakref__")
 
     _ids = itertools.count(1)    # next() is atomic: no lock on the hot path
 
@@ -144,6 +146,9 @@ class Span:
         self.children: list = []  # Span | dict (grafted remote spans)
         self.events: list = []    # point-in-time markers (QoS shed, ...)
         self.dropped = 0
+        # weakref to the trace's root span, handed down where a child
+        # attaches (a strong one would make every tree a cycle).
+        self.root = None
         self._t0 = time.perf_counter()
         self._token = None
         self._tracer = tracer
@@ -266,7 +271,9 @@ class Tracer:
         Span.__enter__/finish). None when tracing is disabled."""
         if not self.enabled:
             return None
-        return Span(name, trace_id, tags=tags or None, tracer=self)
+        root = Span(name, trace_id, tags=tags or None, tracer=self)
+        root.root = weakref.ref(root)
+        return root
 
     def span(self, name: str, parent: Span | None = None, **tags):
         """Child span context manager. Attaches to `parent` when given
@@ -278,8 +285,19 @@ class Tracer:
                 return _NOOP
         child = Span(name, parent.trace_id, parent.span_id,
                      tags=tags or None)
+        child.root = parent.root
         parent.add_child(child)
         return child
+
+    @staticmethod
+    def tag_root(**tags) -> None:
+        """Tags on the ROOT of the thread's current trace, from any
+        depth under it (the erasure set a key routed to: known three
+        layers below where the root was opened). No-op untraced."""
+        cur = _current.get()
+        root = cur.root() if cur is not None and cur.root else None
+        if root is not None:
+            root.tags.update(tags)
 
     @staticmethod
     def record(name: str, parent: Span | None, t0: float, t1: float,

@@ -520,7 +520,7 @@ class CodecAutotuner:
             FAULTS.kernel("rs_encode")
             runner = self._lane_runner(lane, pm, data, k, m)
             out = runner()  # warm: jit compile / native build / cache
-            wall = min(self._timed(runner) for _ in range(2))
+            wall = min(self._timed(runner) for _ in range(3))
             got = np.asarray(out)
             # Normalize to (m, B, S): the jit lane answers batch-major
             # (B, m, S), the host lanes column-folded (m, B*S).
@@ -546,15 +546,22 @@ class CodecAutotuner:
         cols = np.ascontiguousarray(
             data.transpose(1, 0, 2).reshape(k, B * S))
         if lane in (DEVICE, XLA_CPU):
-            import jax.numpy as jnp
-
-            from . import rs_tpu
+            from . import batching, rs_tpu
             from .gf256 import gf_matrix_to_bitplane
-            bm = jnp.asarray(
+            # The dispatch a served batch makes, its placement inside
+            # the clock: the matrix where serving keeps it (on every
+            # mesh device), the batch through device_put_batch each
+            # run (one H2D copy on one device, the mesh's sharding and
+            # its census entry on several). A batch placed once,
+            # before the clock, on device 0 reads a dispatch that is
+            # never served: on the four-chip host it flipped RS to
+            # the chip in 1 of 2 boots (PERF.md section 7, PR 28).
+            bm = batching.device_put_replicated(
                 gf_matrix_to_bitplane(pm).astype(np.float32))
-            placed = jnp.asarray(data)
 
             def run_jit():
+                placed = batching.device_put_batch(data,
+                                                   kernel=RS_ENCODE)
                 out = rs_tpu.gf_apply(bm, placed)
                 return np.asarray(out)  # sync: the wall must be real
             return run_jit

@@ -176,22 +176,25 @@ def set_mesh_devices(n: int | None) -> None:
     reset_serving_mesh()
 
 
-def device_put_batch(x, affinity: int | None = None):
+def device_put_batch(x, affinity: int | None = None, *, kernel: str):
     """np (B, R, S) -> device array: sharded across the serving mesh
     when an axis divides it, pinned WHOLE to the owning erasure set's
     home device otherwise (parallel/mesh.batch_placement — concurrent
     sets' small dispatches spread across chips instead of all queueing
     on device 0).  Every placement lands in the MESH_AFFINITY census
-    so the spread is provable."""
+    under `kernel`, with the bytes each device holds (an axis left
+    replicated counts whole on every device along it), so the spread
+    and the redundancy are provable."""
     import jax
     import jax.numpy as jnp
     m = serving_mesh()
     if m is None:
         return jnp.asarray(x)
-    from ..parallel.mesh import MESH_AFFINITY, batch_placement
+    from ..parallel.mesh import MESH_AFFINITY, batch_placement, shard_nbytes
     B, _, S = x.shape
     sh, dev_indices = batch_placement(m, B, S, affinity)
-    MESH_AFFINITY.record_dispatch(dev_indices, x.nbytes)
+    MESH_AFFINITY.record_dispatch(kernel, dev_indices, x.nbytes,
+                                  shard_nbytes(sh, x))
     return jax.device_put(x, sh)
 
 
@@ -236,7 +239,8 @@ def _device_reconstruct(stack: np.ndarray, k: int, m: int,
             batch_home_device(stack, affinity))
         ph.phase("enqueue")
         with timed() as t:
-            dev = rs_tpu.gf_apply(bm, device_put_batch(stack, affinity))
+            dev = rs_tpu.gf_apply(bm, device_put_batch(
+                stack, affinity, kernel=RS_DECODE))
             ph.phase("wait")
             out = np.asarray(dev)
     KERNEL.record(RS_DECODE, True, stack.nbytes, t.s,

@@ -483,15 +483,18 @@ def hash_chunks(chunks: np.ndarray, key: bytes = MAGIC_KEY,
         if m is not None:
             # Rows shard over every mesh device when B divides it; a
             # batch that does not stays whole on the default device
-            # (index 0).
-            from ..obs.metrics2 import METRICS2
-            METRICS2.inc("minio_tpu_v2_hh256_mesh_dispatches_total",
-                         {"placement": "sharded" if sharded else "single"})
+            # (index 0). The census takes either as it is placed.
+            from ..parallel.mesh import MESH_AFFINITY
             if sharded:
                 from ..parallel.mesh import rows_sharding
                 words = jax.device_put(words, rows_sharding(m, B, 3))
                 rem_packet = jax.device_put(rem_packet,
                                             rows_sharding(m, B, 2))
+                MESH_AFFINITY.record_dispatch(
+                    HH256, tuple(range(m.size)), chunks.nbytes,
+                    chunks.nbytes // m.size)
+            else:
+                MESH_AFFINITY.record_dispatch(HH256, (0,), chunks.nbytes)
         mesh = m if sharded else None
         _report_impl(mesh)
         with timed() as t:

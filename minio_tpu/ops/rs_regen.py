@@ -306,7 +306,8 @@ def _device_apply(mat: np.ndarray | None, bitplane: np.ndarray | None,
     with timed() as t:
         out = np.asarray(rs_tpu.gf_apply(
             batching.device_put_replicated(bm),
-            batching.device_put_batch(cols[None], affinity)))[0]
+            batching.device_put_batch(cols[None], affinity,
+                                      kernel=REGEN_CODE)))[0]
     KERNEL.record(REGEN_CODE, True, cols.nbytes, t.s, blocks=blocks,
                   backend=batching.attempt_backend())
     return out

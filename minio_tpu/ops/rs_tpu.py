@@ -297,7 +297,8 @@ def encode_batch(data: np.ndarray, k: int, m: int,
         ph.phase("enqueue")
         with timed() as t:
             if data.ndim == 3:
-                placed = batching.device_put_batch(data, affinity)
+                placed = batching.device_put_batch(data, affinity,
+                                                   kernel=RS_ENCODE)
             else:
                 placed = jnp.asarray(data)
             dev = encode_blocks(bm, placed)

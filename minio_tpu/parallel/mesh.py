@@ -94,22 +94,41 @@ def batch_placement(mesh: Mesh, B: int, S: int,
     return sh, tuple(range(mesh.size))
 
 
+SHARDED, PINNED, REPLICATED = "sharded", "pinned", "replicated"
+
+
+def shard_nbytes(sharding, x) -> int:
+    """Bytes of `x` that ONE device holds under `sharding`: the whole
+    array on a single device or along an axis left replicated, a
+    share of it along an axis that is sharded."""
+    return math.prod(sharding.shard_shape(x.shape)) * x.itemsize
+
+
 class DeviceAffinity:
-    """Per-erasure-set home-device assignment + per-device dispatch
-    census (``MESH_AFFINITY``).
+    """Per-erasure-set home-device assignment + the census of what
+    every device dispatch placed where (``MESH_AFFINITY``).
 
     Each ``ErasureObjects`` set registers at construction and gets the
-    next device round-robin; every placed dispatch records which
-    device indices it occupied.  The census is the proof behind the
-    admin ``/codec-plan`` affinity map and the 8-virtual-device spread
-    tests — per-set affinity is only real if the counters say so."""
+    next device round-robin.  Every dispatch onto a serving mesh
+    (``ops/batching.device_put_batch`` for the RS kernels,
+    ``ops/hh256_tpu.hash_chunks``) records the device indices it
+    occupied and the bytes EACH of them holds: a batch sharded four
+    ways adds a quarter to each of four devices, an axis left
+    replicated adds the whole to each (chips repeating each other's
+    work), a pinned batch adds the whole to one.  The batch's own
+    bytes are counted once, by kernel and placement.  One store, read
+    by the admin ``/codec-plan`` affinity map and by the
+    ``minio_tpu_v2_mesh_*`` series (obs/metrics2.py collects them at
+    scrape); on a single device nothing records, so neither exists."""
 
     def __init__(self):
         self._mu = threading.Lock()
         self._assign: dict[str, int] = {}
         self._next = 0
-        self._dispatches: dict[int, int] = {}
-        self._bytes: dict[int, int] = {}
+        # (kernel, device index) -> [dispatches, bytes that device held]
+        self._devices: dict[tuple[str, int], list[int]] = {}
+        # (kernel, placement) -> [dispatches, bytes of the batches, once]
+        self._placements: dict[tuple[str, str], list[int]] = {}
 
     @staticmethod
     def n_devices() -> int:
@@ -133,39 +152,89 @@ class DeviceAffinity:
         with self._mu:
             self._assign.pop(owner, None)
 
-    def record_dispatch(self, device_indices: tuple[int, ...],
-                        nbytes: int) -> None:
+    def record_dispatch(self, kernel: str,
+                        device_indices: tuple[int, ...], nbytes: int,
+                        device_nbytes: int | None = None) -> None:
+        """One dispatch of `nbytes` that occupied `device_indices`,
+        each holding `device_nbytes` (default: the whole batch)."""
+        if device_nbytes is None:
+            device_nbytes = nbytes
+        if len(device_indices) == 1:
+            placement = PINNED
+        elif device_nbytes * len(device_indices) == nbytes:
+            placement = SHARDED
+        else:
+            placement = REPLICATED
         with self._mu:
             for i in device_indices:
-                self._dispatches[i] = self._dispatches.get(i, 0) + 1
-                self._bytes[i] = self._bytes.get(i, 0) + nbytes
+                d = self._devices.setdefault((kernel, i), [0, 0])
+                d[0] += 1
+                d[1] += device_nbytes
+            p = self._placements.setdefault((kernel, placement), [0, 0])
+            p[0] += 1
+            p[1] += nbytes
 
     def counters(self) -> dict[int, dict]:
+        """Per device index, over every kernel."""
+        out: dict[int, dict] = {}
         with self._mu:
-            return {i: {"dispatches": self._dispatches.get(i, 0),
-                        "bytes": self._bytes.get(i, 0)}
-                    for i in sorted(set(self._dispatches)
-                                    | set(self._bytes))}
+            for (_, i), (n, b) in sorted(self._devices.items(),
+                                         key=lambda kv: kv[0][1]):
+                d = out.setdefault(i, {"dispatches": 0, "bytes": 0})
+                d["dispatches"] += n
+                d["bytes"] += b
+        return out
+
+    def device_bytes(self) -> list[tuple[dict, int]]:
+        """``mesh_device_bytes_total``: (labels, bytes) per kernel and
+        device."""
+        with self._mu:
+            return [({"kernel": k, "device": str(i)}, v[1])
+                    for (k, i), v in sorted(self._devices.items())]
+
+    def dispatch_bytes(self) -> list[tuple[dict, int]]:
+        """``mesh_dispatch_bytes_total``: (labels, bytes) per kernel
+        and placement."""
+        with self._mu:
+            return [({"kernel": k, "placement": p}, v[1])
+                    for (k, p), v in sorted(self._placements.items())]
 
     def snapshot(self) -> dict:
         """The affinity map the admin /codec-plan serves."""
         with self._mu:
-            return {
-                "nDevices": self.n_devices(),
-                "assignments": dict(sorted(self._assign.items())),
-                "dispatches": {
-                    str(i): {"dispatches": self._dispatches.get(i, 0),
-                             "bytes": self._bytes.get(i, 0)}
-                    for i in sorted(set(self._dispatches)
-                                    | set(self._bytes))},
-            }
+            assignments = dict(sorted(self._assign.items()))
+            kernels: dict[str, dict] = {}
+            for (k, i), (n, b) in sorted(self._devices.items()):
+                kernels.setdefault(k, {"devices": {}, "placements": {}})[
+                    "devices"][str(i)] = {"dispatches": n, "bytes": b}
+            for (k, p), (n, b) in sorted(self._placements.items()):
+                kernels.setdefault(k, {"devices": {}, "placements": {}})[
+                    "placements"][p] = {"dispatches": n, "bytes": b}
+        return {
+            "nDevices": self.n_devices(),
+            "assignments": assignments,
+            "dispatches": {str(i): v
+                           for i, v in self.counters().items()},
+            "kernels": kernels,
+        }
 
     def reset(self) -> None:
         with self._mu:
             self._assign.clear()
             self._next = 0
-            self._dispatches.clear()
-            self._bytes.clear()
+            self._devices.clear()
+            self._placements.clear()
 
 
 MESH_AFFINITY = DeviceAffinity()
+
+
+def _export() -> None:
+    from ..obs.metrics2 import METRICS2
+    METRICS2.collect("minio_tpu_v2_mesh_device_bytes_total",
+                     MESH_AFFINITY.device_bytes)
+    METRICS2.collect("minio_tpu_v2_mesh_dispatch_bytes_total",
+                     MESH_AFFINITY.dispatch_bytes)
+
+
+_export()

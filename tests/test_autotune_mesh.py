@@ -68,8 +68,8 @@ def test_indivisible_batch_pins_to_home_device():
     b = MESH_AFFINITY.assign("owner-b")
     assert a != b
     x = np.arange(3 * 4 * 7, dtype=np.uint8).reshape(3, 4, 7)
-    placed_a = batching.device_put_batch(x, a)
-    placed_b = batching.device_put_batch(x, b)
+    placed_a = batching.device_put_batch(x, a, kernel="rs_encode")
+    placed_b = batching.device_put_batch(x, b, kernel="rs_encode")
     assert len(placed_a.sharding.device_set) == 1
     assert len(placed_b.sharding.device_set) == 1
     assert placed_a.sharding.device_set != placed_b.sharding.device_set
@@ -84,7 +84,7 @@ def test_divisible_batch_still_shards_across_mesh():
     divides the mesh spreads over all chips even with a home device."""
     a = MESH_AFFINITY.assign("owner-big")
     x = np.arange(16 * 4 * 256, dtype=np.uint8).reshape(16, 4, 256)
-    placed = batching.device_put_batch(x, a)
+    placed = batching.device_put_batch(x, a, kernel="rs_encode")
     assert len(placed.sharding.device_set) == 8
     np.testing.assert_array_equal(np.asarray(placed), x)
 

@@ -96,6 +96,23 @@ def test_rs_probe_rungs_compile_for_v5e(one_chip, S):
     _compile_rs(one_chip, 8, 4, 2, S, False)
 
 
+@pytest.mark.parametrize("S", [1024, 16384, 65536, 262144])
+def test_rs_probe_rungs_compile_sharded_on_2x2(mesh, S):
+    """On a serving mesh the ladder's device rung places its (8, 4, S)
+    batch as a served batch is placed (PR 28): both axes divide, one
+    local kernel per chip over (4, 4, S/2)."""
+    from minio_tpu.ops import rs_pallas
+    from minio_tpu.parallel.mesh import batch_sharding, replicated
+    bm = jax.ShapeDtypeStruct((8 * 2, 8 * 4), jnp.float32,
+                              sharding=replicated(mesh))
+    x = jax.ShapeDtypeStruct((8, 4, S), jnp.uint8,
+                             sharding=batch_sharding(mesh, 8, S))
+    txt = jax.jit(lambda bm, x: rs_pallas._apply_sharded(
+        mesh, bm, x, interpret=False, with_data=False)).lower(
+            bm, x).compile().as_text()
+    assert "tpu_custom_call" in txt and "all-gather" not in txt
+
+
 @pytest.mark.parametrize("B,S", [(6, _shard_len(8)), (16, MiB // 8)])
 @pytest.mark.parametrize("r", [1, 2])
 def test_rs_reconstruct_8_4_compiles_for_v5e(one_chip, r, B, S):

@@ -34,7 +34,7 @@ def test_mesh_exists_on_virtual_devices():
 
 def test_device_put_batch_actually_shards():
     x = np.arange(16 * 4 * 256, dtype=np.uint8).reshape(16, 4, 256)
-    placed = batching.device_put_batch(x)
+    placed = batching.device_put_batch(x, kernel="rs_encode")
     # Every device holds a proper slice, not a replica.
     n_shards = len(placed.sharding.device_set)
     assert n_shards == 8
@@ -46,7 +46,7 @@ def test_device_put_batch_actually_shards():
 
 def test_device_put_batch_indivisible_dims_still_work():
     x = np.arange(3 * 4 * 7, dtype=np.uint8).reshape(3, 4, 7)
-    placed = batching.device_put_batch(x)
+    placed = batching.device_put_batch(x, kernel="rs_encode")
     np.testing.assert_array_equal(np.asarray(placed), x)
 
 
