@@ -144,6 +144,60 @@ class _LockedStream:
             pass
 
 
+class ObjectHandle:
+    """An object opened for reading (ErasureObjects.open_object): the
+    `info` of ONE metadata quorum read and the namespace read lock it
+    was made under. stream() hands the lock on to the chunk iterator
+    it returns; close() releases it when no stream was taken (HEAD,
+    304, 412, an invalid range, any exception) and does nothing after
+    one was. A context manager, so no path leaks a read lock. A stat
+    the hot-object cache answered holds no lock and no FileInfo, and
+    get_object_stream opens for it if the cache has no bytes either."""
+
+    def __init__(self, engine: "ErasureObjects", info: ObjectInfo,
+                 version_id: str = "", fi: FileInfo | None = None,
+                 agreed: list | None = None, lock_ctx=None):
+        self.info = info
+        self._engine = engine
+        self._version_id = version_id
+        self._fi = fi
+        self._agreed = agreed
+        self._ctx = lock_ctx  # already entered; None = nothing held
+        # A layer above may wrap the chunk iterator (ErasureSets counts
+        # the set's GET bytes on it).
+        self.wrap_stream = None
+
+    def stream(self, offset: int = 0, length: int = -1):
+        """Chunk iterator over [offset, offset+length) of the version
+        that was opened (ValueError on a range outside it)."""
+        self.info, stream = self._engine.get_object_stream(
+            self.info.bucket, self.info.name, offset, length,
+            self._version_id, opened=self)
+        return stream if self.wrap_stream is None \
+            else self.wrap_stream(stream)
+
+    def _take_lock(self):
+        ctx, self._ctx = self._ctx, None
+        return ctx
+
+    def close(self) -> None:
+        ctx = self._take_lock()
+        if ctx is not None:
+            ctx.__exit__(None, None, None)
+
+    def __enter__(self) -> "ObjectHandle":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    def __del__(self):
+        try:
+            self.close()
+        except Exception:
+            pass
+
+
 class ErasureObjects:
     """Object engine over one erasure set of k+m disks."""
 
@@ -468,8 +522,11 @@ class ErasureObjects:
 
     def _check_bucket(self, bucket: str) -> None:
         self._check_not_reserved(bucket)
-        # ec.meta: the drives' metadata before any data moves — this
-        # stat fan-out over every drive and the xl.meta quorum read.
+        # ec.meta: the drives' metadata before any data moves. This
+        # stat fan-out over every drive is for callers with no read
+        # that carries the evidence itself (PUT, tags, multipart,
+        # listing); GET / HEAD take BucketNotFound from their xl.meta
+        # quorum read's own errors (_open_locked) and never come here.
         from ..obs.span import TRACER
         with TRACER.span("ec.meta", what="bucket"):
             exists = self.bucket_exists(bucket)
@@ -1075,36 +1132,68 @@ class ErasureObjects:
             raise ObjectNotFound(f"{bucket}/{object_name}")
         return ObjectInfo.from_file_info(fi)
 
-    def get_object_info(self, bucket: str, object_name: str,
-                        version_id: str = "") -> ObjectInfo:
+    def _open_locked(self, bucket: str, object_name: str,
+                     version_id: str = "") -> ObjectHandle:
+        """The one metadata routine of the read path: the namespace
+        read lock, then ONE xl.meta quorum read inside ONE ec.meta
+        span; the handle keeps both. No bucket stat: read_version
+        answers VolumeNotFound where the volume is gone, and
+        _quorum_file_info turns a majority of those into
+        BucketNotFound (_raise_if_bucket_gone), as the reference's
+        GetObjectInfo does. The lock makes a stat racing a commit or a
+        delete see before-or-after state, never the mid-write mixture
+        (ref getObjectInfo taking the shared ns lock,
+        cmd/erasure-object.go:383), and covers metadata + data so an
+        overwrite cannot swap the data dir between the two reads."""
+        self._check_not_reserved(bucket)
+        from ..obs.span import TRACER
+        ctx = self.ns_lock.read_locked(bucket, object_name)
+        _t_lock = time.perf_counter()
+        ctx.__enter__()
+        try:
+            TRACER.record("lock.wait", TRACER.current(), _t_lock,
+                          time.perf_counter(), mode="read")
+            with TRACER.span("ec.meta"):
+                fi, agreed = self._quorum_file_info(bucket, object_name,
+                                                    version_id)
+            if fi.deleted:
+                if version_id:
+                    raise MethodNotAllowed(f"{bucket}/{object_name}")
+                raise ObjectNotFound(f"{bucket}/{object_name}")
+            return ObjectHandle(self, ObjectInfo.from_file_info(fi),
+                                version_id, fi, agreed, ctx)
+        except BaseException:
+            ctx.__exit__(None, None, None)
+            raise
+
+    def open_object(self, bucket: str, object_name: str,
+                    version_id: str = "") -> ObjectHandle:
+        """Open an object once per request: the handle's `info` serves
+        the stat, the preconditions and the range, and its stream()
+        the bytes of that same version under the same read lock.
+        close() it (or leave its `with` block) when no stream is
+        taken. BucketNotFound comes from the metadata read's own
+        errors (_open_locked).
+
+        With the hot-object cache on, a memory-tier entry answers the
+        stat with no lock and no disk I/O (latest-only; versioned
+        opens take the quorum path); stream() then consults the tiers
+        as get_object_stream does."""
         from ..cache.hotcache import HOTCACHE
         if HOTCACHE.enabled and not version_id:
-            # Memory-tier stat: a hot GET's HEAD/stat half also skips
-            # the metadata fan-out (latest-only; versioned stats take
-            # the quorum path below).
             info = HOTCACHE.lookup_info(
                 self.cache_ns, bucket, object_name,
                 lambda: self._uncached_info(bucket, object_name))
             if info is not None:
-                return info
-        self._check_bucket(bucket)
-        # Same read lock as the data path: a stat racing a concurrent
-        # commit/delete must see before-or-after state, never the
-        # mid-parallel-write mixture (ref getObjectInfo taking the
-        # shared ns lock, cmd/erasure-object.go:383).
-        from ..obs.span import TRACER
-        _t_lock = time.perf_counter()
-        with self.ns_lock.read_locked(bucket, object_name):
-            TRACER.record("lock.wait", TRACER.current(), _t_lock,
-                          time.perf_counter(), mode="read")
-            with TRACER.span("ec.meta"):
-                fi, _ = self._quorum_file_info(bucket, object_name,
-                                               version_id)
-        if fi.deleted:
-            if version_id:
-                raise MethodNotAllowed(f"{bucket}/{object_name}")
-            raise ObjectNotFound(f"{bucket}/{object_name}")
-        return ObjectInfo.from_file_info(fi)
+                return ObjectHandle(self, info)
+        return self._open_locked(bucket, object_name, version_id)
+
+    def get_object_info(self, bucket: str, object_name: str,
+                        version_id: str = "") -> ObjectInfo:
+        """Stat = open + close: one xl.meta quorum read under the read
+        lock (or the hot cache's memory tier), no bucket stat."""
+        with self.open_object(bucket, object_name, version_id) as h:
+            return h.info
 
     def get_object(self, bucket: str, object_name: str, offset: int = 0,
                    length: int = -1, version_id: str = "",
@@ -1115,67 +1204,72 @@ class ErasureObjects:
 
     def get_object_stream(self, bucket: str, object_name: str,
                           offset: int = 0, length: int = -1,
-                          version_id: str = "",
+                          version_id: str = "", *,
+                          opened: ObjectHandle | None = None,
                           ) -> tuple[ObjectInfo, "object"]:
-        """(info, chunk iterator) — the streaming GET: blocks are
+        """(info, chunk iterator) — the streaming GET = open + stream:
+        one xl.meta quorum read (BucketNotFound from its own errors,
+        no bucket stat), or none where the caller hands in the handle
+        it `opened` (ObjectHandle.stream does); then blocks are
         fetched, bitrot-verified, and reconstructed group-by-group, so
         peak memory is O(group), never O(range) (ref blockwise decode,
-        cmd/erasure-decode.go:248-263). The read lock is held for the
-        stream's lifetime, like the reference holds its read lock across
-        the response write (cmd/erasure-object.go:134); exhaust or
-        close() the iterator to release it.
+        cmd/erasure-decode.go:248-263). The open's read lock passes to
+        the iterator and is held for the stream's lifetime, like the
+        reference holds its read lock across the response write
+        (cmd/erasure-object.go:134); exhaust or close() the iterator
+        to release it. An invalid range releases it here.
 
         The hot-object cache is consulted twice (cache/hotcache.py):
         a tier hit up front serves decoded bytes with NO disk I/O at
         all; past the metadata quorum read, a concurrent fill of the
         same key+etag is joined (coalesced wait — N cold GETs of one
         hot key perform exactly one shard fan-out + decode), and a
-        full-object read registers itself as the single-flight fill."""
+        full-object read registers itself as the single-flight fill.
+        A handle that holds its lock is its own revalidation oracle:
+        its `info` IS the current uncached quorum read, and a second
+        read lock under a waiting writer would wait for itself."""
         from ..cache.hotcache import HOTCACHE
+        # A stat the cache answered has nothing locked to stream from.
+        held = opened is not None and opened._fi is not None
         if HOTCACHE.enabled and not version_id:
             served = HOTCACHE.serve(
                 self.cache_ns, bucket, object_name, offset, length,
-                lambda: self._uncached_info(bucket, object_name))
+                (lambda: opened.info) if held else
+                (lambda: self._uncached_info(bucket, object_name)))
             if served is not None:
+                if held:
+                    opened.close()
                 return served
-        self._check_bucket(bucket)
-        # The read lock covers metadata + data so a concurrent overwrite
-        # cannot swap the data dir between the two reads.
-        from ..obs.span import TRACER
-        ctx = self.ns_lock.read_locked(bucket, object_name)
-        _t_lock = time.perf_counter()
-        ctx.__enter__()
-        TRACER.record("lock.wait", TRACER.current(), _t_lock,
-                      time.perf_counter(), mode="read")
-        try:
-            with TRACER.span("ec.meta"):
-                fi, agreed = self._quorum_file_info(bucket, object_name,
-                                                    version_id)
-            if fi.deleted:
-                if version_id:
-                    raise MethodNotAllowed(f"{bucket}/{object_name}")
-                raise ObjectNotFound(f"{bucket}/{object_name}")
-            info = ObjectInfo.from_file_info(fi)
-            if offset < 0 or offset > fi.size:
-                raise ValueError("invalid range")
-            if length < 0:
-                length = fi.size - offset
-            if offset + length > fi.size:
-                raise ValueError("invalid range")
-            if length == 0 or fi.size == 0:
-                ctx.__exit__(None, None, None)
-                return info, iter(())
-            if HOTCACHE.enabled and not version_id:
-                cached = self._cache_fill_or_join(
-                    ctx, fi, agreed, info, bucket, object_name,
-                    offset, length)
-                if cached is not None:
-                    return cached
-            gen = self._iter_ranges(fi, agreed, offset, length)
-            return info, _LockedStream(ctx, gen)
-        except BaseException:
-            ctx.__exit__(None, None, None)
-            raise
+        with (opened if held else
+              self._open_locked(bucket, object_name, version_id)) as h:
+            return h.info, self._stream_locked(h, offset, length)
+
+    def _stream_locked(self, h: ObjectHandle, offset: int, length: int):
+        """Chunk iterator over a range of the version `h` holds locked;
+        the lock leaves the handle only with the iterator that owns it
+        from then on, so a refused range leaves it to h.close()."""
+        from ..cache.hotcache import HOTCACHE
+        fi, agreed, info = h._fi, h._agreed, h.info
+        if h._ctx is None:
+            raise RuntimeError("object handle is closed")
+        if offset < 0 or offset > fi.size:
+            raise ValueError("invalid range")
+        if length < 0:
+            length = fi.size - offset
+        if offset + length > fi.size:
+            raise ValueError("invalid range")
+        if length == 0 or fi.size == 0:
+            h.close()
+            return iter(())
+        if HOTCACHE.enabled and not h._version_id:
+            cached = self._cache_fill_or_join(
+                h._ctx, fi, agreed, info, info.bucket, info.name,
+                offset, length)
+            if cached is not None:
+                h._take_lock()  # the fill / the waiter disposed of it
+                return cached[1]
+        gen = self._iter_ranges(fi, agreed, offset, length)
+        return _LockedStream(h._take_lock(), gen)
 
     def _cache_fill_or_join(self, ctx, fi, agreed, info, bucket: str,
                             object_name: str, offset: int, length: int):

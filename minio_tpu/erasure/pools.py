@@ -133,6 +133,14 @@ class ErasureServerPools:
                            lambda p: p.get_object_info(
                                bucket, object_name, version_id))
 
+    def open_object(self, bucket: str, object_name: str,
+                    version_id: str = ""):
+        """The first pool that holds the key opens it (see
+        ErasureObjects.open_object)."""
+        return self._probe(bucket, object_name,
+                           lambda p: p.open_object(
+                               bucket, object_name, version_id))
+
     def delete_object(self, bucket: str, object_name: str,
                       version_id: str = "",
                       versioned: bool = False) -> ObjectInfo:

@@ -174,6 +174,15 @@ class ErasureSets:
         return self.set_for(object_name).get_object_info(
             bucket, object_name, version_id)
 
+    def open_object(self, bucket: str, object_name: str,
+                    version_id: str = ""):
+        """ErasureObjects.open_object of the hashed set; the stream the
+        handle hands out counts the set's GET bytes."""
+        target, labels = self._route(object_name, "get")
+        handle = target.open_object(bucket, object_name, version_id)
+        handle.wrap_stream = lambda s: _CountedStream(s, labels)
+        return handle
+
     def delete_object(self, bucket: str, object_name: str,
                       version_id: str = "",
                       versioned: bool = False) -> ObjectInfo:

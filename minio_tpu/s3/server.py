@@ -1120,12 +1120,35 @@ class S3ApiHandlers:
 
     def get_object(self, req: S3Request, head: bool = False) -> S3Response:
         version_id = self._version_param(req)
+        # An object is opened once per request where the layer can
+        # (erasure layers): the handle's one metadata read serves the
+        # stat, the preconditions, the range and the headers, and its
+        # stream() the bytes of that same version under the same read
+        # lock. Other layers keep a stat and then a read.
+        open_fn = getattr(self.layer, "open_object", None)
+        handle = None
         try:
+            from ..bucket import tiering as tier_mod
+            from ..crypto import sse as sse_mod
             from ..utils import compress
-            info = self.layer.get_object_info(req.bucket, req.key,
-                                              version_id)
-            okey = self._sse_unseal_for_read(req, info)
+            if open_fn is not None:
+                handle = open_fn(req.bucket, req.key, version_id)
+                info = handle.info
+            else:
+                info = self.layer.get_object_info(req.bucket, req.key,
+                                                  version_id)
             comp = info.metadata.get(compress.META_COMPRESSION)
+            if handle is not None and (
+                    head or comp or sse_mod.is_encrypted(info.metadata)
+                    or tier_mod.needs_tier_read(info.metadata)):
+                # Only a plain object streams from the handle. HEAD
+                # needs no more of it, and the transformed branches
+                # make reads of their own: let the read lock go first
+                # (a second one under a waiting writer would wait for
+                # itself), and before any call to a KMS.
+                handle.close()
+                handle = None
+            okey = self._sse_unseal_for_read(req, info)
             # Ranges address the PLAINTEXT for transformed objects (ref
             # DecryptObjectInfo size rewrite).
             size = self._actual_size(info)
@@ -1137,7 +1160,6 @@ class S3ApiHandlers:
                 raise s3err.ERR_PRECONDITION_FAILED
             rng = _parse_range(req.headers.get("range", ""), size)
             data = b""
-            from ..bucket import tiering as tier_mod
             if not head and tier_mod.needs_tier_read(info.metadata):
                 try:
                     plain = self._transitioned_plain(
@@ -1149,7 +1171,6 @@ class S3ApiHandlers:
             elif not head:
                 stream_fn = getattr(self.layer, "get_object_stream",
                                     None)
-                from ..crypto import sse as sse_mod
                 # Multipart SSE streams are per-part stitched — the
                 # ranged (buffered-per-package-window) path handles
                 # them; single-part objects stream end-to-end.
@@ -1229,9 +1250,12 @@ class S3ApiHandlers:
                     # the socket when the layer supports it (O(group)
                     # memory for any object size).
                     off, ln = rng if rng is not None else (0, size)
-                    stream_fn = getattr(self.layer, "get_object_stream",
-                                        None)
-                    if stream_fn is not None:
+                    if handle is not None:
+                        data = handle.stream(off, ln)
+                        # The same version's; the hot cache may have
+                        # answered with its own copy of it.
+                        info = handle.info
+                    elif stream_fn is not None:
                         info, data = stream_fn(req.bucket, req.key,
                                                offset=off, length=ln,
                                                version_id=version_id)
@@ -1247,6 +1271,11 @@ class S3ApiHandlers:
             if version_id:
                 raise s3err.ERR_NO_SUCH_VERSION
             raise s3err.ERR_NO_SUCH_KEY
+        finally:
+            # HEAD, 304, 412, an invalid range, any exception: the read
+            # lock goes here; a stream that was taken owns it by now.
+            if handle is not None:
+                handle.close()
 
         headers = self._object_headers(info)
         headers.update(self._sse_response_headers(info))

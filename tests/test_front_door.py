@@ -144,7 +144,8 @@ def test_burnt_deadline_keepalive_second_request_ok(tmp_path):
         client.make_bucket("bkt")
         client.put_object("bkt", "k", b"x" * 1024)
         slow = {"on": True}
-        real_info = srv.handlers.layer.get_object_info
+        # A GET's first call into the layer: it opens the object once.
+        real_info = srv.handlers.layer.open_object
 
         def slow_info(*a, **kw):
             if slow["on"]:
@@ -154,7 +155,7 @@ def test_burnt_deadline_keepalive_second_request_ok(tmp_path):
                 raise DeadlineExceeded("budget spent")
             return real_info(*a, **kw)
 
-        srv.handlers.layer.get_object_info = slow_info
+        srv.handlers.layer.open_object = slow_info
         srv.config.set_kv("api requests_max_read=8 "
                           "requests_deadline=200ms")
         conn = http.client.HTTPConnection("127.0.0.1", port,
@@ -176,7 +177,7 @@ def test_burnt_deadline_keepalive_second_request_ok(tmp_path):
             assert r2.read() == b"x" * 1024
         finally:
             conn.close()
-            srv.handlers.layer.get_object_info = real_info
+            srv.handlers.layer.open_object = real_info
             srv.config.set_kv("api requests_max_read=0 "
                               "requests_deadline=10s")
         _wait_inflight_zero(srv)

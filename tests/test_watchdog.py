@@ -854,11 +854,19 @@ def test_e2e_fault_plan_fires_drive_alert_with_incident(tmp_path):
         assert mine and mine[0]["state"] == "firing"
         assert "suspect" in mine[0]["cause"]
         assert slow_ep not in mine[0]["cause"]
-        # Cause-carrying console line with join keys.
-        lines = [e for e in Logger.get().ring.tail(200)
-                 if e.source == "watchdog"
-                 and "drive_degraded" in e.message
-                 and "firing" in e.message]
+        # Cause-carrying console line with join keys. The tick thread
+        # logs it a moment after the state flip: poll, like the gauge
+        # below (an earlier test's firing line is in the ring too).
+        deadline = time.time() + 5
+        while True:
+            lines = [e for e in Logger.get().ring.tail(200)
+                     if e.source == "watchdog"
+                     and "drive_degraded" in e.message
+                     and "firing" in e.message]
+            if (lines and lines[-1].fields["alert_id"]
+                    == mine[0]["alertId"]) or time.time() > deadline:
+                break
+            time.sleep(0.05)
         assert lines and lines[-1].fields["alert_id"] == \
             mine[0]["alertId"]
         # The gauge is written by the sampler-tick thread moments
