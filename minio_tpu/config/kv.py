@@ -89,8 +89,8 @@ DEFAULT_KVS: dict[str, dict[str, str]] = {
     # routes every commit rename through fsync-file + fsync-parent-dir
     # so a power cut cannot lose an acknowledged write to the page
     # cache. Default off — the reference's fsync-less reliable-rename
-    # — because the overhead is real (bench.py crash_recovery measures
-    # it paired; docs/robustness.md documents the tradeoff).
+    # — because every commit then pays two fsyncs (docs/robustness.md
+    # documents the tradeoff).
     "storage": {
         "fsync": "off",
     },

@@ -311,9 +311,8 @@ class KernelProfiler:
     # -- views ---------------------------------------------------------
 
     def mix_snapshot(self) -> dict[str, dict]:
-        """Cumulative per-backend dispatch/byte counters — bench.py
-        deltas these around each config so every bench record says
-        which backend actually did the math."""
+        """Cumulative per-backend dispatch/byte counters: the delta
+        around a piece of work says which backend did the math."""
         with self._mu:
             return {b.name: {"dispatches": b.dispatches,
                              "bytes": b.bytes,

@@ -8,7 +8,8 @@ a computed **blamed layer** so "why was this request slow?" is answered
 from the entry itself, not by replaying load. Deliberate backpressure
 (admission sheds, burnt deadlines) is EXEMPT: a 503 SlowDown is the
 QoS layer working, and letting sheds flood the ring/blame histogram
-would bury the real tail (bench.py's qos_brownout asserts this).
+would bury the real tail (tests/test_observability.py holds the
+exemption).
 
 Blame is derived from child-span SELF-times (duration minus children):
   admission-wait  QoS queue wait before the handler ran

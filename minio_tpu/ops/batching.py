@@ -169,8 +169,8 @@ def reset_serving_mesh() -> None:
 
 def set_mesh_devices(n: int | None) -> None:
     """Cap the serving mesh at the first n devices (None = all) and
-    rebuild — the n_devices-aware north-star sweep (bench.py) measures
-    the 1..N scaling curve through this."""
+    rebuild: how a process with more devices than a deployment is
+    given the deployment's mesh."""
     global _mesh_n_override
     _mesh_n_override = n
     reset_serving_mesh()

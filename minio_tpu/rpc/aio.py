@@ -1,30 +1,23 @@
-"""Async peer-RPC fabric: every internal hop on ONE event loop.
+"""The peer-RPC client: every internal hop on ONE event loop.
 
-The PR-11 front door put client serving on an event loop, but each
-in-flight peer call still parked a thread inside the pooled
-``http.client`` transport — a k+m shard fan-out on a 16-node cluster
-cost a fleet of blocked threads exactly where the distributed layer
-must scale. This module moves the CLIENT side of the RPC plane onto
-asyncio:
+A thread parked on a socket per in-flight peer call makes a k+m shard
+fan-out on a 16-node cluster cost a fleet of blocked threads exactly
+where the distributed layer must scale. So the CLIENT side of the RPC
+plane runs on asyncio:
 
 - one process-wide daemon event-loop thread (``RPC_LOOP``) owns every
   outbound peer connection; sync call sites bridge onto it with
   ``run_coroutine_threadsafe`` and block on a future — the calling
   thread waits, but no NEW thread exists per in-flight call;
-- ``call_async`` replicates ``RPCClient.call`` semantics exactly
-  (offline gate + jittered reconnect probe, fault injection, deadline
-  fast-fail/capping, self-tuning timeout bookkeeping, the single-shot
-  stale-pool retry, control-plane overrides, trace-span grafting) so
-  behaviour cannot drift between the fabrics;
+- ``call_async`` is the one implementation of a call: offline gate +
+  jittered reconnect probe, fault injection, deadline fast-fail and
+  capping, self-tuning timeout bookkeeping, the single-shot stale-pool
+  retry, control-plane overrides, trace-span grafting;
 - ``fanout``/``fanout_nowait`` run N-peer pushes as N coroutines on
   the one loop (``rpc/peer.py`` previously spawned a thread per peer);
 - ``Pipeline`` issues HTTP/1.1 pipelined requests on one dedicated
   connection — ``RemoteStorage.create_file`` streams chunk frames
   without a per-chunk round-trip stall.
-
-The legacy threaded transport stays fully functional behind
-``MINIO_RPC_FABRIC=threaded`` (the paired-bench / escape-hatch knob,
-mirroring ``MINIO_FRONT_DOOR``).
 
 Thread-model invariant: the per-client async connection pool is only
 ever touched FROM the RPC loop thread, so it needs no lock. Cross-
@@ -46,19 +39,11 @@ from ..storage import errors as serr
 from .transport import RPC_PREFIX, RPCClient, frame, sign, unframe, \
     wire_to_error
 
-# Pooled keep-alive connections kept per peer (matches the sync pool).
+# Pooled keep-alive connections kept per peer.
 POOL_SIZE = 8
 # In-flight pipelined requests per Pipeline before send() blocks on
 # the oldest response (bounds peer-side queueing and sender memory).
 PIPELINE_WINDOW = 4
-
-
-def fabric_async() -> bool:
-    """Env knob: MINIO_RPC_FABRIC=threaded keeps the legacy pooled
-    http.client transport (paired benches; emergency escape hatch)."""
-    import os
-    return os.environ.get("MINIO_RPC_FABRIC",
-                          "async").strip().lower() != "threaded"
 
 
 # ---------------------------------------------------------------------------
@@ -208,9 +193,10 @@ async def _open_aconn(client, timeout: float) -> _AConn:
 
 
 async def _get_aconn(client, timeout: float) -> tuple[_AConn, bool]:
-    """(connection, reused) — same contract as the sync pool: callers
-    retry once on a FRESH socket when a reused one fails before any
-    response byte (a peer restart leaves pooled keep-alives stale)."""
+    """(connection, reused): callers retry once on a FRESH socket when
+    a reused one fails before any response byte — a peer restart leaves
+    every pooled keep-alive stale, and treating that as peer death
+    knocks a healthy node out for OFFLINE_RETRY."""
     st = _aio_state(client)
     while st.pool:
         c = st.pool.pop()
@@ -222,13 +208,11 @@ async def _get_aconn(client, timeout: float) -> tuple[_AConn, bool]:
 
 async def _connect_mapped(client, eff_timeout: float, ddl, override,
                           service: str, method: str):
-    """``_get_aconn`` with the threaded transport's failure mapping.
+    """``_get_aconn`` with the call's failure mapping.
 
-    The sync pool hands back an UNCONNECTED ``http.client`` object —
-    the TCP connect happens lazily inside the request try-block, so
-    its error mapping covers it for free.  ``asyncio.open_connection``
-    connects eagerly, so a refused/timed-out connect here must get the
-    identical treatment (offline mark, dyn-timeout tuning on genuine
+    ``asyncio.open_connection`` connects eagerly, outside the
+    round-trip's try-block, so a refused/timed-out connect gets the
+    same treatment here (offline mark, dyn-timeout tuning on genuine
     ceiling hits only, deadline attribution) or it leaks a raw
     ``OSError`` past the offline gate.
     """
@@ -299,6 +283,9 @@ def _request_bytes(client, service: str, method: str, args: dict,
     if ddl is not None:
         lines.append(f"{H_DEADLINE}: {round(ddl.remaining_ms(), 3)}")
     if span is not None:
+        # The peer opens a server-side span under this context and
+        # ships its subtree back in the result (_graft_spans), so a
+        # cross-node request stitches into ONE tree.
         lines.append(f"x-mtpu-trace: {span.trace_id}:{span.span_id}")
     return ("\r\n".join(lines) + "\r\n\r\n").encode() + body
 
@@ -346,8 +333,9 @@ async def _roundtrip(conn: _AConn, req: bytes, got_resp: list,
 
 def _graft_spans(result, span) -> None:
     """Pop the peer's server-side span subtree out of the result and
-    graft it under the caller's span (same prune bounds as the sync
-    transport — peer-supplied subtrees are untrusted input)."""
+    graft it under the caller's span. Peer-supplied subtrees are
+    untrusted input: pruned to the local depth/fan-out/size bounds
+    before they enter the trace ring."""
     if not isinstance(result, dict):
         return
     remote_spans = result.pop("_trace_spans", None)
@@ -361,14 +349,15 @@ def _graft_spans(result, span) -> None:
 
 
 # ---------------------------------------------------------------------------
-# The async call — a faithful port of RPCClient.call
+# The call
 
 
 async def call_async(client, service: str, method: str, args: dict,
                      payload: bytes = b"",
                      timeout: float | None = None,
                      ddl=None, span=None) -> tuple[dict, bytes]:
-    """Async twin of ``RPCClient.call`` with identical semantics.
+    """``RPCClient.call`` on the RPC loop; returns (result_json,
+    body_bytes), raises storage errors.
 
     ``ddl``/``span`` are passed EXPLICITLY (captured at the sync
     boundary by ``bridge_call``): contextvars do not reliably cross
@@ -377,6 +366,10 @@ async def call_async(client, service: str, method: str, args: dict,
     """
     if not client.is_online():
         raise serr.DiskNotFound(f"{client.endpoint()} offline")
+    # Per-peer wire faults (minio_tpu/faultinject): an injected
+    # partition behaves exactly like an unreachable peer — the health
+    # gate closes and reconnect probes (with jitter) take over;
+    # slow-wire adds latency ahead of the socket I/O.
     from ..faultinject import FAULTS
     if FAULTS.enabled:
         _lat, _part = FAULTS.peer(client.endpoint())
@@ -386,6 +379,11 @@ async def call_async(client, service: str, method: str, args: dict,
             client._mark_offline()
             raise serr.DiskNotFound(
                 f"{client.endpoint()} unreachable: injected partition")
+    # Deadline propagation (qos/deadline.py): a request whose budget
+    # is already spent must not burn peer capacity — fail here.
+    # Otherwise forward the REMAINING budget so the peer can refuse
+    # expired work, and cap the call's timeout to it so a slow peer
+    # call cancels when the deadline expires.
     eff_timeout = timeout if timeout is not None else client.timeout
     if ddl is not None:
         rem_s = ddl.remaining()
@@ -485,9 +483,9 @@ def bridge_call(client, service: str, method: str, args: dict,
 
 
 def _fabric_serves(peers: dict) -> bool:
-    """The async fabric only speaks to real RPCClients — test doubles
-    and in-process loopback clients keep the thread fan-out path."""
-    return (fabric_async() and bool(peers)
+    """The RPC loop only speaks to real RPCClients — test doubles and
+    in-process loopback clients keep the thread fan-out path."""
+    return (bool(peers)
             and all(isinstance(c, RPCClient) for c in peers.values()))
 
 

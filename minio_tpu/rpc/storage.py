@@ -286,13 +286,13 @@ class RemoteStorage(StorageAPI):
         # Streamed write: first chunk creates/truncates, the rest append
         # — one bounded RPC frame per chunk, never the whole object
         # (ref storageRESTClient.CreateFile streaming body,
-        # cmd/storage-rest-client.go). On the async fabric the chunk
-        # frames ride ONE pipelined connection (up to aio.
-        # PIPELINE_WINDOW in flight) so chunk N's upload overlaps the
-        # peer's disk write for chunks N-1..N-3 instead of paying a
-        # full round-trip stall per chunk.
-        from . import aio
-        if aio.fabric_async() and isinstance(self.client, RPCClient):
+        # cmd/storage-rest-client.go). To a real peer the chunk frames
+        # ride ONE pipelined connection (up to aio.PIPELINE_WINDOW in
+        # flight) so chunk N's upload overlaps the peer's disk write
+        # for chunks N-1..N-3 instead of paying a full round-trip
+        # stall per chunk; an in-process or test-double client takes
+        # the plain loop below.
+        if isinstance(self.client, RPCClient):
             self._create_file_pipelined(volume, path, data)
             return
         first = True

@@ -1,7 +1,7 @@
 """Internal RPC transport: authenticated POST with length-prefixed JSON +
-binary framing, pooled keep-alive connections, and health-gated clients
-with reconnect (ref cmd/rest/client.go:62,193 MarkOffline +
-HealthCheckFn).
+binary framing, and health-gated clients with reconnect (ref
+cmd/rest/client.go:62,193 MarkOffline + HealthCheckFn). The client's
+connections and its call path live on the RPC event loop (rpc/aio.py).
 
 Wire format per call (everything in the BODY — headers stay tiny):
     POST /minio-tpu/rpc/v1/<service>/<method>
@@ -20,17 +20,14 @@ from __future__ import annotations
 
 import hashlib
 import hmac
-import http.client
 import json
 import random
 import struct
-import socket
 import threading
 import time
 
 from ..qos.deadline import (H_DEADLINE, Deadline, DeadlineExceeded,
-                            current_deadline, deadline_scope,
-                            record_expiry)
+                            deadline_scope, record_expiry)
 from ..storage import errors as serr
 
 RPC_PREFIX = "/minio-tpu/rpc/v1"
@@ -92,8 +89,9 @@ def wire_to_error(status: int, body: bytes) -> Exception:
 
 
 class RPCClient:
-    """Health-gated RPC caller to one peer, with a pooled keep-alive
-    connection."""
+    """Health-gated RPC caller to one peer: the peer's address, key,
+    self-tuning timeout and offline window. Its pooled keep-alive
+    connections belong to the RPC loop (rpc/aio.py)."""
 
     # Seconds a peer stays marked offline before a reconnect probe.
     # Live-reloadable via config-KV `rpc offline_retry=` (the server's
@@ -130,7 +128,6 @@ class RPCClient:
         self.dyn_timeout = DynamicTimeout(timeout, minimum=2.5)
         self._offline_until = 0.0
         self._mu = threading.Lock()
-        self._pool: list[http.client.HTTPConnection] = []
 
     def endpoint(self) -> str:
         return f"{self.host}:{self.port}"
@@ -148,44 +145,6 @@ class RPCClient:
     def timeout(self) -> float:
         return self.dyn_timeout.timeout
 
-    def _get_conn(self, t: float | None = None,
-                  ) -> tuple[http.client.HTTPConnection, bool]:
-        """(connection, reused): callers retry once on a FRESH socket
-        when a pooled one fails — a peer restart leaves every pooled
-        keep-alive connection stale, and treating that as peer death
-        knocks a healthy node out for OFFLINE_RETRY."""
-        if t is None:
-            t = self.timeout
-        with self._mu:
-            if self._pool:
-                conn = self._pool.pop()
-                conn.timeout = t  # used on (re)connect
-                if conn.sock is not None:
-                    conn.sock.settimeout(t)
-                return conn, True
-        return self._new_conn(t), False
-
-    def _new_conn(self, t: float) -> http.client.HTTPConnection:
-        if self.tls is not None:
-            return http.client.HTTPSConnection(
-                self.host, self.port, timeout=t, context=self.tls)
-        return http.client.HTTPConnection(self.host, self.port,
-                                          timeout=t)
-
-    def _drop_pool(self) -> None:
-        """Close every pooled connection (stale after a peer restart)."""
-        with self._mu:
-            pool, self._pool = self._pool, []
-        for c in pool:
-            c.close()
-
-    def _put_conn(self, conn: http.client.HTTPConnection) -> None:
-        with self._mu:
-            if len(self._pool) < 8:
-                self._pool.append(conn)
-                return
-        conn.close()
-
     def call(self, service: str, method: str, args: dict,
              payload: bytes = b"",
              timeout: float | None = None) -> tuple[dict, bytes]:
@@ -197,163 +156,14 @@ class RPCClient:
         mark the peer offline on expiry, so a slow control-plane poll
         can never knock a healthy peer out of the data plane.
 
-        By default the call runs on the async fabric (rpc/aio.py): the
-        coroutine twin of the body below executes on the process-wide
-        RPC event loop and this thread blocks on its future — same
-        semantics, zero extra threads per in-flight call.
-        MINIO_RPC_FABRIC=threaded keeps the pooled http.client path."""
+        The call runs on the RPC event loop (rpc/aio.py
+        `call_async`) and this thread blocks on its future: no extra
+        thread per in-flight call."""
         from . import aio
-        if aio.fabric_async():
-            return aio.bridge_call(self, service, method, args, payload,
-                                   timeout)
-        return self._call_threaded(service, method, args, payload,
-                                   timeout)
-
-    def _call_threaded(self, service: str, method: str, args: dict,
-                       payload: bytes = b"",
-                       timeout: float | None = None) -> tuple[dict, bytes]:
-        """Legacy thread-blocking transport (MINIO_RPC_FABRIC=threaded
-        and the paired fabric bench): one pooled http.client
-        connection, this thread parked on the socket."""
-        if not self.is_online():
-            raise serr.DiskNotFound(f"{self.endpoint()} offline")
-        # Per-peer wire faults (minio_tpu/faultinject): an injected
-        # partition behaves exactly like an unreachable peer — the
-        # health gate closes and reconnect probes (with jitter) take
-        # over; slow-wire adds latency ahead of the socket I/O.
-        from ..faultinject import FAULTS
-        if FAULTS.enabled:
-            _lat, _part = FAULTS.peer(self.endpoint())
-            if _lat:
-                time.sleep(_lat)
-            if _part:
-                self._mark_offline()
-                raise serr.DiskNotFound(
-                    f"{self.endpoint()} unreachable: injected "
-                    "partition")
-        # Deadline propagation (qos/deadline.py): a request whose
-        # budget is already spent must not burn peer capacity — fail
-        # here. Otherwise forward the REMAINING budget so the peer can
-        # refuse expired work, and cap the socket timeout to it so a
-        # slow peer call cancels when the deadline expires instead of
-        # holding the handler for the full transport timeout.
-        ddl = current_deadline()
-        eff_timeout = timeout
-        if ddl is not None:
-            rem_s = ddl.remaining()
-            if rem_s <= 0:
-                record_expiry("rpc-client")
-                raise DeadlineExceeded(
-                    f"{service}/{method} to {self.endpoint()}: request "
-                    "deadline exhausted before dispatch")
-            base = timeout if timeout is not None else self.timeout
-            eff_timeout = max(0.05, min(base, rem_s))
-        args_json = json.dumps(args, sort_keys=True)
-        ts = str(int(time.time()))
-        body = frame(args_json.encode(), payload)
-        headers = {
-            "x-mtpu-ts": ts,
-            "x-mtpu-auth": sign(self.cluster_key, f"{service}/{method}",
-                                ts, args_json, payload),
-            "Content-Length": str(len(body)),
-        }
-        if ddl is not None:
-            headers[H_DEADLINE] = str(round(ddl.remaining_ms(), 3))
-        # Distributed tracing: the caller's trace context rides a tiny
-        # header; the peer opens a server-side span under it and ships
-        # its subtree back in the reserved _trace_spans result key, so
-        # a cross-node request stitches into ONE tree (the reference
-        # has no cross-node stitching — its admin trace merges flat
-        # per-node entries).
-        from ..obs.span import current_span
-        _cur = current_span()
-        if _cur is not None:
-            headers["x-mtpu-trace"] = f"{_cur.trace_id}:{_cur.span_id}"
-        override = timeout is not None
-        from .aio import CENSUS
-        CENSUS.enter()
-        try:
-            conn, reused = self._get_conn(eff_timeout)
-            # mtpu-lint: disable=R6 -- single-shot retry, not a loop: the continue requires reused=True and a fresh socket comes back reused=False, so it fires at most once; no backoff by design (a stale pool is instant-fail, the peer is healthy)
-            while True:
-                t0 = time.monotonic()
-                logged = override
-                resp = None
-                try:
-                    conn.request("POST",
-                                 f"{RPC_PREFIX}/{service}/{method}",
-                                 body=body, headers=headers)
-                    resp = conn.getresponse()
-                    rbody = resp.read()
-                    if not override:
-                        self.dyn_timeout.log_success(
-                            time.monotonic() - t0)
-                    logged = True
-                    if resp.status != 200:
-                        self._put_conn(conn)
-                        raise wire_to_error(resp.status, rbody)
-                    result_json, data = unframe(rbody)
-                    self._put_conn(conn)
-                    result = json.loads(result_json or b"{}")
-                    if isinstance(result, dict):
-                        remote_spans = result.pop("_trace_spans", None)
-                        if remote_spans and _cur is not None and \
-                                isinstance(remote_spans, list):
-                            # Peer-supplied subtrees are untrusted
-                            # input: prune to the local depth/fan-out/
-                            # size bounds before they enter the trace
-                            # ring.
-                            from ..obs.span import sanitize_remote
-                            for s in remote_spans[:8]:
-                                sc = sanitize_remote(s)
-                                if sc is not None:
-                                    _cur.add_child(sc)
-                    return result, data
-                except (OSError, http.client.HTTPException,
-                        ValueError) as e:
-                    conn.close()
-                    if (reused and resp is None and isinstance(
-                            e, (http.client.RemoteDisconnected,
-                                ConnectionResetError,
-                                BrokenPipeError))):
-                        # A stale pooled socket (peer restarted): the
-                        # error arrived BEFORE any response started, on
-                        # a reused keep-alive connection — the
-                        # signature of a dead pool, not a dead peer.
-                        # Retry ONCE on a fresh socket; errors after a
-                        # response began (or any error on a fresh
-                        # socket) never retry, so an RPC the peer may
-                        # have executed is never re-sent.
-                        self._drop_pool()
-                        conn, reused = self._get_conn(eff_timeout)
-                        continue
-                    if ddl is not None and ddl.expired():
-                        # The request DEADLINE elapsed, not the peer:
-                        # the socket timeout above was deadline-capped,
-                        # so say nothing about peer health — no offline
-                        # mark, no dynamic-timeout tuning.
-                        record_expiry("rpc-client")
-                        raise DeadlineExceeded(
-                            f"{service}/{method} to {self.endpoint()}: "
-                            f"deadline expired mid-call: {e}")
-                    # Only genuine ceiling hits tune the timeout up —
-                    # an instant connection-refused says nothing about
-                    # slowness.
-                    if not logged and isinstance(e, (TimeoutError,
-                                                     socket.timeout)):
-                        self.dyn_timeout.log_failure()
-                    if not override:
-                        self._mark_offline()
-                    raise serr.DiskNotFound(
-                        f"{self.endpoint()} unreachable: {e}")
-        finally:
-            CENSUS.exit()
+        return aio.bridge_call(self, service, method, args, payload,
+                               timeout)
 
     def close(self) -> None:
-        with self._mu:
-            for c in self._pool:
-                c.close()
-            self._pool.clear()
         from . import aio
         aio.close_client(self)
 

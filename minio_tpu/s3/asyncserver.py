@@ -3,14 +3,14 @@ on a handful of loop threads, with request EXECUTION handed to a worker
 pool so every handler in ``s3/server.py`` (and the storage/erasure/
 kernel layers below) stays synchronous and semantically unchanged.
 
-The thread-per-connection front end (``ThreadingHTTPServer``) costs one
-OS stack per socket — idle keep-alive connections are exactly as
-expensive as active ones, which caps realistic concurrency in the low
-thousands.  This module replaces only L1: the listener, HTTP/1.1
-framing, and body/response streaming live on asyncio event loops; the
-moment a request head is parsed the connection hands an ``_AsyncTxn``
-to the shared request core (``S3Server._serve_one``), which runs on a
-bounded ``ThreadPoolExecutor`` exactly like a handler thread used to.
+A thread per connection costs one OS stack per socket — idle
+keep-alive connections are exactly as expensive as active ones, which
+caps realistic concurrency in the low thousands.  So L1 (the listener,
+HTTP/1.1 framing, body/response streaming) lives on asyncio event
+loops; the moment a request head is parsed the connection hands an
+``_AsyncTxn`` to the request core (``S3Server._serve_one``), which runs
+on a bounded ``ThreadPoolExecutor``.  This is the server's only front
+door.
 
 Key boundaries (why each piece looks the way it does):
 
@@ -19,9 +19,10 @@ Key boundaries (why each piece looks the way it does):
   feeds chunks as they arrive and pauses the transport past the high
   water mark, so backpressure propagates to the client socket instead
   of buffering the object in memory; the worker blocks on a condition
-  variable with the same 120s stall deadline the threaded server's
-  socket timeout enforced.  Chunks pass through as the ``bytes``
-  objects asyncio delivered (split via memoryview) — no re-buffering.
+  variable with a 120s stall deadline (``STALL_TIMEOUT_S``), so a
+  client that stops sending releases the namespace lock it holds.
+  Chunks pass through as the ``bytes`` objects asyncio delivered
+  (split via memoryview) — no re-buffering.
 
 - **Expect: 100-continue**: a request carrying it dispatches BEFORE the
   body exists; the interim 100 goes out lazily on the bridge's first
@@ -51,7 +52,6 @@ Key boundaries (why each piece looks the way it does):
   not send it).  Nothing desyncs the next pipelined request.
 
 Tuning knobs (env):
-- ``MINIO_FRONT_DOOR``          async (default) | threaded
 - ``MINIO_FRONT_DOOR_WORKERS``  request-execution threads (default 64)
 - ``MINIO_LOOP_THREADS``        event-loop threads (default 1)
 - ``MINIO_SHUTDOWN_DRAIN``      SIGTERM drain seconds (default 10)
@@ -81,7 +81,8 @@ MAX_HEAD_BYTES = 64 * 1024
 # writes, POSTs) buffer to completion like their Content-Length twins;
 # with no declared length this cap is what bounds them.
 CHUNKED_BUF_MAX = 64 * 1024 * 1024
-# Same stall deadline the threaded server's socket timeout enforced.
+# A body read or a response write that makes no progress for this long
+# fails, and releases the request's locks and admission slot.
 STALL_TIMEOUT_S = 120.0
 # Idle keep-alive reaper period (sweep granularity, not precision).
 SWEEP_PERIOD_S = 15.0
@@ -688,12 +689,11 @@ class _HttpConn(asyncio.Protocol):
         if chunked:
             return self._begin_chunked(method, raw_path, query,
                                        headers, expect, is_s3)
-        # Bridge (stream) only object PUTs: large ones like the
-        # threaded path, plus ANY carrying Expect (admission must run
-        # before the upload). Everything else — STS POSTs, multipart
-        # completes, sub-resource writes — buffers exactly like the
-        # threaded front end, so handlers that read req.body before
-        # route()'s drain point keep their semantics.
+        # Bridge (stream) only object PUTs: large ones, plus ANY
+        # carrying Expect (admission must run before the upload).
+        # Everything else — STS POSTs, multipart completes,
+        # sub-resource writes — is buffered whole, because those
+        # handlers read req.body before route()'s drain point.
         want_stream = (is_s3 and cl > 0 and method == "PUT"
                        and "/" in raw_path.lstrip("/")
                        and (expect
@@ -842,8 +842,7 @@ class _HttpConn(asyncio.Protocol):
     # write buffer WHOLE before pause_writing can matter — at 10k
     # connections a fleet of slow readers would pin conns x body-size
     # of RSS. Chunking with a writability wait between chunks bounds
-    # each connection near the transport's high-water mark (the
-    # threaded path got the same bound from blocking socket writes).
+    # each connection near the transport's high-water mark.
     WRITE_CHUNK = 256 * 1024
 
     def send_from_worker(self, data) -> None:
@@ -1055,7 +1054,7 @@ class _HttpConn(asyncio.Protocol):
         except Exception as e:  # noqa: BLE001
             # Mid-stream decode/auth failure AFTER the 200 went out:
             # abort the connection so the client sees a short body,
-            # never a clean success (same policy as the threaded path).
+            # never a clean success.
             ok = False
             from ..logger import Logger
             Logger.get().log_once(
@@ -1095,8 +1094,8 @@ class _HttpConn(asyncio.Protocol):
 
     def reap_if_idle(self, now: float, timeout: float) -> None:
         """Close connections with nothing in flight that have been
-        silent past the keep-alive timeout (the threaded server's
-        idle reaper, amortized into a periodic sweep)."""
+        silent past the keep-alive timeout (the idle reaper, run as
+        a periodic sweep)."""
         if self._closed or self._in_flight:
             return
         if self.idle_for(now) > timeout:
@@ -1148,8 +1147,7 @@ def _close_and_finish(pending, body_iter, finish_fn) -> None:
 
 class AsyncFrontDoor:
     """Owns the listen socket, the loop threads, the worker pool, and
-    the connection census; ``S3Server.start`` boots one of these unless
-    ``MINIO_FRONT_DOOR=threaded``."""
+    the connection census; ``S3Server.start`` boots one of these."""
 
     def __init__(self, server, cert_manager=None, workers: int = 0,
                  loop_threads: int = 0, keepalive_timeout: float = 120.0):
@@ -1200,19 +1198,14 @@ class AsyncFrontDoor:
     # -- lifecycle ------------------------------------------------------
 
     def start(self, host: str, port: int) -> int:
-        import os
         raise_nofile_limit()
         # Multi-loop accept via SO_REUSEPORT: each loop thread owns
         # its OWN listen socket bound to the same port, so the KERNEL
         # load-spreads incoming connections across loops — no accept
         # handoff, no cross-loop self-pipe wakeup per connection.
         # Falls back to the single-socket round-robin accept loop when
-        # the option is unavailable (or MINIO_REUSEPORT=off).
-        want_reuseport = (
-            hasattr(socket, "SO_REUSEPORT")
-            and os.environ.get("MINIO_REUSEPORT", "on").strip().lower()
-            not in ("off", "0", "no"))
-        if want_reuseport:
+        # the platform lacks the option or refuses it.
+        if hasattr(socket, "SO_REUSEPORT"):
             try:
                 bind_port = port
                 for _ in range(self._n_loops):

@@ -2,8 +2,8 @@
 
 The analog of the reference's L1/L2 (ref cmd/routers.go:86 middleware
 chain, cmd/api-router.go:82 route table, cmd/object-handlers.go,
-cmd/bucket-handlers.go), over Python stdlib http.server (threaded) with
-the erasure object engine as the ObjectLayer.
+cmd/bucket-handlers.go), behind the event-loop front door
+(`s3/asyncserver.py`), with the erasure object engine as the ObjectLayer.
 """
 
 from __future__ import annotations
@@ -15,7 +15,6 @@ import threading
 import time
 import urllib.parse
 import uuid
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from ..erasure.engine import (BucketExists, BucketNotFound, ErasureObjects,
                               MethodNotAllowed, ObjectInfo, ObjectNotFound)
@@ -2240,9 +2239,8 @@ class S3Server:
         # PUT bodies at or above this size stream through the engine's
         # block pipeline instead of buffering (O(batch) server memory).
         self.stream_threshold = 8 * 1024 * 1024
-        self._httpd: ThreadingHTTPServer | None = None
-        self._thread: threading.Thread | None = None
-        self._front_door = None  # asyncserver.AsyncFrontDoor when async
+        self.address: tuple[str, int] | None = None  # bound by start()
+        self._front_door = None  # asyncserver.AsyncFrontDoor once started
 
     @property
     def layer(self):
@@ -3782,10 +3780,9 @@ class S3Server:
 
     def preflight(self, raw_path: str, headers: dict,
                   ) -> tuple[int, list]:
-        """CORS preflight decision, shared by the threaded handler's
-        do_OPTIONS and the async front door (unauthenticated by
-        design; ref the preflight path of the CORS middleware).
-        Returns (status, response headers)."""
+        """CORS preflight decision (unauthenticated by design; ref the
+        preflight path of the CORS middleware). Returns (status,
+        response headers)."""
         origin = headers.get("origin", "")
         want = headers.get("access-control-request-method", "")
         want_headers = [
@@ -3815,14 +3812,13 @@ class S3Server:
         return 200, out
 
     def _serve_one(self, txn) -> None:
-        """One request's full lifecycle over an abstract transport
-        (`txn`): routing, QoS boundary, trace root, accounting,
-        response framing.  Both front ends — the threaded handler
-        (`_ThreadedTxn`) and the async event loop (`asyncserver`'s
-        `_AsyncTxn`) — drive requests through THIS method, so the
-        semantics at the QoS/trace/metrics boundary cannot drift
-        between them.  Runs on a handler thread (threaded) or a
-        worker-pool thread (async)."""
+        """One request's full lifecycle: routing, QoS boundary, trace
+        root, accounting, response framing.  `txn`
+        (`asyncserver._AsyncTxn`) is the seam between the HTTP state
+        machine on the event loop and this request core: the head and
+        body as the loop framed them, and the calls that put a
+        response back on the connection.  Runs on a worker-pool
+        thread."""
         server = self
         t0 = time.monotonic()
         root_span = None
@@ -3873,12 +3869,11 @@ class S3Server:
             if root_span is not None:
                 root_span.__enter__()
                 # What happened to the request before this thread had
-                # it (async front door): a body buffered on the loop,
-                # then the wait for a pool worker. Both END where the
-                # root starts, so they are phases of the request but no
-                # part of the root's (or api_request_duration_ms's)
-                # interval.
-                t_disp = getattr(txn, "t_dispatch", None)
+                # it: a body buffered on the loop, then the wait for a
+                # pool worker. Both END where the root starts, so they
+                # are phases of the request but no part of the root's
+                # (or api_request_duration_ms's) interval.
+                t_disp = txn.t_dispatch
                 if t_disp is not None:
                     if body:
                         TRACER.record("door.recv", root_span,
@@ -3966,21 +3961,20 @@ class S3Server:
                     trace_tree = root_span.finish()
             # Keep-alive hygiene: whatever the handler left unread
             # (auth failures, sheds, burnt deadlines, early errors)
-            # must not desync the next pipelined request. Policy is
-            # the transport's: threaded drains the remainder inline;
-            # async discards small tails loop-side and CLOSES past its
-            # cap (or when an Expect body was never solicited), per
+            # must not desync the next pipelined request. The
+            # transport discards small tails loop-side and CLOSES past
+            # its cap (or when an Expect body was never solicited), per
             # Content-Length. close_hdr = the response must carry
             # `Connection: close` so the client knows.
             close_hdr = txn.prepare_body_cleanup()
             resp_len = (int(resp.headers.get("Content-Length", 0))
                         if body_is_stream else len(resp.body))
 
-            # Atomic once-guard: on the async path the teardown safety
-            # net and the drain task's cleanup can (in pathological
-            # interleavings) both reach this from different pool
-            # threads — a bare flag's check-then-set window would
-            # account the request twice and double-release its slot.
+            # Atomic once-guard: the teardown safety net and the drain
+            # task's cleanup can (in pathological interleavings) both
+            # reach this from different pool threads — a bare flag's
+            # check-then-set window would account the request twice
+            # and double-release its slot.
             _fin_mu = threading.Lock()
             _finished = [False]
 
@@ -4119,9 +4113,8 @@ class S3Server:
                 # compression damage, GCM auth) arrive AFTER the
                 # 200 headers went out — the transport aborts the
                 # connection so the client sees a short body, never
-                # a clean success. The threaded transport drives
-                # the body inline; the async one DETACHES (returns
-                # True) and its loop pulls chunks, owning finish_fn
+                # a clean success. The transport DETACHES (returns
+                # True): its loop pulls the chunks and owns finish_fn
                 # from here.
                 detached = txn.stream_response(resp, raw_path,
                                                _finish_request,
@@ -4136,7 +4129,7 @@ class S3Server:
             # body wrote still gets its metrics/trace
             # accounted, and an open span context never leaks
             # into the next keep-alive request on this thread.
-            # A DETACHED response hands both duties to the async
+            # A DETACHED response hands both duties to the loop's
             # drain task (backstopped by connection teardown).
             if not detached:
                 if finish_fn is not None:
@@ -4148,33 +4141,25 @@ class S3Server:
 
     def start(self, host: str = "127.0.0.1", port: int = 0,
               cert_manager=None) -> int:
-        """Boot the front door. Default is the asyncio event-loop
-        listener (`s3/asyncserver.py`): accept/parse/keep-alive for
-        10k+ sockets on a handful of loop threads, request execution
-        on a bounded worker pool through the same `_serve_one` core.
-        `MINIO_FRONT_DOOR=threaded` keeps the legacy thread-per-
-        connection front end. cert_manager: utils.certs.CertManager
-        for HTTPS with hot-reloaded certificates (None = plaintext)."""
-        import os as _os
+        """Boot the front door, the asyncio event-loop listener
+        (`s3/asyncserver.py`): accept/parse/keep-alive for 10k+ sockets
+        on a handful of loop threads, request execution on a bounded
+        worker pool through `_serve_one`. cert_manager:
+        utils.certs.CertManager for HTTPS with hot-reloaded
+        certificates (None = plaintext). Returns the bound port, which
+        `self.address` keeps beside the host."""
         self.cert_manager = cert_manager
-        mode = _os.environ.get("MINIO_FRONT_DOOR",
-                               "async").strip().lower()
-        if mode == "threaded":
-            bound = self._start_threaded(host, port, cert_manager)
-        else:
-            from .asyncserver import AsyncFrontDoor
-            front = AsyncFrontDoor(self, cert_manager=cert_manager)
-            try:
-                bound = front.start(host, port)
-            except BaseException:
-                front.pool.shutdown(wait=False)
-                front.rpc_pool.shutdown(wait=False)
-                front.stream_pool.shutdown(wait=False)
-                raise
-            self._front_door = front
-            # Address shim: callers (webrpc port probe, tests) read
-            # `server._httpd.server_address` regardless of front end.
-            self._httpd = _BoundAddress(host, bound)
+        from .asyncserver import AsyncFrontDoor
+        front = AsyncFrontDoor(self, cert_manager=cert_manager)
+        try:
+            bound = front.start(host, port)
+        except BaseException:
+            front.pool.shutdown(wait=False)
+            front.rpc_pool.shutdown(wait=False)
+            front.stream_pool.shutdown(wait=False)
+            raise
+        self._front_door = front
+        self.address = (host, bound)
         # Timeline sampler: one process-wide daemon deltaing the
         # registry per sample period (refcounted — the last server to
         # stop stops it; its tick also drives kernprof's rate-limited
@@ -4207,159 +4192,6 @@ class S3Server:
             cert_manager.start()
         return bound
 
-    def _start_threaded(self, host: str, port: int,
-                        cert_manager) -> int:
-        """The legacy thread-per-connection front end
-        (MINIO_FRONT_DOOR=threaded): one OS thread per socket,
-        BaseHTTPRequestHandler framing, same `_serve_one` core."""
-        server = self
-
-        class Handler(BaseHTTPRequestHandler):
-            protocol_version = "HTTP/1.1"
-            # Socket timeout: a client that stops reading (streamed GET)
-            # or writing (streamed PUT) errors out and releases any held
-            # namespace lock instead of pinning it indefinitely (ref the
-            # reference's conn read/write deadlines, cmd/http/listener.go).
-            timeout = 120
-
-            def log_message(self, *args):  # silence
-                pass
-
-            def _reject(self, status: int, msg: str):
-                """Pre-dispatch framing error: terse close-delimited
-                response (the request body's extent is unknowable, so
-                keep-alive is off the table)."""
-                self.send_response(status, msg)
-                self.send_header("Content-Length", "0")
-                self.send_header("Connection", "close")
-                self.end_headers()
-                self.close_connection = True
-
-            def _handle(self):
-                try:
-                    length = int(self.headers.get("Content-Length", 0))
-                    raw_path, _, query = self.path.partition("?")
-                    headers = {k.lower(): v
-                               for k, v in self.headers.items()}
-                    te = headers.get("transfer-encoding", "").strip()
-                    if te:
-                        if te.lower() != "chunked":
-                            return self._reject(501, "Not Implemented")
-                        if "content-length" in headers:
-                            # CL + TE together is the classic request
-                            # smuggling vector: refuse outright.
-                            return self._reject(400, "Bad Request")
-                        if self.request_version == "HTTP/1.0":
-                            return self._reject(400, "Bad Request")
-                        return self._handle_chunked(
-                            raw_path, query, headers)
-                    # Large object PUTs stream: the socket body is never
-                    # buffered whole (ref the reference's streaming PUT
-                    # pipeline, cmd/erasure-encode.go:73).
-                    stream_body = (
-                        self.command == "PUT"
-                        and length >= server.stream_threshold
-                        and not raw_path.startswith("/minio-tpu/")
-                        and "/" in raw_path.lstrip("/"))
-                    if stream_body:
-                        from ..utils.streams import LimitReader
-                        body = b""
-                        body_stream = LimitReader(self.rfile, length)
-                    else:
-                        body = self.rfile.read(length) if length else b""
-                        body_stream = None
-                    txn = _ThreadedTxn(self, raw_path, query, headers,
-                                       body, body_stream, length)
-                    server._serve_one(txn)
-                except (BrokenPipeError, ConnectionResetError):
-                    pass
-
-            def _handle_chunked(self, raw_path, query, headers):
-                """Chunked Transfer-Encoding request body: object PUTs
-                stream the decoder straight into the erasure pipeline
-                (length -1 = unknown); everything else decodes to a
-                buffer first — same split as the async front door
-                (`asyncserver._HttpConn._begin_chunked`)."""
-                from .asyncserver import CHUNKED_BUF_MAX
-                from ..utils.streams import (ChunkedTEReader,
-                                             ChunkedTooLarge)
-                stream_body = (
-                    self.command == "PUT"
-                    and not raw_path.startswith("/minio-tpu/")
-                    and "/" in raw_path.lstrip("/"))
-                if stream_body:
-                    body = b""
-                    body_stream = ChunkedTEReader(
-                        self.rfile, MAX_OBJECT_SIZE + 1)
-                    length = -1
-                else:
-                    reader = ChunkedTEReader(self.rfile, CHUNKED_BUF_MAX)
-                    acc = bytearray()
-                    try:
-                        while True:
-                            piece = reader.read(64 * 1024)
-                            if not piece:
-                                break
-                            acc += piece
-                    except ChunkedTooLarge:
-                        return self._reject(413, "Payload Too Large")
-                    except ValueError:
-                        return self._reject(400, "Bad Request")
-                    body = bytes(acc)
-                    body_stream = None
-                    length = len(body)
-                txn = _ThreadedTxn(self, raw_path, query, headers,
-                                   body, body_stream, length)
-                server._serve_one(txn)
-
-            def do_OPTIONS(self):
-                """CORS preflight: unauthenticated by design (ref the
-                preflight path of the CORS middleware)."""
-                raw_path, _, _q = self.path.partition("?")
-                headers = {k.lower(): v for k, v in self.headers.items()}
-                status, hdrs = server.preflight(raw_path, headers)
-                self.send_response(status)
-                for k, v in hdrs:
-                    self.send_header(k, v)
-                self.end_headers()
-
-            do_GET = do_PUT = do_POST = do_DELETE = do_HEAD = _handle
-
-        class _Server(ThreadingHTTPServer):
-            # Keep-alive handler threads must never block shutdown
-            # (the reference's xhttp.Server drains with a deadline,
-            # cmd/http/server.go:117).
-            daemon_threads = True
-            block_on_close = False
-
-            def finish_request(self, request, client_address):
-                # TLS wraps PER CONNECTION in the handler thread — a
-                # wrapped LISTENING socket would run the blocking
-                # handshake inside the single accept loop, letting one
-                # silent client stall every new connection (trivial
-                # DoS). The handshake also gets the handler timeout.
-                if cert_manager is not None:
-                    import ssl as _ssl
-                    request.settimeout(Handler.timeout)
-                    try:
-                        request = cert_manager.context.wrap_socket(
-                            request, server_side=True)
-                    except (_ssl.SSLError, OSError, TimeoutError):
-                        try:
-                            request.close()
-                        except OSError:
-                            pass
-                        return
-                super().finish_request(request, client_address)
-
-        Handler.timeout = 120  # idle keep-alive reaper
-        self._httpd = _Server((host, port), Handler)
-        # mtpu-lint: disable=R1 -- the accept loop itself; request context is OPENED per request below it
-        self._thread = threading.Thread(target=self._httpd.serve_forever,
-                                        daemon=True)
-        self._thread.start()
-        return self._httpd.server_address[1]
-
     @property
     def notifier(self):
         return self.handlers.notifier if self.handlers else None
@@ -4385,11 +4217,9 @@ class S3Server:
                     del INCIDENTS.providers[key]
         if getattr(self, "cert_manager", None) is not None:
             self.cert_manager.stop()
-        if getattr(self, "_front_door", None) is not None:
+        if self._front_door is not None:
             # Graceful drain: stop accepting, let in-flight requests
-            # finish within the deadline, then abort stragglers —
-            # the SIGTERM semantics the threaded front end only
-            # approximated with abandoned daemon threads.
+            # finish within the deadline, then abort stragglers.
             import os as _os
             try:
                 drain = float(_os.environ.get(
@@ -4398,9 +4228,6 @@ class S3Server:
                 drain = 10.0
             self._front_door.stop(drain_s=drain)
             self._front_door = None
-        if self._httpd:
-            self._httpd.shutdown()
-            self._httpd.server_close()
         # Stop the layer's background daemons (MRF heal worker, disk
         # monitors, quarantine prober) — a stopped server's daemons
         # must not keep churning its disks (tests run many servers per
@@ -4414,113 +4241,3 @@ class S3Server:
             self.handlers.replication.close()
         if self.audit is not None:
             self.audit.close()
-
-
-class _BoundAddress:
-    """Duck-typed stand-in for the ThreadingHTTPServer attribute
-    surface the rest of the stack reads (`server_address`), when the
-    async front door owns the socket."""
-
-    def __init__(self, host: str, port: int):
-        self.server_address = (host, port)
-
-    def shutdown(self) -> None:
-        pass
-
-    def server_close(self) -> None:
-        pass
-
-
-class _ThreadedTxn:
-    """Transport adapter for the legacy thread-per-connection front
-    end: one request on a ThreadingHTTPServer handler thread, driven
-    through the same `S3Server._serve_one` core as the async front
-    door (`s3/asyncserver._AsyncTxn`)."""
-
-    def __init__(self, handler, raw_path: str, query: str,
-                 headers: dict, body: bytes, body_stream, length: int):
-        self.h = handler
-        self.command = handler.command
-        self.raw_path = raw_path
-        self.query = query
-        self.headers = headers
-        self.body = body
-        self.body_stream = body_stream  # raw LimitReader (or None)
-        self.content_length = length  # -1 = chunked (unknown)
-        self.rx_length = max(length, 0)
-        self.client_ip = handler.client_address[0]
-        self.close_after = False
-        self.detached = False
-
-    # -- body hygiene ---------------------------------------------------
-
-    def prepare_body_cleanup(self) -> bool:
-        """Keep-alive framing after an early response (shed, burnt
-        deadline, auth failure) left body bytes unread: drain the
-        remainder inline — per Content-Length, so the next pipelined
-        request can never desync. The handler THREAD pays for the
-        whole drain here, however large (this transport has no way to
-        linger a half-closed socket); the async front door instead
-        discards small tails loop-side and closes large ones with a
-        lingering FIN."""
-        bs = self.body_stream
-        if bs is None:
-            return False
-        if bs.remaining() <= 0:
-            return False
-        try:
-            while bs.read(64 * 1024):
-                pass
-        except (OSError, ValueError):
-            self.set_close()
-            return True
-        return False
-
-    def set_close(self) -> None:
-        self.h.close_connection = True
-        self.close_after = True
-
-    # -- response plumbing ----------------------------------------------
-
-    def send_head(self, status: int, headers: list) -> None:
-        self.h.send_response(status)
-        for k, v in headers:
-            self.h.send_header(k, v)
-        self.h.end_headers()
-
-    def write(self, data) -> None:
-        if data:
-            self.h.wfile.write(data)
-
-    def stream_response(self, resp, raw_path: str, finish_fn,
-                        root_span) -> bool:
-        """Drive the iterator body inline on this handler thread (the
-        threaded model: a slow reader parks the thread). Returns False
-        — never detaches; finish_fn runs here and again (idempotent)
-        in the core's finally."""
-        h = self.h
-        from ..obs.span import TRACER
-        try:
-            for chunk in resp.body:
-                if chunk:
-                    with TRACER.span("door.send", parent=root_span,
-                                     bytes=len(chunk)):
-                        h.wfile.write(chunk)
-        except (BrokenPipeError, ConnectionResetError):
-            raise
-        except Exception as e:  # noqa: BLE001
-            from ..logger import Logger
-            Logger.get().log_once(
-                f"streaming GET {raw_path} aborted "
-                f"mid-body: {type(e).__name__}: {e}",
-                "s3-stream-abort")
-            h.close_connection = True
-        finally:
-            close = getattr(resp.body, "close", None)
-            if close is not None:
-                close()
-            # Streaming: the trace closes only now, so it carries the
-            # lazy shard-read spans and the duration covers the body
-            # transfer.
-            finish_fn()
-        return False

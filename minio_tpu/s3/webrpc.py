@@ -269,8 +269,8 @@ class WebHandlers:
                 "region": self.server.region}
 
     def server_port(self) -> int:
-        httpd = self.server._httpd
-        return httpd.server_address[1] if httpd else 0
+        address = self.server.address
+        return address[1] if address else 0
 
     # -- raw upload / download (ref /minio/upload|download routes) -------
 

@@ -1,9 +1,9 @@
 """JAX's persistent compilation cache, placed once for every launcher.
 
-Every process that jits calls ``configure()`` before its first jit:
-the server entry (``python -m minio_tpu server`` — which is how
-``chip_smoke.py``, itself off JAX, gets it), ``tools/device_bench.py``,
-``tools/tpu_tune.py`` and ``bench.py``:
+Every process that jits calls ``configure()`` before its first jit.
+The server entry does (``python -m minio_tpu server``, ``__main__.main``),
+which is how ``chip_smoke.py``, itself off JAX, and the benchmark's
+serving child get it:
 
 - where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
   nothing is set in code — whoever placed the cache from outside owns

@@ -36,9 +36,8 @@ the worker waited on a full queue; stage=consume: the consumer waited
 on an empty one), and stalls above ``STALL_EVENT_S`` land as events on
 the active trace span — so `mc admin trace` shows exactly where a
 pipelined request lost its overlap. ``PIPE_STATS`` aggregates per-run
-busy/stall/wall seconds so bench.py can print an overlap factor
-(sum of stage busy time / wall time; > 1.0 means stages truly ran
-concurrently).
+busy/stall/wall seconds into an overlap factor (sum of stage busy time
+/ wall time; > 1.0 means stages truly ran concurrently).
 """
 
 from __future__ import annotations
@@ -289,8 +288,9 @@ class Prefetch:
         error response behind the client's own stall. An abandoned
         worker consumes at most its current item (the stop flag is
         checked before every next one), drops it, and exits; callers
-        whose source is a request body rely on LimitReader's atomic
-        reads to keep connection framing exact through that window."""
+        whose source is a request body rely on its atomic reads
+        (`s3/asyncserver.BodyBridge.read`, under one condition) to keep
+        connection framing exact through that window."""
         if self._closed:
             return
         self._closed = True

@@ -63,128 +63,6 @@ class IterReader(Reader):
         return out
 
 
-class LimitReader(Reader):
-    """Caps a file-like object at `limit` bytes (an HTTP body whose
-    socket stays open past Content-Length).
-
-    Reads are atomic (one lock): when a pipelined PUT fails mid-stream,
-    the server's keep-alive drain loop may briefly overlap with the
-    pipeline worker finishing its current batch read — serialized reads
-    keep the byte accounting (and therefore the connection framing)
-    exact no matter which thread consumes the remainder."""
-
-    def __init__(self, f, limit: int):
-        import threading
-        self._f = f
-        self._left = limit
-        self._mu = threading.Lock()
-
-    def read(self, n: int) -> bytes:
-        with self._mu:
-            if self._left <= 0:
-                return b""
-            chunk = self._f.read(min(n, self._left))
-            self._left -= len(chunk)
-            return chunk
-
-    def remaining(self) -> int:
-        """Bytes of the capped window not yet consumed — the front
-        door's keep-alive hygiene reads this to decide drain vs close
-        for an abandoned body."""
-        with self._mu:
-            return self._left
-
-
-class ChunkedTEReader(Reader):
-    """Incremental ``Transfer-Encoding: chunked`` decoder over a
-    blocking socket file (the threaded front door's ``rfile``): read(n)
-    returns DECODED payload bytes, b'' once the terminal 0-chunk and
-    trailer section have been consumed. The async front door decodes
-    the same framing loop-side (`s3/asyncserver._ChunkedTEParser`);
-    this is its pull-model twin so both doors accept chunked bodies.
-
-    Framing errors raise ValueError; exceeding `max_decoded` raises
-    ChunkedTooLarge (a ValueError) so the caller can answer 413 vs 400.
-    remaining() is 0 only after clean EOF — an abandoned chunked body
-    has no byte count to drain by, so keep-alive hygiene must close."""
-
-    MAX_LINE = 8192          # chunk-size line incl. extensions
-    MAX_TRAILER = 16 * 1024  # total trailer-section bytes
-
-    def __init__(self, f, max_decoded: int = -1):
-        self._f = f
-        self._left = 0        # payload bytes left in current chunk
-        self._need_crlf = False
-        self._done = False
-        self._decoded = 0
-        self._max = max_decoded
-
-    def _read_line(self) -> bytes:
-        line = self._f.readline(self.MAX_LINE + 2)
-        if not line:
-            raise ValueError("chunked body: EOF inside framing")
-        if not line.endswith(b"\n"):
-            raise ValueError("chunked body: framing line too long")
-        return line.strip(b"\r\n")
-
-    def _consume_crlf(self) -> None:
-        b = self._f.read(1)
-        if b == b"\r":
-            b = self._f.read(1)
-        if b != b"\n":
-            raise ValueError("chunked body: missing CRLF after chunk")
-
-    def _next_chunk(self) -> None:
-        line = self._read_line()
-        size_s = line.split(b";", 1)[0].strip()
-        try:
-            size = int(size_s, 16)
-        except ValueError:
-            raise ValueError(
-                f"chunked body: bad chunk size {size_s[:32]!r}") from None
-        if size == 0:
-            total = 0
-            while True:
-                t = self._read_line()
-                if not t:
-                    break
-                total += len(t)
-                if total > self.MAX_TRAILER:
-                    raise ValueError("chunked body: trailer too large")
-            self._done = True
-            return
-        if self._max >= 0 and self._decoded + size > self._max:
-            raise ChunkedTooLarge("chunked body exceeds size cap")
-        self._left = size
-
-    def read(self, n: int) -> bytes:
-        if self._done or n <= 0:
-            return b""
-        while self._left == 0:
-            if self._need_crlf:
-                self._consume_crlf()
-                self._need_crlf = False
-            self._next_chunk()
-            if self._done:
-                return b""
-        take = min(n, self._left)
-        data = self._f.read(take)
-        if len(data) < take:
-            raise ValueError("chunked body: EOF inside chunk data")
-        self._left -= take
-        self._decoded += take
-        if self._left == 0:
-            self._need_crlf = True
-        return data
-
-    def remaining(self) -> int:
-        return 0 if self._done else 1
-
-
-class ChunkedTooLarge(ValueError):
-    """Decoded chunked body crossed the caller's cap (413, not 400)."""
-
-
 class PushbackReader(Reader):
     """Prepends already-consumed bytes back onto an inner reader (the
     one-byte lookahead the PUT pipeline uses to tell a final

@@ -1,10 +1,9 @@
-"""Chunked Transfer-Encoding request bodies on BOTH front doors:
+"""Chunked Transfer-Encoding request bodies at the front door:
 byte-exact round-trips for plain-SigV4 and streaming-SigV4 (aws-chunked
 inside chunked TE) object PUTs, keep-alive reuse after a chunked PUT,
 broken chunk-signature chains, torn mid-chunk aborts (admission-slot
 release proven), the smuggling rejects (CL+TE, non-chunked TE,
-HTTP/1.0), and the buffered-path cap. Parametrized over the async and
-threaded doors — parity IS the acceptance criterion."""
+HTTP/1.0), and the buffered-path cap."""
 
 import os
 import socket
@@ -20,15 +19,9 @@ from minio_tpu.storage.xl import XLStorage
 
 ACCESS, SECRET = "chunkak1", "chunk-secret-1"
 
-_forced_threaded = os.environ.get(
-    "MINIO_FRONT_DOOR", "").strip().lower() == "threaded"
-DOORS = ["threaded"] if _forced_threaded else ["async", "threaded"]
-
-
-@pytest.fixture(params=DOORS)
-def door(request, tmp_path, monkeypatch):
-    """(srv, port, client) on the requested front door, bucket ready."""
-    monkeypatch.setenv("MINIO_FRONT_DOOR", request.param)
+@pytest.fixture
+def door(tmp_path):
+    """(srv, port, client) on a started server, bucket ready."""
     disks = [XLStorage(str(tmp_path / f"disk{i}")) for i in range(4)]
     layer = ErasureObjects(disks, 2, 2, block_size=256 * 1024)
     srv = S3Server(layer, ACCESS, SECRET)
@@ -333,8 +326,7 @@ def test_blocked_loop_torn_chunked_put_releases_slot(door):
     a half-sent chunked PUT while every front-door loop is deliberately
     blocked 400ms. The abort must still release the admission slot and
     store nothing — a stalled loop delays teardown, it must never
-    swallow it. (On the threaded door the block lands on the loopmon
-    census only; the abort path is the same assertion.)"""
+    swallow it."""
     from minio_tpu.obs import loopmon
     srv, port, cl = door
     payload = os.urandom(300_000)
@@ -344,11 +336,8 @@ def test_blocked_loop_torn_chunked_put_releases_slot(door):
     s.sendall(head + f"{len(aws):x}\r\n".encode() + aws[:30_000])
     time.sleep(0.2)
     # ...block every loop while the body is half-read...
-    front = getattr(srv, "_front_door", None)
-    if front is not None:
-        for loop in front._loops:
-            loop.call_soon_threadsafe(loopmon._injected_loop_block,
-                                      0.4)
+    for loop in srv._front_door._loops:
+        loop.call_soon_threadsafe(loopmon._injected_loop_block, 0.4)
     # ...and walk away mid-stall.
     s.close()
     _wait_inflight_zero(srv)

@@ -1,11 +1,11 @@
-"""Async front door tests: event-loop serving semantics that the
-shared request core + `s3/asyncserver.py` must uphold — keep-alive
+"""Front door tests: event-loop serving semantics that the request
+core + `s3/asyncserver.py` must uphold — keep-alive
 framing after sheds/burnt deadlines (drain-or-close per
 Content-Length), Expect: 100-continue gating (admission before
 upload), admission-slot release tied to connection teardown, pipelined
-requests, graceful drain, connection-plane metrics, the threaded
-fallback, and the high-concurrency asyncio loadgen. All fast —
-tier-1."""
+requests, graceful drain, connection-plane metrics, the
+single-listener fallback, and the high-concurrency asyncio loadgen.
+All fast — tier-1."""
 
 import http.client
 import os
@@ -23,17 +23,6 @@ from minio_tpu.s3.server import S3Server
 from minio_tpu.storage.xl import XLStorage
 
 ACCESS, SECRET = "fdadmin1", "fdadmin-secret1"
-
-# Most of this module asserts ASYNC-path semantics (bridged bodies,
-# lazy 100-continue, conns gauges); a tier-1 run forced onto the
-# legacy path (MINIO_FRONT_DOOR=threaded env) skips those rather than
-# failing on behavior that path never promised.
-_forced_threaded = os.environ.get(
-    "MINIO_FRONT_DOOR", "").strip().lower() == "threaded"
-needs_async_front = pytest.mark.skipif(
-    _forced_threaded,
-    reason="MINIO_FRONT_DOOR=threaded forces the legacy front end")
-
 
 def _start_server(tmp_path, n_disks=4, k=2, m=2):
     disks = [XLStorage(str(tmp_path / f"disk{i}"))
@@ -188,7 +177,6 @@ def test_burnt_deadline_keepalive_second_request_ok(tmp_path):
 # ---------------- Expect: 100-continue ----------------
 
 
-@needs_async_front
 def test_expect_100_continue_put_roundtrip(tmp_path):
     """A PUT with Expect: 100-continue gets the interim 100 BEFORE the
     body is read, then a 200; the bytes land exactly."""
@@ -214,7 +202,6 @@ def test_expect_100_continue_put_roundtrip(tmp_path):
         srv.stop()
 
 
-@needs_async_front
 def test_expect_shed_answers_before_body_and_closes(tmp_path):
     """QoS admission runs BEFORE the body upload: a shed Expect-PUT is
     answered 503 with NO interim 100, carries Connection: close (the
@@ -251,7 +238,6 @@ def test_expect_shed_answers_before_body_and_closes(tmp_path):
 # ---------------- teardown-tied slot release ----------------
 
 
-@needs_async_front
 def test_aborted_mid_body_put_releases_slot(tmp_path):
     """A client that dies mid-upload of a STREAMING body must unwind
     the blocked worker and release its admission slot (structural:
@@ -328,7 +314,6 @@ def test_pipelined_requests_same_socket(tmp_path):
         srv.stop()
 
 
-@needs_async_front
 def test_half_close_after_request_still_answered(tmp_path):
     """A client that shutdown(SHUT_WR)s after sending its request
     (Go-style CloseWrite) must still receive the full response."""
@@ -350,7 +335,6 @@ def test_half_close_after_request_still_answered(tmp_path):
         srv.stop()
 
 
-@needs_async_front
 def test_half_close_with_pipelined_request_answers_both(tmp_path):
     """sendall(reqA + reqB) then CloseWrite: BOTH responses arrive
     before the server closes — a buffered pipelined request must not
@@ -377,7 +361,6 @@ def test_half_close_with_pipelined_request_answers_both(tmp_path):
         srv.stop()
 
 
-@needs_async_front
 def test_malformed_head_rejected_and_counted(tmp_path):
     srv, port = _start_server(tmp_path)
     try:
@@ -477,7 +460,6 @@ def test_graceful_stop_finishes_inflight_request(tmp_path,
 # ---------------- connection-plane observability ----------------
 
 
-@needs_async_front
 def test_connection_metrics_and_timeline_row(tmp_path):
     srv, port = _start_server(tmp_path)
     try:
@@ -514,57 +496,9 @@ def test_connection_metrics_and_timeline_row(tmp_path):
         srv.stop()
 
 
-# ---------------- threaded fallback ----------------
-
-
-def test_threaded_front_door_still_serves(tmp_path, monkeypatch):
-    """MINIO_FRONT_DOOR=threaded keeps the legacy path working through
-    the same request core — including the shed keep-alive fix."""
-    monkeypatch.setenv("MINIO_FRONT_DOOR", "threaded")
-    srv, port = _start_server(tmp_path)
-    try:
-        assert srv._front_door is None  # really the threaded path
-        client = S3Client("127.0.0.1", port, ACCESS, SECRET)
-        client.make_bucket("bkt")
-        body = os.urandom(128 * 1024)
-        assert client.put_object("bkt", "k", body).status == 200
-        got = client.get_object("bkt", "k")
-        assert got.status == 200 and got.body == body
-        # Shed + keep-alive on the threaded path too.
-        srv.config.set_kv("api requests_max_write=1 "
-                          "requests_deadline=200ms")
-        held = srv.qos.acquire("write")
-        conn = http.client.HTTPConnection("127.0.0.1", port,
-                                          timeout=30)
-        try:
-            small = b"z" * 2048
-            conn.request("PUT", "/bkt/s1", body=small,
-                         headers=_signed_headers("PUT", "/bkt/s1",
-                                                 small, port))
-            r1 = conn.getresponse()
-            r1.read()
-            assert r1.status == 503
-            held.release()
-            conn.request("PUT", "/bkt/s2", body=small,
-                         headers=_signed_headers("PUT", "/bkt/s2",
-                                                 small, port))
-            r2 = conn.getresponse()
-            r2.read()
-            assert r2.status == 200
-        finally:
-            held.release()
-            conn.close()
-        srv.config.set_kv("api requests_max_write=0 "
-                          "requests_deadline=10s")
-        _wait_inflight_zero(srv)
-    finally:
-        srv.stop()
-
-
 # ---------------- high-concurrency loadgen ----------------
 
 
-@needs_async_front
 def test_async_loadgen_closed_loop(tmp_path):
     """The asyncio driver holds a keep-alive fleet, mixes signed
     PUT/GET closed-loop, and reports per-class connect/TTFB/total
@@ -603,7 +537,6 @@ def test_async_loadgen_closed_loop(tmp_path):
 # ---------------- loop-under-stall (loopmon satellite) ----------------
 
 
-@needs_async_front
 def test_blocked_loop_put_completes_and_releases_slots(tmp_path):
     """The loopmon stall scenario against real traffic: every
     front-door loop gets a deliberate 400ms block while a PUT is in

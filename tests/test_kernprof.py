@@ -283,10 +283,9 @@ def test_probe_all_reports_every_backend():
 
 
 def test_put_path_overhead_paired_on_off(tmp_path):
-    """Tripwire, not the acceptance number: bench.py's put_p50 carries
-    the <=1% paired-delta claim on 1 MiB bodies; this guards against a
-    catastrophic regression (e.g. sampling moved onto the hot path)
-    with bounds loose enough for a loaded 2-core CI box."""
+    """Tripwire, not a measurement: guards against a catastrophic
+    regression (e.g. sampling moved onto the hot path) with bounds
+    loose enough for a loaded 2-core CI box."""
     from minio_tpu.erasure.engine import ErasureObjects
     from minio_tpu.obs.timeline import TIMELINE
     from minio_tpu.s3.client import S3Client

@@ -416,17 +416,14 @@ def _client(port):
 
 
 def test_server_config_validation_reload_and_profile(tmp_path):
-    import os
     srv, port = _start_server(tmp_path)
     try:
         c = _client(port)
         # Boot applied the defaults: stall bar + profiler running.
         assert LOOPMON.stall_ms == 250.0
         assert LOOPMON.profiler.running is True
-        if os.environ.get(
-                "MINIO_FRONT_DOOR", "").strip().lower() != "threaded":
-            # Front-door loops and the RPC loop are registered.
-            assert _wait(lambda: "s3-0" in LOOPMON.lag_census())
+        # Front-door loops and the RPC loop are registered.
+        assert _wait(lambda: "s3-0" in LOOPMON.lag_census())
         # Live reload.
         r = c.request("POST", "/minio-tpu/admin/v1/set-config-kv",
                       body=b"obs loop_stall_ms=100")

@@ -654,7 +654,7 @@ def test_loadgen_against_capped_server(tmp_path):
             return orig_put(*a, **kw)
 
         srv.handlers.layer.put_object = slow_put
-        report = run_load("127.0.0.1", srv._httpd.server_address[1],
+        report = run_load("127.0.0.1", srv.address[1],
                           ACCESS, SECRET, "bench", concurrency=6,
                           duration=1.5, put_fraction=1.0,
                           object_bytes=2048)

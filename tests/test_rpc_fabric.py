@@ -1,6 +1,6 @@
-"""Async RPC fabric (rpc/aio.py): semantic parity with the threaded
-transport — offline gate + PR-6 jittered reconnect probe, stale-pool
-single-shot retry, deadline fast-fail/capping without offline marks,
+"""The peer-RPC client (rpc/aio.py): offline gate + PR-6 jittered
+reconnect probe, stale-pool single-shot retry, pool teardown on
+close, deadline fast-fail/capping without offline marks,
 the in-flight census behind the zero-thread-per-call claim, peer
 fan-out, and HTTP/1.1 pipelining. All against a real wire server (an
 S3Server front door serving an RPCRegistry), so the bytes on the
@@ -16,17 +16,14 @@ from minio_tpu.qos.deadline import (Deadline, DeadlineExceeded,
                                     deadline_scope)
 from minio_tpu.rpc import aio
 from minio_tpu.rpc.cluster import derive_cluster_key
+from minio_tpu.rpc.storage import RemoteStorage, StorageRPCService
 from minio_tpu.rpc.transport import RPCClient, RPCRegistry
 from minio_tpu.s3.server import S3Server
 from minio_tpu.storage import errors as serr
+from minio_tpu.storage.xl import XLStorage
 
 ACCESS, SECRET = "fabricak1", "fabric-secret-1"
 KEY = derive_cluster_key(ACCESS, SECRET)
-
-needs_async_fabric = pytest.mark.skipif(
-    not aio.fabric_async(),
-    reason="MINIO_RPC_FABRIC=threaded forces the legacy transport")
-
 
 class _EchoService:
     """Registry service exercising every fabric path: echo (request/
@@ -88,14 +85,13 @@ def _free_port() -> int:
 # ---------------- round trip + pool reuse ----------------
 
 
-@needs_async_fabric
 def test_async_call_roundtrip_and_pool_reuse(echo_server):
     port, _svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
     try:
         res, data = cl.call("test", "echo", {"x": 1}, b"payload")
         assert res["echo"] == 1 and data == b"payload"
-        st = cl._aio_state  # exists only when the async fabric served
+        st = cl._aio_state
         assert len(st.pool) == 1
         res2, _ = cl.call("test", "echo", {"x": 2})
         assert res2["echo"] == 2
@@ -105,16 +101,25 @@ def test_async_call_roundtrip_and_pool_reuse(echo_server):
         cl.close()
 
 
-def test_threaded_fabric_parity(monkeypatch, echo_server):
-    """The escape hatch serves the identical call surface."""
-    monkeypatch.setenv("MINIO_RPC_FABRIC", "threaded")
+def test_close_drops_the_pool_and_a_later_call_reconnects(echo_server):
+    """close() closes what the client still owns, its keep-alives on
+    the RPC loop: the pooled socket is shut (not just forgotten), the
+    pool's generation moves on, and the client stays usable."""
     port, _svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
     try:
-        res, data = cl.call("test", "echo", {"x": 7}, b"pp")
-        assert res["echo"] == 7 and data == b"pp"
-        assert getattr(cl, "_aio_state", None) is None
-        assert aio.CENSUS.current() == 0  # threaded calls counted too
+        assert cl.call("test", "echo", {"x": 1})[0]["echo"] == 1
+        st = cl._aio_state
+        (conn,), gen = st.pool, st.gen
+        cl.close()
+        deadline = time.monotonic() + 5
+        while st.pool and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert st.pool == [] and st.gen == gen + 1
+        assert conn.writer.is_closing()
+        assert cl.call("test", "echo", {"x": 2})[0]["echo"] == 2
+        (fresh,) = st.pool
+        assert fresh is not conn and fresh.gen == st.gen
     finally:
         cl.close()
 
@@ -122,11 +127,9 @@ def test_threaded_fabric_parity(monkeypatch, echo_server):
 # ---------------- offline gate: PR-6 jittered reconnect probe -------
 
 
-@needs_async_fabric
 def test_async_offline_gate_inherits_jittered_window():
-    """Satellite regression: a failed async call marks the peer
-    offline through the SAME jittered window as the threaded
-    transport — repeated marks spread over [OFFLINE_RETRY,
+    """A failed call marks the peer offline through the jittered
+    window — repeated marks spread over [OFFLINE_RETRY,
     (1+J) x OFFLINE_RETRY] (no reconnect thundering herd), and while
     offline, calls fast-fail without touching the socket."""
     cl = RPCClient("127.0.0.1", _free_port(), KEY, timeout=2.0)
@@ -168,12 +171,11 @@ class _DeadWriter:
         pass
 
 
-@needs_async_fabric
 def test_stale_pooled_conn_retries_once_on_fresh_socket(echo_server):
     """A reused connection failing BEFORE any response byte retries
-    exactly once on a fresh socket — the peer-restart case the sync
-    pool handles — and the success neither marks the peer offline nor
-    surfaces the transient."""
+    exactly once on a fresh socket — the peer-restart case — and the
+    success neither marks the peer offline nor surfaces the
+    transient."""
     port, _svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
     try:
@@ -189,7 +191,6 @@ def test_stale_pooled_conn_retries_once_on_fresh_socket(echo_server):
         cl.close()
 
 
-@needs_async_fabric
 def test_peer_restart_keep_alive_survives(echo_server):
     """End-to-end reconnect storm check: pool a keep-alive, restart
     the peer on the same port, call again — the fabric recovers on
@@ -224,7 +225,6 @@ def test_peer_restart_keep_alive_survives(echo_server):
 # ---------------- deadline semantics ----------------
 
 
-@needs_async_fabric
 def test_deadline_fast_fail_before_dispatch(echo_server):
     port, _svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
@@ -237,7 +237,6 @@ def test_deadline_fast_fail_before_dispatch(echo_server):
         cl.close()
 
 
-@needs_async_fabric
 def test_deadline_caps_timeout_and_never_marks_offline(echo_server):
     port, _svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
@@ -255,7 +254,6 @@ def test_deadline_caps_timeout_and_never_marks_offline(echo_server):
 # ---------------- census: the zero-thread claim ----------------
 
 
-@needs_async_fabric
 def test_inflight_census_counts_without_thread_growth(echo_server):
     """64 concurrent peer calls in flight on the ONE loop thread: the
     census sees them all while the process thread count stays flat on
@@ -305,7 +303,6 @@ def test_timeline_sample_carries_rpc_census():
 # ---------------- peer fan-out ----------------
 
 
-@needs_async_fabric
 def test_fanout_parallel_results_and_per_peer_errors(echo_server):
     port, _svc = echo_server
     cl_up = RPCClient("127.0.0.1", port, KEY)
@@ -321,7 +318,6 @@ def test_fanout_parallel_results_and_per_peer_errors(echo_server):
         cl_down.close()
 
 
-@needs_async_fabric
 def test_fanout_nowait_delivers_and_returns_immediately(echo_server):
     port, svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
@@ -348,7 +344,6 @@ def test_fanout_declines_non_rpcclient_peers():
 # ---------------- HTTP/1.1 pipelining ----------------
 
 
-@needs_async_fabric
 def test_pipeline_streams_chunks_in_order(echo_server):
     port, svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
@@ -366,7 +361,6 @@ def test_pipeline_streams_chunks_in_order(echo_server):
         cl.close()
 
 
-@needs_async_fabric
 def test_pipeline_error_surfaces_and_aborts(echo_server):
     port, svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
@@ -383,7 +377,6 @@ def test_pipeline_error_surfaces_and_aborts(echo_server):
         cl.close()
 
 
-@needs_async_fabric
 def test_pipeline_respects_deadline(echo_server):
     port, _svc = echo_server
     cl = RPCClient("127.0.0.1", port, KEY)
@@ -395,3 +388,82 @@ def test_pipeline_respects_deadline(echo_server):
                 pipe.finish()
     finally:
         cl.close()
+
+
+# ---------------- RemoteStorage.create_file over an iterator ----------
+
+
+class _RecordingStorageService(StorageRPCService):
+    """The storage service, noting the order its writes arrived in."""
+
+    def __init__(self, disks):
+        super().__init__(disks)
+        self.writes: list[tuple[str, int]] = []
+
+    def rpc_create_file(self, a, p):
+        self.writes.append(("create_file", len(p)))
+        return super().rpc_create_file(a, p)
+
+    def rpc_append_file(self, a, p):
+        self.writes.append(("append_file", len(p)))
+        return super().rpc_append_file(a, p)
+
+
+CHUNK_LISTS = {
+    "several_chunks": [b"a" * 70_000, b"b" * 70_000, b"c" * 123],
+    "one_chunk": [b"only"],
+    "empty_iterator": [],
+}
+
+
+@pytest.mark.parametrize("chunks", list(CHUNK_LISTS))
+@pytest.mark.parametrize("client_kind", ["in_process", "peer"])
+def test_remote_create_file_streams_an_iterator(tmp_path, monkeypatch,
+                                                client_kind, chunks):
+    """First chunk creates, the rest append, an empty iterator still
+    creates the file. A real peer gets the frames down one pipelined
+    connection; an in-process (or test-double) client, which the RPC
+    loop cannot speak to, gets one plain call a chunk. Same writes in
+    the same order, same file."""
+    from minio_tpu.parallel import quorum
+    # RemoteStorage latches this for the process; give it back.
+    monkeypatch.setattr(quorum, "FORCE_THREADS", quorum.FORCE_THREADS)
+    local = XLStorage(str(tmp_path / "disk"))
+    local.make_volume("vol")
+    svc = _RecordingStorageService({local.root: local})
+    plain_calls = []
+    real_call = RemoteStorage._call
+
+    def spy(self, method, args=None, payload=b""):
+        plain_calls.append(method)
+        return real_call(self, method, args, payload)
+
+    monkeypatch.setattr(RemoteStorage, "_call", spy)
+    srv = client = None
+    if client_kind == "peer":
+        reg = RPCRegistry(KEY)
+        reg.register("storage", svc)
+        srv = S3Server(None, ACCESS, SECRET, rpc_registry=reg)
+        client = RPCClient("127.0.0.1", srv.start("127.0.0.1", 0), KEY)
+    else:
+        class _InProcess:
+            def call(self, service, method, args, payload=b""):
+                return getattr(svc, f"rpc_{method}")(args, payload)
+        client = _InProcess()
+    try:
+        pieces = CHUNK_LISTS[chunks]
+        RemoteStorage(client, local.root).create_file(
+            "vol", "f/stream.bin", iter(pieces))
+        want = [("create_file", len(pieces[0]))] + [
+            ("append_file", len(p)) for p in pieces[1:]
+        ] if pieces else [("create_file", 0)]
+        assert svc.writes == want
+        assert local.read_all("vol", "f/stream.bin") == b"".join(pieces)
+        if client_kind == "peer":
+            assert plain_calls == []  # every frame rode the pipeline
+        else:
+            assert plain_calls == [m for m, _ in want]
+    finally:
+        if srv is not None:
+            client.close()
+            srv.stop()

@@ -202,8 +202,7 @@ def test_streaming_create_file_local(tmp_path):
 def _drain_probe_ladder():
     """The first dispatch (or a server boot) kicks the background
     probe ladder; its probe buffers would land inside the memory
-    tests' tracemalloc windows — drain it first, same reason bench.py
-    drains before its paired measurements."""
+    tests' tracemalloc windows — drain it first."""
     from minio_tpu.ops.autotune import AUTOTUNE
     t = AUTOTUNE._probe_thread
     if t is not None and t.is_alive():

@@ -2,9 +2,8 @@
 PUT/GET, latency percentiles on stdout. Dependency-free — drives the
 server with the same stdlib SigV4 client the test suite uses.
 
-Used by tests/test_qos.py and the bench.py `qos_brownout` config to
-prove the admission layer sheds with 503 SlowDown under overload
-instead of queueing unboundedly.
+Used by tests/test_qos.py to prove the admission layer sheds with 503
+SlowDown under overload instead of queueing unboundedly.
 
 CLI:
     python -m tools.loadgen --port 9000 --bucket bench \\
