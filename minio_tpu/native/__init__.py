@@ -67,7 +67,8 @@ def _compile(srcs: list[str], so: str) -> None:
 def _build_and_load() -> ctypes.CDLL | None:
     srcs = [os.path.join(_HERE, "highwayhash.cc"),
             os.path.join(_HERE, "lzblock.cc"),
-            os.path.join(_HERE, "rs.cc")]
+            os.path.join(_HERE, "rs.cc"),
+            os.path.join(_HERE, "fsops.cc")]
     try:
         so = os.path.join(
             _BUILD_DIR, f"libminio_tpu_native-{_build_key(srcs)}.so")
@@ -98,6 +99,19 @@ def _build_and_load() -> ctypes.CDLL | None:
                                        ctypes.c_size_t, ctypes.c_void_p,
                                        ctypes.c_size_t]
         lib.rs_gf_apply_mt.restype = None
+        path = ctypes.c_char_p
+        lib.fs_append.argtypes = [path, path, path, ctypes.c_void_p,
+                                  ctypes.c_size_t]
+        lib.fs_append.restype = ctypes.c_int
+        lib.fs_commit_stage.argtypes = [
+            path, path, path, path, path, path, path, path,
+            ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_long)]
+        lib.fs_commit_stage.restype = ctypes.c_int
+        lib.fs_commit_meta.argtypes = [
+            path, path, ctypes.c_void_p, ctypes.c_size_t, path, path,
+            path, path, path, path]
+        lib.fs_commit_meta.restype = ctypes.c_int
         return lib
     except Exception as exc:
         # Every native lane is now off for the process: say so, and
@@ -291,3 +305,68 @@ def lzb_decompress_native(blob: bytes, out_size: int) -> bytes | None:
     if got < 0:
         raise ValueError("corrupt lzb block")
     return out.raw[:got]
+
+
+# --- a local drive's leg of a PUT (native/fsops.cc; storage/xl.py) ---
+#
+# Results: 0, a positive errno, or one of these typed conditions.
+FS_SRC_VOLUME_NOT_FOUND = -1
+FS_DST_VOLUME_NOT_FOUND = -2
+FS_STAGE_NOT_FOUND = -3
+# fs_commit_stage's answer for an xl.meta larger than the buffer it
+# was handed: the caller reads the file itself.
+FS_META_TOO_BIG = object()
+_FS_META_CAP = 64 * 1024
+# One xl.meta read buffer a thread, so that a commit stays two native
+# calls (a buffer malloc'd in C would need a third to free it).
+_FS_TLS = threading.local()
+
+
+def fs_append(lib: ctypes.CDLL, full: bytes, vol: bytes,
+              sys_tmp: bytes | None, data) -> int:
+    """XLStorage.append_file's system calls in one GIL-free call. The
+    payload is handed over without a copy: ``bytes`` as they are, any
+    other C-contiguous buffer through a numpy view (which raises for a
+    strided one, as a file's ``write`` does)."""
+    if isinstance(data, bytes):
+        return lib.fs_append(full, vol, sys_tmp, data, len(data))
+    import numpy as np
+    view = np.frombuffer(data, dtype=np.uint8)
+    return lib.fs_append(full, vol, sys_tmp, view.ctypes.data, view.size)
+
+
+def fs_commit_stage(lib: ctypes.CDLL, src_vol: bytes,
+                    src_sys_tmp: bytes | None, dst_vol: bytes,
+                    dst_sys_tmp: bytes | None, dst_obj_dir: bytes,
+                    src_dd: bytes | None, dst_dd: bytes | None,
+                    xl_meta: bytes):
+    """XLStorage._rename_data up to the XLMeta merge. Returns
+    ``(result, raw, read_ms)``: `raw` is the destination's xl.meta,
+    None when there is none yet, FS_META_TOO_BIG when the caller has to
+    read it; `read_ms` what reading it took, on the drive's side of the
+    GIL."""
+    buf = getattr(_FS_TLS, "meta", None)
+    if buf is None:
+        buf = _FS_TLS.meta = ctypes.create_string_buffer(_FS_META_CAP)
+    n, ns = ctypes.c_long(-1), ctypes.c_long(0)
+    rc = lib.fs_commit_stage(src_vol, src_sys_tmp, dst_vol, dst_sys_tmp,
+                             dst_obj_dir, src_dd, dst_dd, xl_meta,
+                             buf, _FS_META_CAP,
+                             ctypes.byref(n), ctypes.byref(ns))
+    raw = None
+    if rc == 0 and n.value != -1:
+        raw = FS_META_TOO_BIG if n.value < 0 else buf[:n.value]
+    return rc, raw, ns.value / 1e6
+
+
+def fs_commit_meta(lib: ctypes.CDLL, tmp: bytes, xl_meta: bytes,
+                   blob: bytes, dst_vol: bytes,
+                   dst_sys_tmp: bytes | None, dst_obj_dir: bytes,
+                   old_dd: bytes | None, intent: bytes,
+                   stage_dir: bytes) -> int:
+    """XLStorage._rename_data after the merge: `blob` becomes the new
+    xl.meta through a temporary, then the replaced data dir, the intent
+    breadcrumb and the stage directory go."""
+    return lib.fs_commit_meta(tmp, xl_meta, blob, len(blob), dst_vol,
+                              dst_sys_tmp, dst_obj_dir, old_dd, intent,
+                              stage_dir)

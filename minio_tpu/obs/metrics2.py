@@ -361,6 +361,12 @@ METRICS2.register(
     "minio_tpu_v2_disk_op_duration_ms", "histogram",
     "Per-disk storage call latency in milliseconds, by op.")
 METRICS2.register(
+    "minio_tpu_v2_disk_op_lane_total", "counter",
+    "append_file / rename_data calls on local drives, by op and lane: "
+    "native (the call's system calls batched in native/fsops.cc, "
+    "GIL-free) or python (the library is missing, a fault plan is "
+    "armed or storage fsync is on).")
+METRICS2.register(
     "minio_tpu_v2_rpc_requests_total", "counter",
     "Peer RPC calls served, by service and method.")
 METRICS2.register(

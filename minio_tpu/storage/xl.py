@@ -34,6 +34,7 @@ import uuid
 from . import errors as serr
 from .interface import StorageAPI
 from .metadata import XL_META_FILE, FileInfo, XLMeta
+from .. import native
 from ..erasure import bitrot
 from ..faultinject import FAULTS
 from ..obs.drivemon import DRIVEMON, is_drive_fault
@@ -85,12 +86,17 @@ class _DiskOp:
 
     def __exit__(self, *exc):
         self._cm.__exit__(*exc)
-        ms = (time.perf_counter() - self._t0) * 1e3
-        METRICS2.observe("minio_tpu_v2_disk_op_duration_ms",
-                         {"op": self.op}, ms)
-        DRIVEMON.record(self._disk.root, self.op, ms,
-                        error=bool(exc) and is_drive_fault(exc[0]))
+        _account(self._disk.root, self.op,
+                 (time.perf_counter() - self._t0) * 1e3,
+                 error=bool(exc) and is_drive_fault(exc[0]))
         return False
+
+
+def _account(root: str, op: str, ms: float, error: bool = False) -> None:
+    """One disk op into the histogram and the drive-health monitor."""
+    METRICS2.observe("minio_tpu_v2_disk_op_duration_ms", {"op": op}, ms)
+    DRIVEMON.record(root, op, ms, error=error)
+
 
 MINIO_META_BUCKET = ".minio.sys"
 TMP_DIR = ".minio.sys/tmp"
@@ -155,6 +161,24 @@ def commit_replace(src: str, dst: str) -> None:
         _fsync_fd_of(os.path.dirname(dst))
 
 
+def _native_lib():
+    """native/fsops.cc when a drive's leg may run through it, chosen
+    from what the process can see: the library is loaded, no fault plan
+    is armed and `storage fsync=on` is off. Else None: the Python lane,
+    which is where injected latency, errors, torn writes and crash
+    points act and where the fsyncs are (commit_replace)."""
+    return None if FAULTS.enabled or FSYNC else native.get_lib()
+
+
+def _fs_lane(op: str):
+    """The lane one append_file / rename_data call takes (`_native_lib`
+    or None for the Python lane), counted once a call."""
+    lib = _native_lib()
+    METRICS2.inc("minio_tpu_v2_disk_op_lane_total",
+                 {"op": op, "lane": "python" if lib is None else "native"})
+    return lib
+
+
 def _is_valid_volume(volume: str) -> bool:
     return (volume not in ("", ".", "..") and "/" not in volume
             and "\\" not in volume)
@@ -164,7 +188,8 @@ class XLStorage(StorageAPI):
     def __init__(self, root: str):
         self.root = os.path.abspath(root)
         self.disk_id = ""
-        os.makedirs(os.path.join(self.root, TMP_DIR), exist_ok=True)
+        self._sys_tmp = os.path.join(self.root, TMP_DIR)
+        os.makedirs(self._sys_tmp, exist_ok=True)
 
     def __repr__(self) -> str:
         return f"XLStorage({self.root})"
@@ -395,11 +420,39 @@ class XLStorage(StorageAPI):
                 raise serr.DiskFull(str(e))
             raise serr.FaultyDisk(str(e))
 
+    def _native_vol(self, volume: str) -> tuple[bytes, bytes | None]:
+        """A volume as native/fsops.cc takes it: its path, and for the
+        system volume the tmp directory that self-creates with it."""
+        return (os.fsencode(self._vol_path(volume)),
+                os.fsencode(self._sys_tmp)
+                if volume == MINIO_META_BUCKET else None)
+
+    @staticmethod
+    def _raise_native(rc: int, src: str, dst_volume: str,
+                      src_volume: str = "") -> None:
+        """Raise for a native leg's non-zero result what the Python
+        lane raises for the same condition; an errno as the OSError it
+        would have been, for the caller's `except OSError` to type."""
+        if rc == native.FS_DST_VOLUME_NOT_FOUND:
+            raise serr.VolumeNotFound(dst_volume)
+        if rc == native.FS_SRC_VOLUME_NOT_FOUND:
+            raise serr.VolumeNotFound(src_volume)
+        if rc == native.FS_STAGE_NOT_FOUND:
+            raise serr.FileNotFound(src)
+        raise OSError(rc, os.strerror(rc), src)
+
     def append_file(self, volume: str, path: str, data: bytes) -> None:
         full = self._file_path(volume, path)
         data = FAULTS.filter_write(self.root, "append_file", data)
+        lib = _fs_lane("append_file")
         try:
             with _DiskOp("append_file", self):
+                if lib is not None:
+                    rc = native.fs_append(lib, os.fsencode(full),
+                                          *self._native_vol(volume), data)
+                    if rc != 0:
+                        self._raise_native(rc, f"{volume}/{path}", volume)
+                    return
                 try:
                     f = open(full, "ab")
                 except FileNotFoundError:
@@ -533,6 +586,93 @@ class XLStorage(StorageAPI):
 
     def _rename_data(self, src_volume: str, src_path: str, fi: FileInfo,
                      dst_volume: str, dst_path: str) -> None:
+        lib = _fs_lane("rename_data")
+        try:
+            if lib is None:
+                self._rename_data_py(src_volume, src_path, fi,
+                                     dst_volume, dst_path)
+            else:
+                self._rename_data_native(lib, src_volume, src_path, fi,
+                                         dst_volume, dst_path)
+        except OSError as e:
+            # What neither lane resolves into a typed condition (a
+            # refused rename, EIO, ...) is the drive's fault, as in
+            # append_file and _atomic_write.
+            if e.errno == errno.ENOSPC:
+                raise serr.DiskFull(str(e))
+            raise serr.FaultyDisk(str(e))
+
+    def _rename_data_native(self, lib, src_volume: str, src_path: str,
+                            fi: FileInfo, dst_volume: str,
+                            dst_path: str) -> None:
+        """The steps of _rename_data_py below, in its order, as TWO
+        GIL-free calls (native/fsops.cc) around the XLMeta merge. No
+        crash point stands here: an armed fault plan takes the Python
+        lane (_native_lib), which is where the crash windows are
+        proven; what this lane leaves when a step FAILS is
+        tests/test_storage_native_lane.py's, from file-system state."""
+        src_vol, src_sys = self._native_vol(src_volume)
+        dst_vol, dst_sys = self._native_vol(dst_volume)
+        dst_obj_dir = self._file_path(dst_volume, dst_path)
+        src_dir = self._file_path(src_volume, src_path)
+        src_dd = dst_dd = None
+        if fi.data_dir:
+            src_dd = os.fsencode(self._file_path(
+                src_volume, os.path.join(src_path, fi.data_dir)))
+            dst_dd = os.fsencode(os.path.join(dst_obj_dir, fi.data_dir))
+        xl_meta = os.path.join(dst_obj_dir, XL_META_FILE)
+        rc, raw, read_ms = native.fs_commit_stage(
+            lib, src_vol, src_sys, dst_vol, dst_sys,
+            os.fsencode(dst_obj_dir), src_dd, dst_dd,
+            os.fsencode(xl_meta))
+        if rc != 0:
+            self._raise_native(rc, f"{src_volume}/{src_path}",
+                               dst_volume, src_volume)
+        # The Python lane reads xl.meta through read_all; the drive
+        # monitor's read class goes on hearing of it on this lane too
+        # (a drive judged slow on reads is cleared by reads), timed in
+        # C, where no wait for the GIL is inside.
+        _account(self.root, "read_all", read_ms)
+        if raw is native.FS_META_TOO_BIG:
+            with open(xl_meta, "rb") as f:
+                raw = f.read()
+        try:
+            meta = XLMeta() if raw is None else XLMeta.load(raw)
+        except ValueError as e:
+            raise serr.FileCorrupt(str(e))
+        old_dd = self._merge_version(meta, fi)
+        tmp = os.path.join(self._sys_tmp, str(uuid.uuid4()))
+        rc = native.fs_commit_meta(
+            lib, os.fsencode(tmp), os.fsencode(xl_meta), meta.dump(),
+            dst_vol, dst_sys, os.fsencode(dst_obj_dir),
+            None if old_dd is None
+            else os.fsencode(os.path.join(dst_obj_dir, old_dd)),
+            os.fsencode(os.path.join(src_dir, INTENT_FILE)),
+            os.fsencode(src_dir))
+        if rc != 0:
+            self._raise_native(rc, f"{dst_volume}/{dst_path}", dst_volume)
+
+    @staticmethod
+    def _merge_version(meta: XLMeta, fi: FileInfo) -> str | None:
+        """Add `fi` to `meta`; returns the data dir this frees, if any.
+        Null-version overwrite frees the PREVIOUS NULL version's data
+        dir only (real versions keep theirs; ref xlMetaV2.AddVersion
+        null-version replacement semantics). Crash safety: the caller
+        persists the new xl.meta BEFORE removing that data dir, so
+        metadata never points at deleted shards."""
+        old = None
+        if fi.version_id == "":
+            for v in meta.versions:
+                if v.get("versionId", "") == "":
+                    old = v
+                    break
+        meta.add_version(fi)
+        if old and old.get("dataDir") and old["dataDir"] != fi.data_dir:
+            return old["dataDir"]
+        return None
+
+    def _rename_data_py(self, src_volume: str, src_path: str, fi: FileInfo,
+                        dst_volume: str, dst_path: str) -> None:
         self._check_vol(src_volume)
         dst_obj_dir = self._file_path(dst_volume, dst_path)
         self._makedirs_for(dst_volume, dst_obj_dir)
@@ -572,18 +712,7 @@ class XLStorage(StorageAPI):
             meta = self._read_xlmeta(dst_volume, dst_path)
         except serr.FileNotFound:
             meta = XLMeta()
-        # Null-version overwrite frees the PREVIOUS NULL version's data dir
-        # only (real versions keep theirs; ref xlMetaV2.AddVersion null-
-        # version replacement semantics). Crash safety: the new xl.meta is
-        # persisted BEFORE the orphaned data dir is removed, so metadata
-        # never points at deleted shards.
-        old = None
-        if fi.version_id == "":
-            for v in meta.versions:
-                if v.get("versionId", "") == "":
-                    old = v
-                    break
-        meta.add_version(fi)
+        old_dd = self._merge_version(meta, fi)
         # dir_ready: dst_obj_dir was created at the top of this call;
         # xl.meta lives directly in it. volume still passed so a
         # mid-commit ENOENT (racing delete) resolves typed.
@@ -596,8 +725,8 @@ class XLStorage(StorageAPI):
         # remains — a death here must read as the new version with
         # the leftovers swept at next boot.
         FAULTS.crash_point(CRASH_RENAME_POST)
-        if old and old.get("dataDir") and old["dataDir"] != fi.data_dir:
-            old_dd = os.path.join(dst_obj_dir, old["dataDir"])
+        if old_dd is not None:
+            old_dd = os.path.join(dst_obj_dir, old_dd)
             if os.path.isdir(old_dd):
                 shutil.rmtree(old_dd, ignore_errors=True)
         # Clean the tmp staging dir — after the data-dir replace only

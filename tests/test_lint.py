@@ -526,6 +526,57 @@ def test_r7_scoped_to_storage_package():
     assert not rule.applies(_ctx(src, "tools/sample.py"))
 
 
+_R7_NATIVE = (
+    "int commit_rename(const char* src, const char* dst) {\n"
+    "  return rename(src, dst) == 0 ? 0 : errno;  // the one rename\n"
+    "}\n"
+    "int fs_commit_meta(const char* tmp, const char* dst) {\n"
+    "  // rename(tmp, dst) in a comment is not a call\n"
+    "  return commit_rename(tmp, dst);\n"
+    "}\n")
+
+
+@pytest.mark.parametrize("source, flagged", [
+    (_R7_NATIVE, []),
+    (_R7_NATIVE.replace("return commit_rename(tmp, dst);",
+                        "return rename(tmp, dst);"), [6]),
+    (_R7_NATIVE.replace("return commit_rename(tmp, dst);",
+                        "return renameat(AT_FDCWD, tmp, AT_FDCWD, dst);"),
+     [6]),
+    (_R7_NATIVE.replace("int commit_rename(", "int move_it(", 1), [2]),
+], ids=["blessed", "raw-rename", "raw-renameat", "helper-renamed"])
+def test_r7_native_lane_renames_route_through_commit_rename(source,
+                                                            flagged):
+    """The second blessed site: minio_tpu/native/fsops.cc renames only
+    inside commit_rename(src, dst)."""
+    from tools.mtpu_lint.rules.commits import check_native_source
+    findings = check_native_source("minio_tpu/native/sample.cc", source)
+    assert [f.line for f in findings] == flagged
+    assert all(f.rule == "R7" and "commit_rename" in f.message
+               for f in findings)
+
+
+def test_r7_reads_the_real_native_sources():
+    """The rule reaches fsops.cc through the native loader (the linter
+    walks .py files), finds its rename inside the helper, and flags the
+    same file with the helper's name taken away."""
+    from tools.mtpu_lint.core import REPO
+    from tools.mtpu_lint.rules import commits
+    import os
+    with open(os.path.join(REPO, "minio_tpu/native/fsops.cc")) as f:
+        text = f.read()
+    # The native lane is not taken with `storage fsync=on`
+    # (xl._native_lib), so it must hold no fsync to forget either.
+    assert "rename(src, dst)" in text and "fsync(" not in text
+    assert commits.check_native_source("fsops.cc", text) == []
+    assert commits.check_native_source(
+        "fsops.cc", text.replace("int commit_rename(", "int move_it("))
+    rule = CommitReplaceRule()
+    with open(os.path.join(REPO, commits.NATIVE_LOADER)) as f:
+        ctx = ModuleCtx(os.path.join(REPO, commits.NATIVE_LOADER), f.read())
+    assert rule.applies(ctx) and rule.check(ctx) == []
+
+
 # ---------------------------------------------------------------------------
 # R8 — no blocking calls in async def bodies under minio_tpu/s3/
 
