@@ -1054,7 +1054,11 @@ class ErasureObjects:
         # iam.ConfigStore).
         definitive = (serr.FileNotFound, serr.VersionNotFound,
                       serr.VolumeNotFound)
-        if (sum(f is not None for f in fis) < self.k
+        # The object's own k (its storage class's, from the copies
+        # read), not this set's default.
+        need = max((f.erasure.data_blocks for f in fis if f is not None),
+                   default=self.k)
+        if (sum(f is not None for f in fis) < need
                 and not any(isinstance(e, definitive) for e in errs)):
             for i, e in enumerate(errs):
                 if not isinstance(e, serr.DriveQuarantined):

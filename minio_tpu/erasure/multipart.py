@@ -3,7 +3,8 @@ stitches parts into one versioned object (ref cmd/erasure-multipart.go:
 NewMultipartUpload:314, PutObjectPart:342, CompleteMultipartUpload:678).
 
 On-disk (per disk, inside .minio.sys):
-    mpu/<obj-hash>/<upload_id>/upload.json   upload session record
+    mpu/<obj-hash>/<upload_id>/upload.json   upload session record (with the
+                                             parity its storage class gave)
     mpu/<obj-hash>/<upload_id>/part.N        bitrot-wrapped shard of part N
     mpu/<obj-hash>/<upload_id>/part.N.json   part metadata (size, etag)
 
@@ -16,9 +17,11 @@ from __future__ import annotations
 
 import hashlib
 import json
+import time
 import uuid
 
 from ..faultinject import FAULTS
+from ..obs.span import TRACER
 from ..parallel.quorum import (QuorumError, first_success, hash_order,
                                parallel_map, reduce_quorum_errs,
                                write_quorum)
@@ -26,6 +29,7 @@ from ..storage import errors as serr
 from ..storage.metadata import (ErasureInfo, FileInfo, ObjectPartInfo,
                                 new_data_dir, now)
 from ..storage.xl import INTENT_FILE, MINIO_META_BUCKET, TMP_PATH
+from ..utils.phasetimer import PUT as _PUT
 from . import bitrot
 
 MPU_PATH = "mpu"
@@ -75,24 +79,39 @@ class MultipartUploads:
     # -- session ----------------------------------------------------------
 
     def new_multipart_upload(self, bucket: str, object_name: str,
-                             metadata: dict | None = None) -> str:
+                             metadata: dict | None = None,
+                             parity_shards: int | None = None) -> str:
+        """parity_shards: the parity the request's storage class gives
+        (as put_object's); the upload keeps it in upload.json, and
+        every part and the completed xl.meta are coded with it (ref
+        newMultipartUpload, cmd/erasure-multipart.go)."""
         eng = self.engine
         eng._check_bucket(bucket)
+        n = len(eng.disks)
+        m = eng.m if parity_shards is None else parity_shards
+        if not (0 < m <= n // 2):
+            raise ValueError(f"parity {m} out of range for {n} disks")
         upload_id = uuid.uuid4().hex
         base = _upload_base(bucket, object_name, upload_id)
         record = json.dumps({
             "bucket": bucket, "object": object_name,
             "meta": dict(metadata or {}), "created": now(),
-            "distribution": hash_order(f"{bucket}/{object_name}",
-                                       len(eng.disks)),
+            "distribution": hash_order(f"{bucket}/{object_name}", n),
+            "parity": m,
         }).encode()
         _, errs = parallel_map(
             [lambda d=d: d.write_all(MINIO_META_BUCKET,
                                      f"{base}/upload.json", record)
              for d in eng.disks])
-        reduce_quorum_errs(errs, write_quorum(eng.k, eng.m),
+        reduce_quorum_errs(errs, write_quorum(n - m, m),
                            "new_multipart_upload")
         return upload_id
+
+    def _geometry(self, up: dict) -> tuple[int, int]:
+        """(k, m) of an upload: the parity its initiate recorded; the
+        set's default for a record an older process wrote without it."""
+        m = up.get("parity", self.engine.m)
+        return len(self.engine.disks) - m, m
 
     def _load_upload(self, bucket: str, object_name: str,
                      upload_id: str) -> dict:
@@ -107,14 +126,15 @@ class MultipartUploads:
         the n shard-append RPCs every part batch already fans out. A
         torn record (ValueError) propagates, as before."""
         base = _upload_base(bucket, object_name, upload_id)
-        try:
-            raw = first_success(
-                [lambda d=d: d.read_all(MINIO_META_BUCKET,
-                                        f"{base}/upload.json")
-                 for d in self.engine.disks],
-                swallow=serr.StorageError)
-        except QuorumError:
-            raise UploadNotFound(upload_id) from None
+        with TRACER.span("mpu.load"):
+            try:
+                raw = first_success(
+                    [lambda d=d: d.read_all(MINIO_META_BUCKET,
+                                            f"{base}/upload.json")
+                     for d in self.engine.disks],
+                    swallow=serr.StorageError)
+            except QuorumError:
+                raise UploadNotFound(upload_id) from None
         return json.loads(raw)
 
     def get_upload_meta(self, bucket: str, object_name: str,
@@ -146,10 +166,12 @@ class MultipartUploads:
             raise InvalidPart(f"part number {part_number}")
         up = self._load_upload(bucket, object_name, upload_id)
         dist = up["distribution"]
+        k, m = self._geometry(up)
+        codec = eng.codec_for(k, m)
         base = _upload_base(bucket, object_name, upload_id)
         reader = streams.ensure_reader(data)
         n = len(eng.disks)
-        wq = write_quorum(eng.k, eng.m)
+        wq = write_quorum(k, m)
         stage = f"{base}/part.{part_number}.{uuid.uuid4().hex}.stage"
         md5 = None if hasattr(reader, "etag") else hashlib.md5()
         alive = [True] * n
@@ -169,7 +191,6 @@ class MultipartUploads:
                 eng.disks[i].append_file(MINIO_META_BUCKET, stage,
                                          payload)
                 return
-            from ..obs.span import TRACER
             with TRACER.span("ec.shard_write", parent=parent, disk=i,
                              endpoint=str(eng.disks[i]),
                              bytes=len(payload)):
@@ -180,8 +201,8 @@ class MultipartUploads:
             return f"part write quorum lost ({sum(alive)}/{n})"
 
         try:
-            total, _, _ = eng._stream_shard_writes(
-                reader, eng.k, eng.m, eng.codec, dist, append_shard,
+            total, t_enc, t_wr = eng._stream_shard_writes(
+                reader, k, m, codec, dist, append_shard,
                 alive, disk_errs, wq, quorum_msg, md5)
             if hasattr(reader, "verify"):
                 reader.verify()
@@ -210,9 +231,16 @@ class MultipartUploads:
                                f"{base}/part.{part_number}.json",
                                part_meta)
 
-            _, errs = parallel_map(
-                [lambda i=i: commit_one(i) for i in range(n)])
+            t_commit = time.perf_counter()
+            with TRACER.span("ec.commit"):
+                _, errs = parallel_map(
+                    [lambda i=i: commit_one(i) for i in range(n)])
             reduce_quorum_errs(errs, wq, "put_object_part")
+            # A part's phases beside a PUT's, in the same series.
+            _PUT.record("engine_commit",
+                        (time.perf_counter() - t_commit) * 1e3)
+            _PUT.record("engine_encode", t_enc * 1e3)
+            _PUT.record("engine_write", t_wr * 1e3)
         except BaseException:
             cleanup(range(n))
             raise
@@ -225,19 +253,20 @@ class MultipartUploads:
         self._load_upload(bucket, object_name, upload_id)
         base = _upload_base(bucket, object_name, upload_id)
         parts: dict[int, dict] = {}
-        for disk in self.engine.disks:
-            try:
-                entries = disk.list_dir(MINIO_META_BUCKET, base)
-            except serr.StorageError:
-                continue
-            for e in entries:
-                if e.startswith("part.") and e.endswith(".json"):
-                    try:
-                        rec = json.loads(disk.read_all(
-                            MINIO_META_BUCKET, f"{base}/{e}"))
-                    except serr.StorageError:
-                        continue
-                    parts.setdefault(rec["number"], rec)
+        with TRACER.span("mpu.list"):
+            for disk in self.engine.disks:
+                try:
+                    entries = disk.list_dir(MINIO_META_BUCKET, base)
+                except serr.StorageError:
+                    continue
+                for e in entries:
+                    if e.startswith("part.") and e.endswith(".json"):
+                        try:
+                            rec = json.loads(disk.read_all(
+                                MINIO_META_BUCKET, f"{base}/{e}"))
+                        except serr.StorageError:
+                            continue
+                        parts.setdefault(rec["number"], rec)
         return [parts[n] for n in sorted(parts)]
 
     def list_uploads(self, bucket: str,
@@ -287,6 +316,7 @@ class MultipartUploads:
         eng = self.engine
         up = self._load_upload(bucket, object_name, upload_id)
         dist = up["distribution"]
+        k, m = self._geometry(up)
         base = _upload_base(bucket, object_name, upload_id)
         have = {p["number"]: p for p in self.list_parts(
             bucket, object_name, upload_id)}
@@ -327,73 +357,74 @@ class MultipartUploads:
             # Handler-transformed parts (SSE/compression): record the
             # logical object length (ref X-Minio-Internal-actual-size).
             meta["x-internal-actual-size"] = str(total_actual)
-        wq = write_quorum(eng.k, eng.m)
+        wq = write_quorum(k, m)
 
         from .engine import _stage_intent_blob
         intent_blob = _stage_intent_blob(bucket, object_name, "",
                                          data_dir)
+        # The fan-out's workers do not inherit this thread's context:
+        # each drive's two phases hang off the request's span by hand.
+        req_span = TRACER.current()
+
+        def stage_one(disk, tmp_path: str) -> None:
+            if total_size == 0:
+                return
+            # Recovery breadcrumb before the link/copy loop: a crash
+            # mid-commit leaves this stage dir for the boot sweep to
+            # map back to the object.
+            try:
+                disk.append_file(MINIO_META_BUCKET,
+                                 f"{tmp_path}/{INTENT_FILE}", intent_blob)
+            except serr.StorageError:
+                pass
+            # Stage this disk's part shards into the commit data dir,
+            # KEEPING the client's part numbers (SSE derives per-part
+            # keys from them, and ListParts reports them; ref AWS
+            # part-number semantics). Not a rename: a failed quorum
+            # must leave the upload intact so the client can retry
+            # complete (cleanup happens only after quorum success).
+            # Local disks HARD-LINK the immutable shard files (zero
+            # bytes moved — the dominant cost of complete for
+            # multi-GiB uploads); backends without link support fall
+            # back to read+write copy.
+            link = getattr(disk, "link_file", None)
+            for p in part_infos:
+                # Crash window: fires per part, so an `after` count
+                # lands the kill MID hard-link loop — some parts
+                # staged, some not, nothing visible.
+                FAULTS.crash_point(CRASH_MPU_LINK)
+                if link is not None:
+                    try:
+                        link(MINIO_META_BUCKET, f"{base}/part.{p.number}",
+                             MINIO_META_BUCKET,
+                             f"{tmp_path}/{data_dir}/part.{p.number}")
+                        continue
+                    except serr.FileNotFound:
+                        raise
+                    except serr.StorageError:
+                        # Filesystem without hard-link support (FAT,
+                        # some NFS/overlay mounts): take the copy lane
+                        # for the rest of this disk's parts.
+                        link = None
+                shard = disk.read_all(MINIO_META_BUCKET,
+                                      f"{base}/part.{p.number}")
+                disk.create_file(
+                    MINIO_META_BUCKET,
+                    f"{tmp_path}/{data_dir}/part.{p.number}", shard)
 
         def commit_one(i: int):
             disk = eng.disks[i]
             tmp_path = f"{TMP_PATH}/{uuid.uuid4()}"
-            link = getattr(disk, "link_file", None)
             try:
-                if total_size > 0:
-                    # Recovery breadcrumb before the link/copy loop:
-                    # a crash mid-commit leaves this stage dir for the
-                    # boot sweep to map back to the object.
-                    try:
-                        disk.append_file(MINIO_META_BUCKET,
-                                         f"{tmp_path}/{INTENT_FILE}",
-                                         intent_blob)
-                    except serr.StorageError:
-                        pass
-                # Stage this disk's part shards into the commit data
-                # dir, KEEPING the client's part numbers (SSE derives
-                # per-part keys from them, and ListParts reports them;
-                # ref AWS part-number semantics). Not a rename: a
-                # failed quorum must leave the upload intact so the
-                # client can retry complete (cleanup happens only after
-                # quorum success). Local disks HARD-LINK the immutable
-                # shard files (zero bytes moved — the dominant cost of
-                # complete for multi-GiB uploads); backends without
-                # link support fall back to read+write copy.
-                if total_size > 0:
-                    for p in part_infos:
-                        # Crash window: fires per part, so an `after`
-                        # count lands the kill MID hard-link loop —
-                        # some parts staged, some not, nothing
-                        # visible.
-                        FAULTS.crash_point(CRASH_MPU_LINK)
-                        if link is not None:
-                            try:
-                                link(MINIO_META_BUCKET,
-                                     f"{base}/part.{p.number}",
-                                     MINIO_META_BUCKET,
-                                     f"{tmp_path}/{data_dir}"
-                                     f"/part.{p.number}")
-                                continue
-                            except serr.FileNotFound:
-                                raise
-                            except serr.StorageError:
-                                # Filesystem without hard-link support
-                                # (FAT, some NFS/overlay mounts): take
-                                # the copy lane for the rest of this
-                                # disk's parts.
-                                link = None
-                        shard = disk.read_all(MINIO_META_BUCKET,
-                                              f"{base}/part.{p.number}")
-                        disk.create_file(
-                            MINIO_META_BUCKET,
-                            f"{tmp_path}/{data_dir}/part.{p.number}",
-                            shard)
+                with TRACER.span("mpu.stage", parent=req_span, disk=i):
+                    stage_one(disk, tmp_path)
                 fi = FileInfo(
                     volume=bucket, name=object_name, version_id="",
                     data_dir=data_dir if total_size > 0 else "",
                     size=total_size, mod_time=mod_time, metadata=meta,
                     parts=list(part_infos),
                     erasure=ErasureInfo(
-                        data_blocks=eng.k, parity_blocks=eng.m,
+                        data_blocks=k, parity_blocks=m,
                         block_size=eng.block_size, index=dist[i],
                         distribution=list(dist),
                         checksums=[{"part": p.number,
@@ -401,11 +432,12 @@ class MultipartUploads:
                                     "hash": ""}
                                    for p in part_infos]),
                 )
-                if total_size > 0:
-                    disk.rename_data(MINIO_META_BUCKET, tmp_path, fi,
-                                     bucket, object_name)
-                else:
-                    disk.write_metadata(bucket, object_name, fi)
+                with TRACER.span("ec.commit", parent=req_span, disk=i):
+                    if total_size > 0:
+                        disk.rename_data(MINIO_META_BUCKET, tmp_path, fi,
+                                         bucket, object_name)
+                    else:
+                        disk.write_metadata(bucket, object_name, fi)
                 return fi
             except BaseException:
                 try:
@@ -420,7 +452,10 @@ class MultipartUploads:
         FAULTS.crash_point(CRASH_MPU_PRE)
         # Exclusive commit against concurrent put/delete on the same key
         # (ref CompleteMultipartUpload NSLock, cmd/erasure-multipart.go).
+        t_lock = time.perf_counter()
         with eng.ns_lock.write_locked(bucket, object_name):
+            TRACER.record("lock.wait", req_span, t_lock,
+                          time.perf_counter(), mode="write")
             _, errs = parallel_map(
                 [lambda i=i: commit_one(i)
                  for i in range(len(eng.disks))])

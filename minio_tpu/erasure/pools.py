@@ -240,10 +240,11 @@ class _PoolsMultipart:
                 continue
         raise UploadNotFound(upload_id)
 
-    def new_multipart_upload(self, bucket, object_name, metadata=None):
+    def new_multipart_upload(self, bucket, object_name, metadata=None,
+                             parity_shards=None):
         idx = self._pools._put_pool_index(bucket, object_name)
         return self._pools.pools[idx].multipart.new_multipart_upload(
-            bucket, object_name, metadata)
+            bucket, object_name, metadata, parity_shards=parity_shards)
 
     def put_object_part(self, bucket, object_name, upload_id,
                         part_number, data, actual_size=None):

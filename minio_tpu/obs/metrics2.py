@@ -358,6 +358,14 @@ METRICS2.register(
     "Per-phase PUT hot-path latency in milliseconds "
     "(auth, transform, encode, write, commit, post).")
 METRICS2.register(
+    "minio_tpu_v2_multipart_op_ms", "histogram",
+    "Multipart operations answered, in milliseconds from the handler's "
+    "entry to the object layer's answer, by op: initiate, part "
+    "(UploadPart and UploadPartCopy), complete.")
+METRICS2.register(
+    "minio_tpu_v2_multipart_part_bytes_total", "counter",
+    "Stored bytes of the parts multipart uploads were sent.")
+METRICS2.register(
     "minio_tpu_v2_disk_op_duration_ms", "histogram",
     "Per-disk storage call latency in milliseconds, by op.")
 METRICS2.register(

@@ -38,7 +38,9 @@ MAX_CHILDREN = 64
 
 # A ROOT holds the request's phases (depth 1), several per streamed
 # group (ec.fetch, ec.verify, door.hop x2, door.send): its cap is
-# wider, so objects up to ~40 groups reduce without a dropped phase.
+# wider. The cap bounds the TREE (slow log, trace endpoint); the
+# per-phase times do not read the tree: a root folds each depth-1
+# span's interval as it closes (Span._fold_phase), dropped or kept.
 MAX_ROOT_CHILDREN = 256
 
 # Per-span event cap (QoS shed/deadline markers): same bounding rule.
@@ -52,7 +54,8 @@ MAX_EVENTS = 16
 # "other", and what no depth-1 span covers is "unattributed".
 PHASES = ("door.hop", "door.recv", "auth.sigv4", "qos.wait", "lock.wait",
           "ec.meta", "ec.fetch", "ec.verify", "ec.decode", "ec.join",
-          "ec.encode", "ec.write", "ec.commit", "door.send")
+          "ec.encode", "ec.write", "ec.commit", "door.send",
+          "mpu.load", "mpu.list", "mpu.stage")
 _PHASE_SET = frozenset(PHASES)
 
 
@@ -67,40 +70,40 @@ def annotation(name: str, **kw):
     return jax.profiler.TraceAnnotation(name, **kw)
 
 
-def _union_s(intervals: list) -> float:
-    """Length of the union of (start, end) intervals."""
-    total, end = 0.0, float("-inf")
-    for lo, hi in sorted(intervals):
-        if hi > end:
-            total += hi - max(lo, end)
-            end = hi
-    return total
+def _merge(ivs: list, lo: float, hi: float) -> None:
+    """Insert [lo, hi] into `ivs`, a sorted list of disjoint (lo, hi)
+    pairs, merging it with every pair it overlaps or touches. Phases
+    close in near time order, so the walk from the end is short."""
+    i = len(ivs)
+    while i and ivs[i - 1][0] > hi:
+        i -= 1
+    j = i
+    while j and ivs[j - 1][1] >= lo:
+        j -= 1
+        lo, hi = min(lo, ivs[j][0]), max(hi, ivs[j][1])
+    ivs[j:i] = [(lo, hi)]
 
 
 def reduce_phases(root: "Span") -> dict[str, float]:
-    """{phase: ms} of one finished request: per PHASES name the union of
-    its depth-1 spans' intervals on the spans' monotonic clock (a
-    streamed PUT overlaps ec.encode with ec.write, so lengths are
-    unions, not sums), plus "unattributed" = root duration minus the
-    union of ALL depth-1 spans inside the root's interval. Grafted
-    remote dicts and deeper spans are not read."""
+    """{phase: ms} of one finished request, from the root's fold: per
+    PHASES name the union of its depth-1 spans' intervals on the spans'
+    monotonic clock (a streamed PUT overlaps ec.encode with ec.write,
+    so lengths are unions, not sums), plus "unattributed" = root
+    duration minus the union of ALL depth-1 spans inside the root's
+    interval. Every depth-1 span that closed before the root counts,
+    whatever the tree kept of it; grafted remote dicts and deeper spans
+    are not read."""
     t0 = root._t0
     t1 = t0 + root.duration_ms / 1e3
-    by: dict[str, list] = {}
-    inside = []
-    for c in root.children[:MAX_ROOT_CHILDREN]:
-        if isinstance(c, dict) or not c._done:
-            continue
-        lo, hi = c._t0, c._t0 + c.duration_ms / 1e3
-        by.setdefault(c.name if c.name in _PHASE_SET else "other",
-                      []).append((lo, hi))
-        # door.hop ends where the root starts: a phase, but no part of
-        # the root's own interval.
-        if hi > t0 and lo < t1:
-            inside.append((max(lo, t0), min(hi, t1)))
-    out = {name: _union_s(ivs) * 1e3 for name, ivs in by.items()}
-    out["unattributed"] = max(
-        0.0, root.duration_ms - _union_s(inside) * 1e3)
+    with root._fold_mu:
+        by = {name: list(ivs) for name, ivs in root._fold.items()}
+    # door.hop ends where the root starts: a phase, but no part of
+    # the root's own interval.
+    inside = sum(min(hi, t1) - max(lo, t0) for lo, hi in by.pop("", ())
+                 if hi > t0 and lo < t1)
+    out = {name: sum(hi - lo for lo, hi in ivs) * 1e3
+           for name, ivs in by.items()}
+    out["unattributed"] = max(0.0, root.duration_ms - inside * 1e3)
     return out
 
 
@@ -130,7 +133,7 @@ class Span:
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "start",
                  "duration_ms", "tags", "children", "events", "dropped",
                  "root", "_t0", "_token", "_tracer", "_done", "_ann",
-                 "__weakref__")
+                 "_fold", "_fold_mu", "__weakref__")
 
     _ids = itertools.count(1)    # next() is atomic: no lock on the hot path
 
@@ -154,6 +157,11 @@ class Span:
         self._tracer = tracer
         self._done = False
         self._ann = None
+        # A ROOT's fold (Tracer.begin): per phase name, and under ""
+        # for all of them, the merged intervals of the depth-1 spans
+        # closed so far; what reduce_phases reads.
+        self._fold = None
+        self._fold_mu = None
 
     # -- tree assembly -------------------------------------------------
 
@@ -169,6 +177,16 @@ class Span:
 
     def _cap(self) -> int:
         return MAX_CHILDREN if self.parent_id else MAX_ROOT_CHILDREN
+
+    def _fold_phase(self, name: str, lo: float, hi: float) -> None:
+        """A depth-1 span [lo, hi] of this ROOT has closed (on any
+        thread: the pipeline's worker closes ec.encode while the
+        request's own closes ec.write)."""
+        if name not in _PHASE_SET:
+            name = "other"
+        with self._fold_mu:
+            _merge(self._fold.setdefault(name, []), lo, hi)
+            _merge(self._fold.setdefault("", []), lo, hi)
 
     def add_event(self, name: str, **attrs) -> None:
         """Record a point-in-time marker on this span (admission shed,
@@ -245,7 +263,12 @@ class Span:
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
-        if not self.parent_id and self._tracer is not None:
+        if self.parent_id:
+            root = self.root() if self.root is not None else None
+            if root is not None and root.span_id == self.parent_id:
+                root._fold_phase(self.name, self._t0,
+                                 self._t0 + self.duration_ms / 1e3)
+        elif self._tracer is not None:
             return self._tracer._complete(self)
         return None
 
@@ -273,6 +296,7 @@ class Tracer:
             return None
         root = Span(name, trace_id, tags=tags or None, tracer=self)
         root.root = weakref.ref(root)
+        root._fold, root._fold_mu = {}, threading.Lock()
         return root
 
     def span(self, name: str, parent: Span | None = None, **tags):
@@ -315,6 +339,8 @@ class Tracer:
         child.duration_ms = max(0.0, t1 - t0) * 1e3
         child._done = True
         parent.add_child(child)
+        if parent._fold is not None:
+            parent._fold_phase(name, t0, t0 + child.duration_ms / 1e3)
 
     # -- completed traces ----------------------------------------------
 

@@ -37,6 +37,18 @@ def _drain_stream(stream) -> bytes:
     return b"".join(parts)
 
 
+def _multipart_op(op: str, t0: float, part_bytes: int = 0) -> None:
+    """One multipart operation answered: initiate | part | complete,
+    from the handler's entry (perf_counter seconds) to the layer's
+    answer; a part's stored bytes beside it."""
+    from ..obs.metrics2 import METRICS2
+    METRICS2.observe("minio_tpu_v2_multipart_op_ms", {"op": op},
+                     (time.perf_counter() - t0) * 1e3)
+    if part_bytes:
+        METRICS2.inc("minio_tpu_v2_multipart_part_bytes_total", None,
+                     part_bytes)
+
+
 def _trim_iter(it, skip: int, limit: int):
     """Yield exactly `limit` bytes of `it` after dropping `skip`."""
     for chunk in it:
@@ -1327,6 +1339,7 @@ class S3ApiHandlers:
 
     def initiate_multipart(self, req: S3Request) -> S3Response:
         from ..erasure.engine import BucketNotFound as BNF
+        t0 = time.perf_counter()
         meta = {"content-type": req.headers.get(
             "content-type", "application/octet-stream")}
         for k, v in req.headers.items():
@@ -1334,11 +1347,21 @@ class S3ApiHandlers:
                 meta[k] = v
         self._apply_lock_headers(req, meta)
         self._sse_init_multipart(req, meta)
+        # The upload's parity is its storage class's, resolved once,
+        # here, as a plain PUT's is (a class header on UploadPart is
+        # ignored, ref newMultipartUpload); parts stay plain RS under
+        # REGEN. None = a layer without shards (FS, gateways).
+        parity = self._parity_for_request(req)
+        if req.headers.get("x-amz-storage-class"):
+            meta["x-amz-storage-class"] = req.headers[
+                "x-amz-storage-class"]
+        extra = {} if parity is None else {"parity_shards": parity}
         try:
             upload_id = self.layer.multipart.new_multipart_upload(
-                req.bucket, req.key, meta)
+                req.bucket, req.key, meta, **extra)
         except BNF:
             raise s3err.ERR_NO_SUCH_BUCKET
+        _multipart_op("initiate", t0)
         root = Element("InitiateMultipartUploadResult", S3_XMLNS)
         root.child("Bucket", req.bucket)
         root.child("Key", req.key)
@@ -1351,6 +1374,7 @@ class S3ApiHandlers:
         bytes (optionally x-amz-copy-source-range) become the part
         (ref CopyObjectPartHandler, cmd/object-handlers.go)."""
         from ..erasure.multipart import InvalidPart, UploadNotFound
+        t0 = time.perf_counter()
         src = urllib.parse.unquote(req.headers["x-amz-copy-source"])
         src = src.lstrip("/")
         if "/" not in src:
@@ -1389,6 +1413,7 @@ class S3ApiHandlers:
             raise s3err.ERR_NO_SUCH_UPLOAD
         except (InvalidPart, ValueError):
             raise s3err.ERR_INVALID_ARGUMENT
+        _multipart_op("part", t0, part["size"])
         root = Element("CopyPartResult", S3_XMLNS)
         root.child("ETag", f'"{part["etag"]}"')
         root.child("LastModified", _iso8601(time.time()))
@@ -1398,6 +1423,7 @@ class S3ApiHandlers:
     def put_part(self, req: S3Request) -> S3Response:
         from ..erasure.multipart import InvalidPart, UploadNotFound
         from ..utils import streams
+        t0 = time.perf_counter()
         part_number = int(req.params["partNumber"])
         pkey = self._sse_part_key(req, part_number)
         if req.body_stream is not None and (
@@ -1442,11 +1468,13 @@ class S3ApiHandlers:
             raise s3err.ERR_NO_SUCH_UPLOAD
         except (InvalidPart, ValueError):
             raise s3err.ERR_INVALID_ARGUMENT
+        _multipart_op("part", t0, part["size"])
         return S3Response(200, headers={"ETag": f'"{part["etag"]}"'})
 
     def complete_multipart(self, req: S3Request) -> S3Response:
         from ..erasure.multipart import (InvalidPart, PartTooSmall,
                                          UploadNotFound)
+        t0 = time.perf_counter()
         try:
             doc = parse(req.body)
             parts = [(int(p.findtext("PartNumber")),
@@ -1474,6 +1502,7 @@ class S3ApiHandlers:
             raise s3err.ERR_INVALID_PART
         except ParentIsObject:
             raise s3err.ERR_PARENT_IS_OBJECT
+        _multipart_op("complete", t0)
         root = Element("CompleteMultipartUploadResult", S3_XMLNS)
         root.child("Location",
                    f"http://{req.headers.get('host', '')}"

@@ -170,6 +170,7 @@ def _assert_packet_loop_is_one_kernel(txt: str, n: int):
 
 @pytest.mark.parametrize("B,L", [(16, _shard_len(8)),
                                  (16, _shard_len(12)),
+                                 (16, -(-4 * MiB // 12)),
                                  (128, MiB // 8),
                                  (64, -(-MiB // 12)),
                                  (8, _shard_len(4)),
@@ -179,7 +180,8 @@ def test_hh256_compiles_for_v5e(one_chip, B, L):
     """Device HighwayHash at real bitrot sub-block lengths: 8+4's
     1310720 (len % 32 == 0) and 12+4's 873814 (len % 32 == 22, the
     remainder packet; 27306 packets, not a multiple of the block) at
-    the 10 MiB block; 131072 and 87382 (len % 32 == 22) at the 1 MiB
+    the 10 MiB block, and 349526 (% 32 == 22 too), the 4 MiB tail block
+    of a 64 MiB multipart part at 12+4 (PR 32); 131072 and 87382 (len % 32 == 22) at the 1 MiB
     block; 4+2's 2621440 (81920 packets); 256 rows (two lane tiles);
     one row of fewer packets than the unroll. Each build holds the
     Pallas kernel and no per-packet `while`."""

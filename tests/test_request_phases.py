@@ -105,12 +105,124 @@ def test_grafted_dicts_and_deeper_spans_are_not_reduced():
     assert got["unattributed"] == pytest.approx(10.0)
 
 
-def test_child_overflow_is_dropped_and_counted_never_raised():
+def test_child_overflow_is_dropped_from_the_tree_and_still_reduced():
     root = _tree(1000.0, [("door.send", i, i + 0.5)
                           for i in range(MAX_ROOT_CHILDREN + 30)])
     assert root.dropped == 30
+    # The TREE (slow log, trace endpoint) stops at the cap and says so;
+    got = root.to_dict()
+    assert len(got["children"]) == MAX_ROOT_CHILDREN
+    assert got["droppedChildren"] == 30
+    # the phases do not read the tree: every span that closed counts.
     got = reduce_phases(root)
-    assert got["door.send"] == pytest.approx(MAX_ROOT_CHILDREN * 0.5)
+    assert got["door.send"] == pytest.approx((MAX_ROOT_CHILDREN + 30) * 0.5)
+    assert got["unattributed"] == pytest.approx(
+        1000.0 - (MAX_ROOT_CHILDREN + 30) * 0.5)
+
+
+def _walk_reduce(root: Span, kids: list[tuple]) -> dict[str, float]:
+    """The reduction as it was before the fold (PR 25), without its cap
+    of 256: per name the union of ALL the (name, start_ms, end_ms)
+    intervals, sorted, one pass."""
+    def union(ivs):
+        total, end = 0.0, float("-inf")
+        for lo, hi in sorted(ivs):
+            if hi > end:
+                total += hi - max(lo, end)
+                end = hi
+        return total
+
+    t0 = root._t0
+    t1 = t0 + root.duration_ms / 1e3
+    by, inside = {}, []
+    for name, a, b in kids:
+        # The ends as Tracer.record keeps them: start + duration.
+        lo = t0 + a / 1e3
+        hi = lo + max(0.0, (t0 + b / 1e3) - lo) * 1e3 / 1e3
+        by.setdefault(name if name in PHASES else "other",
+                      []).append((lo, hi))
+        if hi > t0 and lo < t1:
+            inside.append((max(lo, t0), min(hi, t1)))
+    out = {name: union(ivs) * 1e3 for name, ivs in by.items()}
+    out["unattributed"] = max(0.0, root.duration_ms - union(inside) * 1e3)
+    return out
+
+
+def _random_kids(n: int, seed: int, span_ms: float) -> list[tuple]:
+    """n depth-1 spans over [-5, span_ms + 5) ms: seven names (one
+    outside PHASES), lengths 0..12 ms, so most overlap a neighbour of
+    another name and many one of their own; closed in no time order."""
+    rng = np.random.default_rng(seed)
+    names = ["ec.fetch", "ec.verify", "door.send", "door.hop", "ec.join",
+             "mpu.load", "select.scan"]
+    kids = []
+    for _ in range(n):
+        a = float(rng.uniform(-5.0, span_ms))
+        kids.append((names[int(rng.integers(len(names)))], a,
+                     a + float(rng.uniform(0.0, 12.0))))
+    return kids
+
+
+def test_a_root_of_1000_phases_reduces_exactly():
+    """A whole read of a 1 GiB object in 64 MiB parts is 112 block
+    groups of about six depth-1 spans: the tree keeps 256, the fold
+    all."""
+    kids = _random_kids(1000, 32, 900.0)
+    root = _tree(900.0, kids)
+    assert root.dropped == 1000 - MAX_ROOT_CHILDREN
+    got, want = reduce_phases(root), _walk_reduce(root, kids)
+    assert set(got) == set(want) == {
+        "ec.fetch", "ec.verify", "door.send", "door.hop", "ec.join",
+        "mpu.load", "other", "unattributed"}
+    for name in want:
+        # Merged on arrival against summed in sorted order: the same
+        # number but for the order of the float additions.
+        assert got[name] == pytest.approx(want[name], rel=1e-9, abs=1e-9)
+    # Sparse, too: unattributed is most of the root.
+    kids = _random_kids(300, 33, 60_000.0)
+    root = _tree(60_000.0, kids)
+    got, want = reduce_phases(root), _walk_reduce(root, kids)
+    assert want["unattributed"] > 50_000.0
+    for name in want:
+        assert got[name] == pytest.approx(want[name], rel=1e-9, abs=1e-9)
+
+
+@pytest.mark.parametrize("n", [1, 7, 40, MAX_ROOT_CHILDREN - 1])
+def test_under_the_cap_the_fold_reads_what_the_tree_walk_read(n):
+    kids = _random_kids(n, 100 + n, 300.0)
+    root = _tree(300.0, kids)
+    assert root.dropped == 0 and len(root.children) == n
+    got, want = reduce_phases(root), _walk_reduce(root, kids)
+    assert set(got) == set(want)
+    for name in want:
+        assert got[name] == pytest.approx(want[name], rel=1e-12, abs=1e-9)
+
+
+def test_the_fold_takes_spans_closed_on_other_threads():
+    root = TRACER.begin("PUT-object", "T-threads")
+    root.__enter__()
+
+    def work():
+        for _ in range(200):
+            with TRACER.span("ec.encode", parent=root):
+                pass
+
+    threads = [threading.Thread(target=work) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for _ in range(200):
+        with TRACER.span("ec.write"):
+            pass
+    for t in threads:
+        t.join()
+    root.finish()
+    got = reduce_phases(root)
+    assert 0.0 < got["ec.encode"] <= root.duration_ms + 1e-6
+    assert 0.0 < got["ec.write"] <= root.duration_ms + 1e-6
+    assert root.dropped == 1000 - MAX_ROOT_CHILDREN
+    # A deeper span is not a phase, wherever it closes.
+    with root._fold_mu:
+        assert set(root._fold) == {"", "ec.encode", "ec.write"}
 
 
 def test_root_finish_observes_each_phase_once_per_request():
@@ -269,6 +381,55 @@ def test_get_with_a_lost_shard_adds_ec_decode(server):
     assert _phase_counts("GET-object").get("ec.decode", 0) == before + 1
     dec = next(ch for ch in tree["children"] if ch["name"] == "ec.decode")
     assert "kernel.rs_decode" in _names(dec)
+
+
+def test_one_upload_of_n_parts_in_the_multipart_series(server):
+    """multipart_op_ms 1 / N / 1, a part's engine phases beside a
+    PUT's (N times a phase), and mpu.* among the request phases."""
+    from tests.test_multipart_storage_class import (_complete, _initiate,
+                                                    _upload)
+    srv, c, _ = server
+    srv.layer.multipart.min_part_size = 1024
+    n_parts = 3
+    part = BODY[:3 * 1024 * 1024 + 17]
+
+    def ops():
+        return {op: _hist("minio_tpu_v2_multipart_op_ms", {"op": op})[1]
+                for op in ("initiate", "part", "complete")}
+
+    def put_phases():
+        return {ph: _hist("minio_tpu_v2_put_phase_duration_ms",
+                          {"phase": ph})[1]
+                for ph in ("engine_encode", "engine_write", "engine_commit")}
+
+    b_ops, b_put = ops(), put_phases()
+    b_bytes = m2.METRICS2.get("minio_tpu_v2_multipart_part_bytes_total")
+    b_post, b_part = _phase_counts("POST-object"), _phase_counts("PUT-object")
+    path = c._key_path("phases", "mpu-series")
+    uid = _initiate(c, path)
+    _complete(c, path, uid, _upload(c, path, uid, [part] * n_parts))
+    a_ops, a_put = ops(), put_phases()
+    assert {op: a_ops[op] - b_ops[op] for op in a_ops} == {
+        "initiate": 1, "part": n_parts, "complete": 1}
+    assert {ph: a_put[ph] - b_put[ph] for ph in a_put} == {
+        ph: n_parts for ph in a_put}
+    assert m2.METRICS2.get("minio_tpu_v2_multipart_part_bytes_total") \
+        == b_bytes + n_parts * len(part)
+    a_post, a_part = _phase_counts("POST-object"), _phase_counts("PUT-object")
+    # Complete: the upload record, the part listing (twice, §7), the
+    # link loop, the write lock, the renames.
+    for phase in ("mpu.load", "mpu.list", "mpu.stage", "lock.wait",
+                  "ec.commit", "unattributed"):
+        assert a_post.get(phase, 0) >= b_post.get(phase, 0) + 1, phase
+    assert a_post["unattributed"] == b_post.get("unattributed", 0) + 2
+    for phase in ("mpu.load", "door.recv", "ec.encode", "ec.write",
+                  "ec.commit"):
+        assert a_part.get(phase, 0) == b_part.get(phase, 0) + n_parts, phase
+    tree = _request_tree("POST-object", "/phases/mpu-series")
+    names = [ch["name"] for ch in tree["children"]]
+    assert names.count("mpu.stage") == names.count("ec.commit") == 4
+    assert names.count("mpu.list") == 2
+    assert c.get_object("phases", "mpu-series").body == part * n_parts
 
 
 # -- the device dispatch ----------------------------------------------------
