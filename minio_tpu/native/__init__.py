@@ -100,17 +100,18 @@ def _build_and_load() -> ctypes.CDLL | None:
                                        ctypes.c_size_t]
         lib.rs_gf_apply_mt.restype = None
         path = ctypes.c_char_p
+        out = ctypes.POINTER(ctypes.c_long)
         lib.fs_append.argtypes = [path, path, path, ctypes.c_void_p,
-                                  ctypes.c_size_t]
+                                  ctypes.c_size_t, out]
         lib.fs_append.restype = ctypes.c_int
         lib.fs_commit_stage.argtypes = [
             path, path, path, path, path, path, path, path,
-            ctypes.c_void_p, ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_long)]
+            ctypes.c_void_p, ctypes.c_size_t, out, out, out]
         lib.fs_commit_stage.restype = ctypes.c_int
         lib.fs_commit_meta.argtypes = [
             path, path, ctypes.c_void_p, ctypes.c_size_t, path, path,
-            path, path, path, path]
+            path, path, ctypes.POINTER(ctypes.c_int), ctypes.c_size_t,
+            path, path, out]
         lib.fs_commit_meta.restype = ctypes.c_int
         return lib
     except Exception as exc:
@@ -309,7 +310,9 @@ def lzb_decompress_native(blob: bytes, out_size: int) -> bytes | None:
 
 # --- a local drive's leg of a PUT (native/fsops.cc; storage/xl.py) ---
 #
-# Results: 0, a positive errno, or one of these typed conditions.
+# Results: 0, a positive errno, or one of these typed conditions. Each
+# wrapper returns its result first and, last, the system calls the
+# function made (minio_tpu_v2_disk_op_syscalls_total).
 FS_SRC_VOLUME_NOT_FOUND = -1
 FS_DST_VOLUME_NOT_FOUND = -2
 FS_STAGE_NOT_FOUND = -3
@@ -323,16 +326,21 @@ _FS_TLS = threading.local()
 
 
 def fs_append(lib: ctypes.CDLL, full: bytes, vol: bytes,
-              sys_tmp: bytes | None, data) -> int:
-    """XLStorage.append_file's system calls in one GIL-free call. The
-    payload is handed over without a copy: ``bytes`` as they are, any
-    other C-contiguous buffer through a numpy view (which raises for a
-    strided one, as a file's ``write`` does)."""
+              sys_tmp: bytes | None, data) -> tuple[int, int]:
+    """XLStorage.append_file's system calls in one GIL-free call:
+    ``(result, calls)``. The payload is handed over without a copy:
+    ``bytes`` as they are, any other C-contiguous buffer through a numpy
+    view (which raises for a strided one, as a file's ``write``
+    does)."""
+    calls = ctypes.c_long(0)
     if isinstance(data, bytes):
-        return lib.fs_append(full, vol, sys_tmp, data, len(data))
-    import numpy as np
-    view = np.frombuffer(data, dtype=np.uint8)
-    return lib.fs_append(full, vol, sys_tmp, view.ctypes.data, view.size)
+        ptr, size = data, len(data)
+    else:
+        import numpy as np
+        view = np.frombuffer(data, dtype=np.uint8)
+        ptr, size = view.ctypes.data, view.size
+    rc = lib.fs_append(full, vol, sys_tmp, ptr, size, ctypes.byref(calls))
+    return rc, calls.value
 
 
 def fs_commit_stage(lib: ctypes.CDLL, src_vol: bytes,
@@ -341,32 +349,37 @@ def fs_commit_stage(lib: ctypes.CDLL, src_vol: bytes,
                     src_dd: bytes | None, dst_dd: bytes | None,
                     xl_meta: bytes):
     """XLStorage._rename_data up to the XLMeta merge. Returns
-    ``(result, raw, read_ms)``: `raw` is the destination's xl.meta,
-    None when there is none yet, FS_META_TOO_BIG when the caller has to
-    read it; `read_ms` what reading it took, on the drive's side of the
-    GIL."""
+    ``(result, raw, read_ms, calls)``: `raw` is the destination's
+    xl.meta, None when there is none yet, FS_META_TOO_BIG when the
+    caller has to read it; `read_ms` what reading it took (on a fresh
+    key: the mkdir that said so), on the drive's side of the GIL."""
     buf = getattr(_FS_TLS, "meta", None)
     if buf is None:
         buf = _FS_TLS.meta = ctypes.create_string_buffer(_FS_META_CAP)
-    n, ns = ctypes.c_long(-1), ctypes.c_long(0)
+    n, ns, calls = ctypes.c_long(-1), ctypes.c_long(0), ctypes.c_long(0)
     rc = lib.fs_commit_stage(src_vol, src_sys_tmp, dst_vol, dst_sys_tmp,
                              dst_obj_dir, src_dd, dst_dd, xl_meta,
-                             buf, _FS_META_CAP,
-                             ctypes.byref(n), ctypes.byref(ns))
+                             buf, _FS_META_CAP, ctypes.byref(n),
+                             ctypes.byref(ns), ctypes.byref(calls))
     raw = None
     if rc == 0 and n.value != -1:
         raw = FS_META_TOO_BIG if n.value < 0 else buf[:n.value]
-    return rc, raw, ns.value / 1e6
+    return rc, raw, ns.value / 1e6, calls.value
 
 
 def fs_commit_meta(lib: ctypes.CDLL, tmp: bytes, xl_meta: bytes,
                    blob: bytes, dst_vol: bytes,
                    dst_sys_tmp: bytes | None, dst_obj_dir: bytes,
-                   old_dd: bytes | None, intent: bytes,
-                   stage_dir: bytes) -> int:
+                   old_dd: bytes | None, old_parts: list[int],
+                   intent: bytes, stage_dir: bytes) -> tuple[int, int]:
     """XLStorage._rename_data after the merge: `blob` becomes the new
-    xl.meta through a temporary, then the replaced data dir, the intent
-    breadcrumb and the stage directory go."""
-    return lib.fs_commit_meta(tmp, xl_meta, blob, len(blob), dst_vol,
-                              dst_sys_tmp, dst_obj_dir, old_dd, intent,
-                              stage_dir)
+    xl.meta through a temporary, then the replaced data dir (its files
+    by name: ``part.N`` for each of `old_parts`), the intent breadcrumb
+    and the stage directory go. ``(result, calls)``."""
+    calls = ctypes.c_long(0)
+    parts = (ctypes.c_int * len(old_parts))(*old_parts) if old_parts else None
+    rc = lib.fs_commit_meta(tmp, xl_meta, blob, len(blob), dst_vol,
+                            dst_sys_tmp, dst_obj_dir, old_dd, parts,
+                            len(old_parts), intent, stage_dir,
+                            ctypes.byref(calls))
+    return rc, calls.value

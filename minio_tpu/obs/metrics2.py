@@ -375,6 +375,12 @@ METRICS2.register(
     "GIL-free) or python (the library is missing, a fault plan is "
     "armed or storage fsync is on).")
 METRICS2.register(
+    "minio_tpu_v2_disk_op_syscalls_total", "counter",
+    "File-system calls append_file / rename_data made on the native "
+    "lane, by op (counted in native/fsops.cc; over "
+    "disk_op_duration_ms_count{op} they are the calls one drive call "
+    "costs: a drive's leg acts first and checks on failure).")
+METRICS2.register(
     "minio_tpu_v2_rpc_requests_total", "counter",
     "Peer RPC calls served, by service and method.")
 METRICS2.register(

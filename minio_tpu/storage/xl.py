@@ -28,6 +28,7 @@ from __future__ import annotations
 import errno
 import os
 import shutil
+import stat
 import time
 import uuid
 
@@ -179,6 +180,56 @@ def _fs_lane(op: str):
     return lib
 
 
+def _count_syscalls(op: str, calls: int) -> None:
+    """The system calls one native call made (native/fsops.cc counts
+    them, nothing is timed): over `disk_op_duration_ms_count{op}` they
+    are the file-system calls a leg costs."""
+    METRICS2.inc("minio_tpu_v2_disk_op_syscalls_total", {"op": op}, calls)
+
+
+# read_all's first read: an xl.meta, a part record or an upload's
+# record fits it many times over, and it is under malloc's mmap
+# threshold, so the buffer costs no system call of its own.
+_READ_FIRST = 64 * 1024
+
+
+def _read_whole(full: str) -> bytes:
+    """A whole file in the fewest calls its size allows: open, read,
+    the read that says "no more", close (a file past `_READ_FIRST` is
+    sized once and its rest read in one piece). The builtin `open` +
+    `read` makes eight for the same bytes (two fstats, an isatty ioctl
+    and an lseek among them), each a release of the GIL that has to be
+    won back from the process's other threads and, on a network mount,
+    most of them a round trip."""
+    fd = os.open(full, os.O_RDONLY | os.O_CLOEXEC)
+    try:
+        chunks = [os.read(fd, _READ_FIRST)]
+        if len(chunks[0]) == _READ_FIRST:
+            rest = os.fstat(fd).st_size - _READ_FIRST
+            chunks.append(os.read(fd, max(rest, 0) + 1))
+        while chunks[-1]:
+            chunks.append(os.read(fd, _READ_FIRST))
+        return chunks[0] if len(chunks) == 2 else b"".join(chunks)
+    finally:
+        os.close(fd)
+
+
+def _read_range(full: str, offset: int, length: int) -> bytes:
+    """`length` bytes at `offset` (fewer only where the file ends):
+    open, ONE pread where the range is inside the file, close."""
+    fd = os.open(full, os.O_RDONLY | os.O_CLOEXEC)
+    try:
+        data = os.pread(fd, length, offset)
+        while 0 < len(data) < length:
+            more = os.pread(fd, length - len(data), offset + len(data))
+            if not more:
+                break
+            data += more
+        return data
+    finally:
+        os.close(fd)
+
+
 def _is_valid_volume(volume: str) -> bool:
     return (volume not in ("", ".", "..") and "/" not in volume
             and "\\" not in volume)
@@ -262,8 +313,16 @@ class XLStorage(StorageAPI):
 
     def stat_volume(self, volume: str) -> dict:
         with _DiskOp("stat_volume", self):
-            p = self._check_vol(volume)
-            st = os.stat(p)
+            # One stat answers both questions; _check_vol is asked only
+            # when it says "no directory" (it raises, or self-creates
+            # the system volume).
+            p = self._vol_path(volume)
+            try:
+                st = os.stat(p)
+            except OSError:
+                st = None
+            if st is None or not stat.S_ISDIR(st.st_mode):
+                st = os.stat(self._check_vol(volume))
         return {"name": volume, "created": st.st_mtime}
 
     def delete_volume(self, volume: str, force: bool = False) -> None:
@@ -366,15 +425,26 @@ class XLStorage(StorageAPI):
                                     bytes(data)),
                 volume=volume)
 
-    def read_all(self, volume: str, path: str) -> bytes:
+    def _raise_read_miss(self, volume: str, path: str, e: OSError) -> None:
+        """Raise for a read's failed `open`. The readers act first
+        and check on failure: no `stat` of the volume goes before the
+        `open` (on a network mount: a round trip a read, ten to twelve
+        a GET), and a path that does not resolve is told apart HERE
+        into the volume gone (VolumeNotFound, from which the engine
+        takes BucketNotFound) and the file gone."""
         self._check_vol(volume)
+        if isinstance(e, NotADirectoryError):
+            raise serr.FaultyDisk(str(e))
+        raise serr.FileNotFound(f"{volume}/{path}")
+
+    def read_all(self, volume: str, path: str) -> bytes:
         full = self._file_path(volume, path)
         try:
-            with _DiskOp("read_all", self), open(full, "rb") as f:
+            with _DiskOp("read_all", self):
                 return FAULTS.filter_read(self.root, "read_all",
-                                          f.read())
-        except FileNotFoundError:
-            raise serr.FileNotFound(f"{volume}/{path}")
+                                          _read_whole(full))
+        except (FileNotFoundError, NotADirectoryError) as e:
+            self._raise_read_miss(volume, path, e)
         except IsADirectoryError:
             raise serr.FileNotFound(f"{volume}/{path}")
         except OSError as e:
@@ -382,15 +452,14 @@ class XLStorage(StorageAPI):
 
     def read_file(self, volume: str, path: str, offset: int,
                   length: int) -> bytes:
-        self._check_vol(volume)
         full = self._file_path(volume, path)
         try:
-            with _DiskOp("read_file", self), open(full, "rb") as f:
-                f.seek(offset)
-                return FAULTS.filter_read(self.root, "read_file",
-                                          f.read(length))
-        except FileNotFoundError:
-            raise serr.FileNotFound(f"{volume}/{path}")
+            with _DiskOp("read_file", self):
+                return FAULTS.filter_read(
+                    self.root, "read_file",
+                    _read_range(full, offset, length))
+        except (FileNotFoundError, NotADirectoryError) as e:
+            self._raise_read_miss(volume, path, e)
         except OSError as e:
             raise serr.FaultyDisk(str(e))
 
@@ -448,8 +517,10 @@ class XLStorage(StorageAPI):
         try:
             with _DiskOp("append_file", self):
                 if lib is not None:
-                    rc = native.fs_append(lib, os.fsencode(full),
-                                          *self._native_vol(volume), data)
+                    rc, calls = native.fs_append(
+                        lib, os.fsencode(full), *self._native_vol(volume),
+                        data)
+                    _count_syscalls("append_file", calls)
                     if rc != 0:
                         self._raise_native(rc, f"{volume}/{path}", volume)
                     return
@@ -605,11 +676,15 @@ class XLStorage(StorageAPI):
     def _rename_data_native(self, lib, src_volume: str, src_path: str,
                             fi: FileInfo, dst_volume: str,
                             dst_path: str) -> None:
-        """The steps of _rename_data_py below, in its order, as TWO
-        GIL-free calls (native/fsops.cc) around the XLMeta merge. No
-        crash point stands here: an armed fault plan takes the Python
-        lane (_native_lib), which is where the crash windows are
-        proven; what this lane leaves when a step FAILS is
+        """_rename_data_py below as TWO GIL-free calls (native/fsops.cc)
+        around the XLMeta merge: its order of visibility and its typed
+        results, but where the Python lane asks first (both volumes, the
+        stage, the name the data dir takes) this lane acts first and
+        checks on failure: the mkdir that succeeds says the key is fresh
+        (no xl.meta to read), the errno of a refused rename says what to
+        look at. No crash point stands here: an armed fault plan takes
+        the Python lane (_native_lib), which is where the crash windows
+        are proven; what this lane leaves when a step FAILS is
         tests/test_storage_native_lane.py's, from file-system state."""
         src_vol, src_sys = self._native_vol(src_volume)
         dst_vol, dst_sys = self._native_vol(dst_volume)
@@ -621,17 +696,19 @@ class XLStorage(StorageAPI):
                 src_volume, os.path.join(src_path, fi.data_dir)))
             dst_dd = os.fsencode(os.path.join(dst_obj_dir, fi.data_dir))
         xl_meta = os.path.join(dst_obj_dir, XL_META_FILE)
-        rc, raw, read_ms = native.fs_commit_stage(
+        rc, raw, read_ms, calls = native.fs_commit_stage(
             lib, src_vol, src_sys, dst_vol, dst_sys,
             os.fsencode(dst_obj_dir), src_dd, dst_dd,
             os.fsencode(xl_meta))
+        _count_syscalls("rename_data", calls)
         if rc != 0:
             self._raise_native(rc, f"{src_volume}/{src_path}",
                                dst_volume, src_volume)
         # The Python lane reads xl.meta through read_all; the drive
         # monitor's read class goes on hearing of it on this lane too
         # (a drive judged slow on reads is cleared by reads), timed in
-        # C, where no wait for the GIL is inside.
+        # C, where no wait for the GIL is inside. On a fresh key, where
+        # no xl.meta is opened, it is the mkdir that said so.
         _account(self.root, "read_all", read_ms)
         if raw is native.FS_META_TOO_BIG:
             with open(xl_meta, "rb") as f:
@@ -640,26 +717,30 @@ class XLStorage(StorageAPI):
             meta = XLMeta() if raw is None else XLMeta.load(raw)
         except ValueError as e:
             raise serr.FileCorrupt(str(e))
-        old_dd = self._merge_version(meta, fi)
+        old = self._merge_version(meta, fi)
+        old_dd, old_parts = None, []
+        if old is not None:
+            old_dd = os.fsencode(os.path.join(dst_obj_dir, old["dataDir"]))
+            old_parts = [p["number"] for p in old.get("parts", [])]
         tmp = os.path.join(self._sys_tmp, str(uuid.uuid4()))
-        rc = native.fs_commit_meta(
+        rc, calls = native.fs_commit_meta(
             lib, os.fsencode(tmp), os.fsencode(xl_meta), meta.dump(),
-            dst_vol, dst_sys, os.fsencode(dst_obj_dir),
-            None if old_dd is None
-            else os.fsencode(os.path.join(dst_obj_dir, old_dd)),
+            dst_vol, dst_sys, os.fsencode(dst_obj_dir), old_dd, old_parts,
             os.fsencode(os.path.join(src_dir, INTENT_FILE)),
             os.fsencode(src_dir))
+        _count_syscalls("rename_data", calls)
         if rc != 0:
             self._raise_native(rc, f"{dst_volume}/{dst_path}", dst_volume)
 
     @staticmethod
-    def _merge_version(meta: XLMeta, fi: FileInfo) -> str | None:
-        """Add `fi` to `meta`; returns the data dir this frees, if any.
-        Null-version overwrite frees the PREVIOUS NULL version's data
-        dir only (real versions keep theirs; ref xlMetaV2.AddVersion
-        null-version replacement semantics). Crash safety: the caller
-        persists the new xl.meta BEFORE removing that data dir, so
-        metadata never points at deleted shards."""
+    def _merge_version(meta: XLMeta, fi: FileInfo) -> dict | None:
+        """Add `fi` to `meta`; returns the version whose data dir this
+        frees, if any (its `dataDir`, and its `parts`: the names of the
+        files in it). Null-version overwrite frees the PREVIOUS NULL
+        version's data dir only (real versions keep theirs; ref
+        xlMetaV2.AddVersion null-version replacement semantics). Crash
+        safety: the caller persists the new xl.meta BEFORE removing
+        that data dir, so metadata never points at deleted shards."""
         old = None
         if fi.version_id == "":
             for v in meta.versions:
@@ -668,7 +749,7 @@ class XLStorage(StorageAPI):
                     break
         meta.add_version(fi)
         if old and old.get("dataDir") and old["dataDir"] != fi.data_dir:
-            return old["dataDir"]
+            return old
         return None
 
     def _rename_data_py(self, src_volume: str, src_path: str, fi: FileInfo,
@@ -712,7 +793,7 @@ class XLStorage(StorageAPI):
             meta = self._read_xlmeta(dst_volume, dst_path)
         except serr.FileNotFound:
             meta = XLMeta()
-        old_dd = self._merge_version(meta, fi)
+        old = self._merge_version(meta, fi)
         # dir_ready: dst_obj_dir was created at the top of this call;
         # xl.meta lives directly in it. volume still passed so a
         # mid-commit ENOENT (racing delete) resolves typed.
@@ -725,8 +806,8 @@ class XLStorage(StorageAPI):
         # remains — a death here must read as the new version with
         # the leftovers swept at next boot.
         FAULTS.crash_point(CRASH_RENAME_POST)
-        if old_dd is not None:
-            old_dd = os.path.join(dst_obj_dir, old_dd)
+        if old is not None:
+            old_dd = os.path.join(dst_obj_dir, old["dataDir"])
             if os.path.isdir(old_dd):
                 shutil.rmtree(old_dd, ignore_errors=True)
         # Clean the tmp staging dir — after the data-dir replace only

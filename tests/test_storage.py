@@ -47,6 +47,129 @@ def test_file_roundtrip(disk):
     assert disk.list_dir("v", "") == []
 
 
+READERS = {"read_all": lambda d, v, p: d.read_all(v, p),
+           "read_file": lambda d, v, p: d.read_file(v, p, 1, 3)}
+
+
+@pytest.mark.parametrize("gone, raised", [
+    ("key", serr.FileNotFound), ("prefix", serr.FileNotFound),
+    ("bucket", serr.VolumeNotFound), ("bucket_is_a_file", serr.VolumeNotFound),
+    ("below_a_file", serr.FaultyDisk)])
+@pytest.mark.parametrize("reader", sorted(READERS))
+def test_a_read_miss_is_typed_by_what_is_gone(disk, reader, gone, raised):
+    """The readers open first and look at the volume only when the open
+    fails: the typed result is what the check in front used to give (the
+    engine takes BucketNotFound from VolumeNotFound)."""
+    disk.make_volume("v")
+    disk.write_all("v", "plain", b"hello")
+    with open(os.path.join(disk.root, "flat"), "wb") as f:
+        f.write(b"a drive's bucket directory replaced by a file")
+    volume, path = {"key": ("v", "missing"),
+                    "prefix": ("v", "no/such/prefix/key"),
+                    "bucket": ("nobucket", "plain"),
+                    "bucket_is_a_file": ("flat", "plain"),
+                    "below_a_file": ("v", "plain/below")}[gone]
+    with pytest.raises(raised):
+        READERS[reader](disk, volume, path)
+    assert not os.path.exists(os.path.join(disk.root, "nobucket"))
+
+
+@pytest.mark.parametrize("reader", sorted(READERS))
+def test_a_read_hit_asks_nothing_about_its_volume(disk, reader,
+                                                  monkeypatch):
+    disk.make_volume("v")
+    disk.write_all("v", "a/b.txt", b"hello")
+    asked = []
+
+    def counting(real):
+        def call(path, *a, **kw):
+            asked.append(path)
+            return real(path, *a, **kw)
+        return call
+    monkeypatch.setattr(os, "stat", counting(os.stat))
+    monkeypatch.setattr(os.path, "isdir", counting(os.path.isdir))
+    want = b"hello" if reader == "read_all" else b"ell"
+    assert READERS[reader](disk, "v", "a/b.txt") == want
+    assert asked == []
+    # ... and a miss does ask, once it has missed.
+    with pytest.raises(serr.FileNotFound):
+        READERS[reader](disk, "v", "a/missing")
+    assert os.path.join(disk.root, "v") in asked
+
+
+def count_os_calls(monkeypatch, *names):
+    made = []
+    for name in names:
+        real = getattr(os, name)
+        monkeypatch.setattr(
+            os, name, lambda *a, _n=name, _r=real, **kw: (
+                made.append(_n), _r(*a, **kw))[1])
+    return made
+
+
+@pytest.mark.parametrize("size", [0, 5, 65535, 65536, 65537, 300_000])
+def test_read_all_makes_the_calls_its_size_allows(disk, monkeypatch, size):
+    """open, read, the read that says "no more", close; a file past the
+    first read's 64 KiB is sized once and its rest read in one piece."""
+    body = os.urandom(size)
+    disk.make_volume("v")
+    disk.write_all("v", "k", body)
+    made = count_os_calls(monkeypatch, "open", "read", "fstat", "stat",
+                          "close", "lseek")
+    assert disk.read_all("v", "k") == body
+    if size == 0:
+        assert made == ["open", "read", "close"]
+    elif size < 65536:
+        assert made == ["open", "read", "read", "close"]
+    else:
+        want = ["open", "read", "fstat", "read"]
+        assert made == want + (["read"] if size > 65536 else []) + ["close"]
+
+
+@pytest.mark.parametrize("offset, length, want", [
+    (0, 1000, slice(0, 1000)), (100, 50, slice(100, 150)),
+    (900, 500, slice(900, 1000)), (1000, 10, slice(0, 0)),
+    (2000, 10, slice(0, 0)), (5, 0, slice(0, 0))])
+def test_read_file_is_one_pread_inside_the_file(disk, monkeypatch, offset,
+                                                length, want):
+    body = os.urandom(1000)
+    disk.make_volume("v")
+    disk.write_all("v", "k", body)
+    made = count_os_calls(monkeypatch, "open", "pread", "read", "fstat",
+                          "stat", "close", "lseek")
+    assert disk.read_file("v", "k", offset, length) == body[want]
+    inside = offset + length <= 1000 or offset >= 1000
+    assert made == ["open", "pread"] + ([] if inside else ["pread"]) \
+        + ["close"]
+
+
+@pytest.mark.parametrize("reader", sorted(READERS))
+def test_a_read_of_a_directory_is_typed_as_it_was(disk, reader):
+    disk.make_volume("v")
+    disk.write_all("v", "a/b.txt", b"hello")
+    with pytest.raises(serr.FileNotFound if reader == "read_all"
+                       else serr.FaultyDisk):
+        READERS[reader](disk, "v", "a")
+
+
+def test_stat_volume_is_one_stat(disk, monkeypatch):
+    disk.make_volume("v")
+    made = count_os_calls(monkeypatch, "stat")
+    assert disk.stat_volume("v")["name"] == "v"
+    assert made == ["stat"]
+    with pytest.raises(serr.VolumeNotFound):
+        disk.stat_volume("nobucket")
+    with open(os.path.join(disk.root, "flat"), "wb") as f:
+        f.write(b"x")
+    with pytest.raises(serr.VolumeNotFound):
+        disk.stat_volume("flat")
+    # The system volume self-creates, as before.
+    import shutil
+    shutil.rmtree(os.path.join(disk.root, MINIO_META_BUCKET))
+    assert disk.stat_volume(MINIO_META_BUCKET)["name"] == MINIO_META_BUCKET
+    assert os.path.isdir(os.path.join(disk.root, MINIO_META_BUCKET, "tmp"))
+
+
 def test_path_traversal_blocked(disk):
     disk.make_volume("v")
     with pytest.raises(serr.StorageError):

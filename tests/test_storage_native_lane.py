@@ -43,9 +43,9 @@ def lane_count(op: str, lane: str) -> int:
 
 
 def fi_of(data_dir: str, size: int, version_id: str = "",
-          mod_time: float = 1700000000.5) -> FileInfo:
+          mod_time: float = 1700000000.5, key: str = "o/k") -> FileInfo:
     return FileInfo(
-        volume="b", name="o/k", version_id=version_id, data_dir=data_dir,
+        volume="b", name=key, version_id=version_id, data_dir=data_dir,
         size=size, mod_time=mod_time, metadata={"etag": "e" * 32},
         parts=[ObjectPartInfo(number=1, size=size, actual_size=size,
                               etag="e" * 32)],
@@ -261,6 +261,85 @@ def test_append_below_a_regular_file_is_faulty_disk(monkeypatch, tmp_path,
 
 
 @pytest.mark.parametrize("lane", LANES)
+def test_source_volume_gone_at_rename_time_is_its_volume_not_found(
+        monkeypatch, tmp_path, lane):
+    """The native lane does not look at the source volume before it
+    renames; the refused rename (ENOENT) is told apart, and a source
+    volume that is gone is named, as the Python lane's check names it."""
+    take(monkeypatch, lane)
+    disk = XLStorage(str(tmp_path / "d"))
+    disk.make_volume("b")
+    disk.make_volume(STAGE_VOL)
+    stage_outside(disk, DD1, b"a" * 10)
+    disk.delete_volume(STAGE_VOL, force=True)
+    with pytest.raises(serr.VolumeNotFound, match=STAGE_VOL):
+        disk.rename_data(STAGE_VOL, "s2", fi_of(DD1, 10), "b", "o/k")
+    assert not os.path.exists(os.path.join(disk.root, STAGE_VOL))
+    with pytest.raises(serr.FileNotFound):
+        disk.read_version("b", "o/k")
+
+
+@pytest.mark.parametrize("data_dir", [DD1, ""], ids=["data_dir", "no_data"])
+@pytest.mark.parametrize("lane", LANES)
+def test_commit_below_a_regular_file_is_faulty_disk(monkeypatch, tmp_path,
+                                                    lane, data_dir):
+    """A regular file holds the object directory's name: the mkdir's
+    EEXIST is not looked into, the next call (the rename, or the open
+    of xl.meta where the version has no data dir) says ENOTDIR, which
+    is the drive's fault as it was; file and stage stay."""
+    take(monkeypatch, lane)
+    disk = XLStorage(str(tmp_path / "d"))
+    disk.make_volume("b")
+    disk.write_all("b", "o/k", b"squatter")
+    tmp = staged(disk)
+    with pytest.raises(serr.FaultyDisk):
+        disk.rename_data(MINIO_META_BUCKET, tmp,
+                         fi_of(data_dir, 10 if data_dir else 0), "b", "o/k")
+    assert disk.read_all("b", "o/k") == b"squatter"
+    assert disk.read_all(MINIO_META_BUCKET,
+                         f"{tmp}/{DD1}/part.1") == b"a" * 10
+    assert disk.read_all(MINIO_META_BUCKET,
+                         f"{tmp}/{INTENT_FILE}") == INTENT
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_a_staged_data_dir_that_is_a_file_is_file_not_found(
+        monkeypatch, tmp_path, lane):
+    """rename(2) would move a regular file in as gladly as a directory:
+    the stage's data dir is asked to be one (a trailing slash on the
+    native lane, a stat on the Python one)."""
+    take(monkeypatch, lane)
+    disk = XLStorage(str(tmp_path / "d"))
+    disk.make_volume("b")
+    tmp = f"{TMP_PATH}/s1"
+    disk.append_file(MINIO_META_BUCKET, f"{tmp}/{INTENT_FILE}", INTENT)
+    disk.append_file(MINIO_META_BUCKET, f"{tmp}/{DD1}", b"not a directory")
+    with pytest.raises(serr.FileNotFound):
+        disk.rename_data(MINIO_META_BUCKET, tmp, fi_of(DD1, 10), "b", "o/k")
+    assert not os.path.exists(os.path.join(disk.root, "b/o/k", DD1))
+    assert disk.read_all(MINIO_META_BUCKET,
+                         f"{tmp}/{DD1}") == b"not a directory"
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_an_old_data_dir_with_a_stray_file_is_removed_whole(
+        monkeypatch, tmp_path, lane):
+    """The freed data dir goes by the names its version listed; what
+    else is in it (rmdir: ENOTEMPTY) takes the walk, so nothing of it
+    stays."""
+    take(monkeypatch, lane)
+    disk = XLStorage(str(tmp_path / "d"))
+    disk.make_volume("b")
+    put_legs(disk, "s1", DD1, [b"a" * 1000])
+    disk.write_all("b", f"o/k/{DD1}/stray.bin", b"x")
+    disk.write_all("b", f"o/k/{DD1}/sub/deep.bin", b"y")
+    put_legs(disk, "s2", DD2, [b"b" * 700])
+    assert sorted(os.listdir(os.path.join(disk.root, "b/o/k"))) \
+        == sorted([DD2, XL_META_FILE])
+    assert disk.read_all("b", f"o/k/{DD2}/part.1") == b"b" * 700
+
+
+@pytest.mark.parametrize("lane", LANES)
 def test_large_xl_meta_is_merged_whole(monkeypatch, tmp_path, lane):
     """An xl.meta past the native read buffer (many versions) is read by
     the caller and merged all the same."""
@@ -272,6 +351,101 @@ def test_large_xl_meta_is_merged_whole(monkeypatch, tmp_path, lane):
     put_legs(disk, "s1", DD1, [b"a" * 10], version_id=VID)
     put_legs(disk, "s2", DD2, [b"b" * 10])
     assert [v.data_dir for v in disk.read_versions("b", "o/k")] == [DD2, DD1]
+
+
+# --- the calls a leg costs, from the counter native/fsops.cc feeds
+
+def syscalls(op: str) -> int:
+    return METRICS2.get("minio_tpu_v2_disk_op_syscalls_total",
+                        {"op": op}) or 0
+
+
+def counted_leg(disk: XLStorage, stage: str, data_dir: str, body: bytes,
+                key: str) -> tuple[list[int], int]:
+    """One drive's leg of a PUT of `key`: the system calls of each
+    append and of the commit."""
+    tmp = f"{TMP_PATH}/{stage}"
+    costs = []
+    for path, data in ((f"{tmp}/{INTENT_FILE}", INTENT),
+                       (f"{tmp}/{data_dir}/part.1", body)):
+        before = syscalls("append_file")
+        disk.append_file(MINIO_META_BUCKET, path, data)
+        costs.append(syscalls("append_file") - before)
+    before = syscalls("rename_data")
+    disk.rename_data(MINIO_META_BUCKET, tmp,
+                     fi_of(data_dir, len(body), key=key), "b", key)
+    return costs, syscalls("rename_data") - before
+
+
+def fresh_key(disk):
+    return "k"
+
+
+def overwrite(disk):
+    counted_leg(disk, "s0", DD1, b"a" * 1000, "k")
+    return "k"
+
+
+def retried_commit(disk):
+    # A first try moved its data dir in and failed before xl.meta: the
+    # retry stages the same data dir again and finds the name taken.
+    disk.write_all("b", f"k/{DD2}/part.1", b"first try")
+    return "k"
+
+
+def nested_prefix(disk):
+    return "a/b/c/k"
+
+
+# (scenario, most calls an append, most calls a commit): a fresh commit
+# is mkdir + rename, then tmp open / write / close / rename and the
+# stage's unlink + rmdir; an overwrite adds the xl.meta read (open,
+# read, read, close) and the old data dir's unlink + rmdir. The slow
+# paths (a walk over what holds the name; a prefix chain to build below
+# the checked volume) may cost more.
+BUDGETS = [(fresh_key, 5, 2 + 6), (overwrite, 5, 6 + 8),
+           (retried_commit, 5, None), (nested_prefix, 5, None)]
+
+
+@pytest.mark.parametrize("scenario, per_append, per_commit", BUDGETS,
+                         ids=lambda v: getattr(v, "__name__", None))
+def test_a_leg_keeps_its_call_budget(tmp_path, scenario, per_append,
+                                     per_commit):
+    """A leg acts first and checks on failure: no stat before a mkdir,
+    a rename or an open, no listing to find a file xl.meta named."""
+    disk = XLStorage(str(tmp_path / "d"))
+    disk.make_volume("b")
+    key = scenario(disk)
+    appends, commit = counted_leg(disk, "s1", DD2, b"b" * 700, key)
+    assert all(0 < n <= per_append for n in appends), appends
+    assert commit > 0
+    if per_commit is not None:
+        assert commit <= per_commit
+    got = tree(disk.root)
+    assert {p: v for p, v in got.items()
+            if v is not None and not p.endswith(XL_META_FILE)} \
+        == {f"b/{key}/{DD2}/part.1": b"b" * 700}
+    assert [v["dataDir"] for v in got[f"b/{key}/{XL_META_FILE}"]] == [DD2]
+    assert not [p for p in got
+                if p.startswith(f"{MINIO_META_BUCKET}/{TMP_PATH}/")]
+
+
+def test_a_later_append_of_a_stream_is_three_calls(tmp_path):
+    """Its directory is there: open, write, close."""
+    disk = XLStorage(str(tmp_path / "d"))
+    path = f"{TMP_PATH}/s1/{DD1}/part.1"
+    disk.append_file(MINIO_META_BUCKET, path, b"a" * 10)
+    before = syscalls("append_file")
+    disk.append_file(MINIO_META_BUCKET, path, b"b" * 10)
+    assert syscalls("append_file") - before == 3
+    assert disk.read_all(MINIO_META_BUCKET, path) == b"a" * 10 + b"b" * 10
+
+
+def test_the_python_lane_counts_no_native_calls(monkeypatch, tmp_path):
+    take(monkeypatch, "python")
+    before = syscalls("append_file"), syscalls("rename_data")
+    run_on(monkeypatch, tmp_path, "python", overwrite_null)
+    assert (syscalls("append_file"), syscalls("rename_data")) == before
 
 
 def lane_counts() -> dict:
