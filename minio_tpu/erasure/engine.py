@@ -329,6 +329,36 @@ class ErasureObjects:
     def _mark_update(self, bucket: str, object_name: str = "") -> None:
         self.update_tracker.mark(bucket, object_name)
 
+    def each_disk(self, op: str, fn) -> tuple[list, list]:
+        """`fn(disk)` on every drive of the set at once, as
+        parallel_map gives it: (results, errs) by disk position. A
+        drive the monitor holds `faulty` is left out WITHOUT the call
+        being issued (DRIVEMON.skip_faulty counts the leg): its slot
+        answers DriveQuarantined, which the quorum math treats as any
+        other down disk. For the fan-outs that have no rule of their
+        own (put_object and the read path do: they let a quarantined
+        drive back in when quorum is at stake)."""
+        from ..obs.drivemon import DRIVEMON
+        n = len(self.disks)
+        live = DRIVEMON.skip_faulty(self.endpoints, op)
+        if len(live) == n:
+            return parallel_map([lambda d=d: fn(d) for d in self.disks])
+        results: list = [None] * n
+        errs: list = [serr.DriveQuarantined(ep) for ep in self.endpoints]
+        got, got_errs = parallel_map(
+            [lambda i=i: fn(self.disks[i]) for i in live])
+        for i, r, e in zip(live, got, got_errs):
+            results[i], errs[i] = r, e
+        return results, errs
+
+    def live_disks(self, op: str) -> list:
+        """The set's drives without the `faulty` ones (not asked, each
+        counted as a skipped leg), for the serial walks and first-
+        success reads that need no answer from every drive."""
+        from ..obs.drivemon import DRIVEMON
+        return [self.disks[i]
+                for i in DRIVEMON.skip_faulty(self.endpoints, op)]
+
     # ------------------------------------------------------------------
     # buckets
 
@@ -416,8 +446,7 @@ class ErasureObjects:
         def one(disk):
             return [disk.stat_volume(v) for v in disk.list_volumes()]
 
-        results, errs = parallel_map(
-            [lambda d=d: one(d) for d in self.disks])
+        results, errs = self.each_disk("list_volumes", one)
         responding = sum(1 for e in errs if e is None)
         seen: dict[str, dict] = {}
         counts: dict[str, int] = {}
@@ -439,8 +468,8 @@ class ErasureObjects:
         """True if any reachable disk has the bucket and no not-found
         majority exists (reads tolerate offline disks; ref getBucketInfo
         first-healthy-disk semantics, cmd/erasure-bucket.go)."""
-        _, errs = parallel_map(
-            [lambda d=d: d.stat_volume(bucket) for d in self.disks])
+        _, errs = self.each_disk("stat_volume",
+                                 lambda d: d.stat_volume(bucket))
         ok = sum(1 for e in errs if e is None)
         not_found = sum(1 for e in errs
                         if isinstance(e, serr.VolumeNotFound))
@@ -763,8 +792,11 @@ class ErasureObjects:
         # (ref addPartial, cmd/erasure-object.go:1082).
         dead = [i for i in range(n) if errs[i] is not None]
         if dead:
-            cleanup_tmp(dead)
-            self.mrf.add(bucket, object_name)
+            # A leg never attempted (a `faulty` drive skipped up front)
+            # left no stage: no delete is issued to it either.
+            cleanup_tmp([i for i in dead if not isinstance(
+                errs[i], serr.DriveQuarantined)])
+            self.mrf.add(bucket, object_name, dead)
         self._mark_update(bucket, object_name)
         # Write-through invalidation: drop every cached decoded copy
         # of the old version, locally and (async) on every peer.
@@ -1027,16 +1059,9 @@ class ErasureObjects:
         # quarantined-and-stalling drive would drag every stat/GET).
         # They answer as pre-failed; the quorum math treats that like
         # any other down disk.
-        from ..obs.drivemon import DRIVEMON
-
-        def one(i: int):
-            if DRIVEMON.is_quarantined(self.endpoints[i]):
-                raise serr.DriveQuarantined(self.endpoints[i])
-            return self.disks[i].read_version(bucket, object_name,
-                                              version_id)
-
-        results, errs = parallel_map(
-            [lambda i=i: one(i) for i in range(len(self.disks))])
+        results, errs = self.each_disk(
+            "read_version",
+            lambda d: d.read_version(bucket, object_name, version_id))
         fis = [r if e is None else None for r, e in zip(results, errs)]
         # Availability over hygiene: when the healthy drives alone
         # can't produce k readable shards (quarantine plus a real
@@ -1337,6 +1362,9 @@ class ErasureObjects:
              if alive[i] and DRIVEMON.is_quarantined(self.endpoints[i])]
         if not q or sum(alive) - len(q) < wq:
             return []
+        from ..obs.metrics2 import METRICS2
+        METRICS2.inc("minio_tpu_v2_drive_legs_skipped_total",
+                     {"op": "write"}, len(q))
         for i in q:
             alive[i] = False
             disk_errs[i] = serr.DriveQuarantined(
@@ -1818,10 +1846,10 @@ class ErasureObjects:
                 version_id=new_version_id(), deleted=True,
                 mod_time=now())
             with self.ns_lock.write_locked(bucket, object_name):
-                _, errs = parallel_map(
-                    [lambda d=d: d.write_metadata(bucket, object_name,
-                                                  marker)
-                     for d in self.disks])
+                _, errs = self.each_disk(
+                    "write_metadata",
+                    lambda d: d.write_metadata(bucket, object_name,
+                                               marker))
                 self.guard_commit_bucket_gone(errs, bucket,
                                               object_name,
                                               marker.version_id)
@@ -1839,19 +1867,23 @@ class ErasureObjects:
         was_marker = False
         with self.ns_lock.write_locked(bucket, object_name):
             if version_id:
-                for d in self.disks:
+                for d in self.live_disks("read_version"):
                     try:
                         was_marker = d.read_version(
                             bucket, object_name, version_id).deleted
                         break
                     except serr.StorageError:
                         continue
-            _, errs = parallel_map(
-                [lambda d=d: d.delete_version(bucket, object_name, fi)
-                 for d in self.disks])
+            _, errs = self.each_disk(
+                "delete_version",
+                lambda d: d.delete_version(bucket, object_name, fi))
         not_found = sum(1 for e in errs if isinstance(
             e, (serr.FileNotFound, serr.VersionNotFound)))
-        if not_found == len(self.disks):
+        # Every drive that is there says so, and a quorum of them is.
+        away = sum(1 for e in errs if isinstance(
+            e, (serr.DiskNotFound, serr.DriveQuarantined)))
+        if (not_found + away == len(self.disks)
+                and not_found >= write_quorum(self.k, self.m)):
             raise ObjectNotFound(f"{bucket}/{object_name}")
         # A missing key counts as success for a DELETE (idempotent), so
         # fold it to None BEFORE the bucket-gone check — a degraded set
@@ -1879,9 +1911,9 @@ class ErasureObjects:
         get_object_info, is not blinded by a delete marker being the
         latest version."""
         self._check_not_reserved(bucket)
-        results, _ = parallel_map(
-            [lambda d=d: d.read_versions(bucket, object_name)
-             for d in self.disks])
+        results, _ = self.each_disk(
+            "read_versions",
+            lambda d: d.read_versions(bucket, object_name))
         return any(r for r in results
                    if r is not None and not isinstance(r, BaseException))
 
@@ -1956,7 +1988,7 @@ class ErasureObjects:
                     continue
                 walk(disk, f"{path}{e}" if path else e)
 
-        for disk in self.disks:
+        for disk in self.live_disks("list_dir"):
             try:
                 base_entries = disk.list_dir(bucket, "")
             except serr.StorageError:

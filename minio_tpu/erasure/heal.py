@@ -28,7 +28,7 @@ import numpy as np
 from ..faultinject import FAULTS
 from ..parallel.quorum import parallel_map
 from ..storage import errors as serr
-from ..storage.metadata import FileInfo
+from ..storage.metadata import REGEN_ALGORITHM, FileInfo
 from ..storage.xl import INTENT_FILE, MINIO_META_BUCKET, TMP_PATH
 from ..utils import ceil_frac
 from . import bitrot
@@ -37,6 +37,17 @@ from .codec import codec_for_algorithm
 # Cap on stacked survivor bytes per coalesced heal dispatch: large
 # enough to saturate the device, small enough to bound heal memory.
 HEAL_BATCH_BYTES = 64 * 1024 * 1024
+
+
+def count_heal_attempt(by: str, outcome: str) -> None:
+    """One background heal attempt: `by` mrf | newdisk, `outcome`
+    started (survivors may be read from here on) | abandoned_offline
+    (every target is a drive the monitor holds `faulty`: nothing was
+    read, the debt is kept)."""
+    from ..obs.metrics2 import METRICS2
+    METRICS2.inc("minio_tpu_v2_heal_attempts_total",
+                 {"by": by, "outcome": outcome})
+
 
 # Crash points on the heal write-back commit: mid shard regeneration
 # (staged frames on the bad disks, object still degraded) and just
@@ -58,6 +69,9 @@ class HealResult:
     missing_disks: list[int] = field(default_factory=list)
     dangling: bool = False
     skipped_lock: bool = False  # lock-contended: requeued via MRF
+    # Bad disks the drive monitor holds `faulty`: nothing is written to
+    # them, and a heal whose only targets they are reads no survivor.
+    offline_disks: list[int] = field(default_factory=list)
 
     @property
     def healthy(self) -> bool:
@@ -91,6 +105,8 @@ class Healer:
         # purges it, not as ObjectNotFound (which would skip it forever).
         fi, agreed = eng._quorum_file_info(bucket, object_name,
                                            reduce_notfound=False)
+        from .regen.repair import REPAIR_BYTES
+        mode = "regen" if fi.erasure.algorithm == REGEN_ALGORITHM else "rs"
 
         def check(i: int) -> str:
             f = agreed[i]
@@ -99,7 +115,11 @@ class Healer:
             if fi.size == 0 or fi.deleted:
                 return "ok"
             try:
-                eng.disks[i].verify_file(bucket, object_name, f)
+                # The deep scan reads the whole part file: background
+                # bytes off a survivor, counted beside the rebuild's
+                # (src=disk) under a src of their own.
+                REPAIR_BYTES.add(mode, "verify", eng.disks[i].verify_file(
+                    bucket, object_name, f) or 0)
                 return "ok"
             except serr.FileCorrupt:
                 return "corrupt"
@@ -139,8 +159,12 @@ class Healer:
                                                  lock_timeout):
                 res = self._heal_object_locked(bucket, object_name,
                                                dry_run=True)
-            if (dry_run or res.dangling
-                    or not (res.corrupt_disks or res.missing_disks)):
+            bad = set(res.corrupt_disks) | set(res.missing_disks)
+            # Nothing bad, or nothing to write to (every bad disk is
+            # `faulty`): the rebuild's k survivor reads, verify and
+            # reconstruct would be thrown away. The caller keeps the
+            # debt (MRFQueue parks the entry).
+            if dry_run or res.dangling or bad <= set(res.offline_disks):
                 return res
             with self.engine.ns_lock.write_locked(bucket, object_name,
                                                   lock_timeout):
@@ -199,12 +223,20 @@ class Healer:
         if not bad:
             res.after_ok = res.before_ok
             return res
+        from ..obs.drivemon import DRIVEMON
+        res.offline_disks = [i for i in bad
+                             if DRIVEMON.is_quarantined(eng.endpoints[i])]
         k, m = fi.erasure.data_blocks, fi.erasure.parity_blocks
         if res.before_ok < k:
-            res.dangling = True  # unrecoverable (ref dangling purge)
+            # Unrecoverable (ref dangling purge), unless drives that
+            # may hold the rest are merely away.
+            res.dangling = not res.offline_disks
             res.after_ok = res.before_ok
             return res
-        if dry_run:
+        # A `faulty` drive is no target: it comes back through probation
+        # and is healed then.
+        bad = [i for i in bad if i not in res.offline_disks]
+        if dry_run or not bad:
             res.after_ok = res.before_ok
             return res
 
@@ -276,7 +308,7 @@ class Healer:
         # sources first — a suspect drive only serves a heal read when
         # no healthier survivor can (the same any-k-of-n policy the GET
         # path uses).
-        from ..obs.drivemon import DRIVEMON, OK as _DM_OK
+        from ..obs.drivemon import OK as _DM_OK
 
         def _rank(i: int) -> tuple:
             ep = eng.endpoints[i]
@@ -686,6 +718,8 @@ class NewDiskMonitor:
 
     def tick(self) -> list[int]:
         """One detection pass; returns indices of disks swept."""
+        import logging
+        from ..obs.drivemon import DRIVEMON
         eng = self.healer.engine
         buckets = [b["name"] for b in eng.list_buckets()]
         if not buckets:
@@ -698,14 +732,26 @@ class NewDiskMonitor:
             # over write locks.
             if not hasattr(disk, "root"):
                 continue
+            if DRIVEMON.is_quarantined(eng.endpoints[i]):
+                # `faulty`: nothing can be written to it, so nothing is
+                # read for it and no call goes to it. Probation brings
+                # it back (QuarantineProber), and its heal follows.
+                count_heal_attempt("newdisk", "abandoned_offline")
+                self._swept.pop(i, None)
+                continue
             try:
                 self._heal_format(i, disk)
+            except serr.StorageError as exc:
+                # The drive does not answer: one line, no traceback;
+                # the list_volumes below says the same and moves on.
+                logging.getLogger("minio_tpu.heal").warning(
+                    "format re-stamp skipped for disk %d (%s): %s: %s",
+                    i, disk.root, type(exc).__name__, exc)
             except Exception:
-                # Dead disk / no healthy peer reachable right now: the
-                # volumes check below still runs, and every later tick
-                # retries the re-stamp. Log it — a silently un-stamped
-                # drive would fail the NEXT restart's format quorum.
-                import logging
+                # No healthy peer reachable right now: the volumes
+                # check below still runs, and every later tick retries
+                # the re-stamp. Log it — a silently un-stamped drive
+                # would fail the NEXT restart's format quorum.
                 logging.getLogger("minio_tpu.heal").warning(
                     "format re-stamp failed for disk %d (%s)",
                     i, getattr(disk, "root", disk), exc_info=True)
@@ -728,6 +774,7 @@ class NewDiskMonitor:
                 continue
             # heal_disk re-creates missing bucket volumes itself
             # (heal_bucket per quorum-listed bucket) before sweeping.
+            count_heal_attempt("newdisk", "started")
             self.healer.heal_disk(i)
             self._swept[i] = time.monotonic()
             self.sweeps += 1
@@ -796,6 +843,12 @@ class QuarantineProber:
         from ..obs.drivemon import DRIVEMON
         eng = self.engine
         reinstated = []
+        if eng.mrf.parked() and not any(
+                DRIVEMON.is_quarantined(ep) for ep in eng.endpoints):
+            # Parked for a drive that did not answer and was never
+            # counted out (back before the monitor's verdict): the
+            # healer asks it again, and parks again if it must.
+            eng.mrf.release_parked()
         for i, disk in enumerate(eng.disks):
             ep = eng.endpoints[i]
             if not DRIVEMON.is_quarantined(ep):
@@ -805,6 +858,9 @@ class QuarantineProber:
                 if DRIVEMON.probation_pass(ep):
                     self.reinstated += 1
                     reinstated.append(i)
+                    # The debt kept while it was away, first: the MRF
+                    # entries parked for want of a target.
+                    eng.mrf.release_parked()
                     self._heal_after_reinstate(i)
             else:
                 DRIVEMON.probation_fail(ep)
@@ -887,13 +943,42 @@ class MRFQueue:
         # internal mutex is not reachable for the membership check).
         self._qmu = threading.Lock()
         self._queued: set[tuple[str, str]] = set()
+        # Disk positions that missed the write, where the writer said
+        # (put_object, complete): what lets _heal see, without a read,
+        # that every target of an entry is a `faulty` drive.
+        self._targets: dict[tuple[str, str], tuple[int, ...]] = {}
+        # Entries kept and NOT chased: every target offline. They stay
+        # in the dedup set and the journal, leave the queue, and come
+        # back through release_parked when a drive is reinstated.
+        self._parked: set[tuple[str, str]] = set()
         from .mrfjournal import MRFJournal
         self.journal = MRFJournal(healer.engine.disks)
 
     def depth(self) -> int:
         return self.q.qsize()
 
-    def add(self, bucket: str, object_name: str) -> None:
+    def parked(self) -> int:
+        return len(self._parked)
+
+    def release_parked(self) -> int:
+        """Back into the queue with what was parked (a drive has been
+        reinstated). Entries the queue cannot take stay parked."""
+        n = 0
+        with self._qmu:
+            for key in sorted(self._parked):
+                try:
+                    self.q.put_nowait(key)
+                except queue.Full:
+                    break
+                self._parked.discard(key)
+                n += 1
+        if n and self._thread is None:
+            self.start()
+        return n
+
+    def add(self, bucket: str, object_name: str,
+            targets=None) -> None:
+        """targets: the disk positions whose leg the writer missed."""
         from ..obs.metrics2 import METRICS2
         key = (bucket, object_name)
         dropped = False
@@ -918,6 +1003,10 @@ class MRFQueue:
                 self.drops += 1
                 dropped = True
             else:
+                if targets:
+                    self._targets[key] = tuple(targets)
+                else:
+                    self._targets.pop(key, None)
                 # Durability: journal the accepted entry so a crash
                 # replays it (no-op when already journaled, when the
                 # set has no local disks, or past the size cap —
@@ -934,6 +1023,7 @@ class MRFQueue:
                     f"for {bucket}/{object_name} "
                     f"({self.drops} drops total)", "heal")
             return
+        METRICS2.inc("minio_tpu_v2_mrf_entries_total")
         METRICS2.set_gauge("minio_tpu_v2_mrf_queue_depth", None,
                            self.q.qsize())
         # Background worker starts lazily on first failure so every
@@ -981,6 +1071,22 @@ class MRFQueue:
             if item is not None:
                 self._heal(item)
 
+    def _away(self, i: int) -> bool:
+        """Drive `i` can take no heal's write now: the monitor holds it
+        `faulty`, or (not counted out yet) it does not answer: one
+        stat, which the monitor counts toward its verdict."""
+        from ..obs.drivemon import DRIVEMON
+        eng = self.healer.engine
+        if DRIVEMON.is_quarantined(eng.endpoints[i]):
+            return True
+        try:
+            eng.disks[i].disk_info()
+        except serr.DiskNotFound:
+            return True
+        except Exception:
+            return False
+        return False
+
     # Contended items retry this many times with a SHORT lock wait so a
     # handful of hot keys can't head-of-line-block the whole queue.
     MAX_TRIES = 8
@@ -990,11 +1096,19 @@ class MRFQueue:
         from ..qos.scheduler import GATE, background_lane
         bucket, object_name, tries = (item if len(item) == 3
                                       else (*item, 0))
+        key = (bucket, object_name)
         requeued = False
         converged = False
+        parked = False
         try:
+            targets = self._targets.get(key)
+            if targets and all(self._away(i) for i in targets):
+                # The debt is kept and not chased: no lock, no read.
+                parked = True
+                return
             with background_lane():
                 GATE.throttle_background()  # MRF drains behind traffic
+            count_heal_attempt("mrf", "started")
             res = self.healer.heal_object(bucket, object_name,
                                           lock_timeout=self.LOCK_WAIT_S)
             # Converged: every bad disk healed (or nothing was bad, or
@@ -1003,8 +1117,12 @@ class MRFQueue:
             # failed heal keeps its durability debt on disk for the
             # next boot/retry.
             bad = set(res.corrupt_disks) | set(res.missing_disks)
-            converged = (res.dangling
-                         or bad <= set(res.healed_disks))
+            left = bad - set(res.healed_disks)
+            converged = res.dangling or not left
+            # What is left is all on `faulty` drives: parked, as above
+            # (an entry without targets: a replayed journal's, a GET's).
+            parked = (not converged
+                      and left <= set(res.offline_disks))
         except TimeoutError:
             # Still contended: requeue to the BACK with a retry cap —
             # the sweep loops that enqueued this expect an eventual
@@ -1018,14 +1136,19 @@ class MRFQueue:
         except Exception:
             pass  # background best-effort
         finally:
-            if not requeued:
+            if parked:
+                count_heal_attempt("mrf", "abandoned_offline")
+                with self._qmu:
+                    self._parked.add(key)
+            elif not requeued:
                 # Retire under the same lock add() inserts under (see
                 # add): the key leaves the dedup set either way — a
                 # FAILED heal must be re-addable by the next degraded
                 # write or sweep — and a CONVERGED heal retires its
                 # journal entry atomically with it.
                 with self._qmu:
-                    self._queued.discard((bucket, object_name))
+                    self._queued.discard(key)
+                    self._targets.pop(key, None)
                     if converged:
                         self.journal.complete(bucket, object_name)
 

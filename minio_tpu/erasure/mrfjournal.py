@@ -178,6 +178,14 @@ class MRFJournal:
             self._compact()  # truncate: healthy sets carry no journal
         self._publish()
 
+    def _writable(self) -> list:
+        """The local disks a journal write goes to: not a drive the
+        monitor holds `faulty` (the debt it caused is not written to
+        it; replay unions the other disks' files)."""
+        from ..obs.drivemon import DRIVEMON, drive_key
+        return [d for d in self.disks
+                if not DRIVEMON.is_quarantined(drive_key(d))]
+
     def _flush(self) -> None:
         """Append everything pending in one write per disk. The writer
         lock serializes file access; bookkeeping stays on _mu so
@@ -189,7 +197,7 @@ class MRFJournal:
             if not batch:
                 return
             blob = b"".join(_line(*k) for k in batch)
-            for disk in self.disks:
+            for disk in self._writable():
                 try:
                     disk.append_file(MINIO_META_BUCKET, MRF_LOG_PATH,
                                      blob)
@@ -214,7 +222,7 @@ class MRFJournal:
                 self._pending_bytes = sum(len(_line(*k))
                                           for k in self._pending)
             blob = b"".join(_line(*k) for k in snapshot)
-            for disk in self.disks:
+            for disk in self._writable():
                 try:
                     if blob:
                         disk.write_all(MINIO_META_BUCKET, MRF_LOG_PATH,

@@ -99,10 +99,9 @@ class MultipartUploads:
             "distribution": hash_order(f"{bucket}/{object_name}", n),
             "parity": m,
         }).encode()
-        _, errs = parallel_map(
-            [lambda d=d: d.write_all(MINIO_META_BUCKET,
-                                     f"{base}/upload.json", record)
-             for d in eng.disks])
+        _, errs = eng.each_disk(
+            "write_all", lambda d: d.write_all(
+                MINIO_META_BUCKET, f"{base}/upload.json", record))
         reduce_quorum_errs(errs, write_quorum(n - m, m),
                            "new_multipart_upload")
         return upload_id
@@ -131,7 +130,7 @@ class MultipartUploads:
                 raw = first_success(
                     [lambda d=d: d.read_all(MINIO_META_BUCKET,
                                             f"{base}/upload.json")
-                     for d in self.engine.disks],
+                     for d in self.engine.live_disks("read_all")],
                     swallow=serr.StorageError)
             except QuorumError:
                 raise UploadNotFound(upload_id) from None
@@ -254,7 +253,7 @@ class MultipartUploads:
         base = _upload_base(bucket, object_name, upload_id)
         parts: dict[int, dict] = {}
         with TRACER.span("mpu.list"):
-            for disk in self.engine.disks:
+            for disk in self.engine.live_disks("list_dir"):
                 try:
                     entries = disk.list_dir(MINIO_META_BUCKET, base)
                 except serr.StorageError:
@@ -275,7 +274,7 @@ class MultipartUploads:
         eng = self.engine
         out = []
         seen = set()
-        for disk in eng.disks:
+        for disk in eng.live_disks("list_dir"):
             try:
                 hashes = disk.list_dir(MINIO_META_BUCKET, MPU_PATH)
             except serr.StorageError:
@@ -412,7 +411,16 @@ class MultipartUploads:
                     MINIO_META_BUCKET,
                     f"{tmp_path}/{data_dir}/part.{p.number}", shard)
 
+        # A `faulty` drive's leg is not attempted (put_object's rule:
+        # only while the others keep write quorum); the MRF entry below
+        # names it.
+        alive = [True] * len(eng.disks)
+        skipped: list = [None] * len(eng.disks)
+        eng._quarantine_skip(alive, skipped, wq)
+
         def commit_one(i: int):
+            if not alive[i]:
+                raise skipped[i]
             disk = eng.disks[i]
             tmp_path = f"{TMP_PATH}/{uuid.uuid4()}"
             try:
@@ -475,8 +483,9 @@ class MultipartUploads:
         # serve the completed object; the leftover upload stays
         # abortable/listable (ref stale-upload cleanup).
         FAULTS.crash_point(CRASH_MPU_POST)
-        if any(e is not None for e in errs):
-            eng.mrf.add(bucket, object_name)
+        dead = [i for i, e in enumerate(errs) if e is not None]
+        if dead:
+            eng.mrf.add(bucket, object_name, dead)
         self._cleanup(bucket, object_name, upload_id)
         eng._mark_update(bucket, object_name)
         # Multipart complete is an overwrite of the key: invalidate
@@ -497,6 +506,6 @@ class MultipartUploads:
     def _cleanup(self, bucket: str, object_name: str,
                  upload_id: str) -> None:
         base = _upload_base(bucket, object_name, upload_id)
-        parallel_map(
-            [lambda d=d: d.delete(MINIO_META_BUCKET, base, recursive=True)
-             for d in self.engine.disks])
+        self.engine.each_disk(
+            "delete",
+            lambda d: d.delete(MINIO_META_BUCKET, base, recursive=True))

@@ -75,34 +75,54 @@ class ConfigStore:
         (config docs are optional — most never exist), so only
         transient failures on every healthy disk justify probing a
         possibly-stalling quarantined drive (availability over
-        hygiene). Returns the first successful read, or None."""
-        from ..obs.drivemon import DRIVEMON, drive_key
-        healthy: list = []
-        quarantined: list = []
-        for d in self.disks:
-            (quarantined if DRIVEMON.is_quarantined(drive_key(d))
-             else healthy).append(d)
+        hygiene). Returns the first successful read, or None.
+
+        A drive that fails is ONE drive's transient failure, whatever
+        it raises (a typed storage error, or an OSError a backend let
+        through): the read goes on to the next."""
+        healthy, quarantined = self._by_health()
         definitive_miss = False
         for d in healthy:
             try:
                 return read(d)
             except (serr.FileNotFound, serr.VolumeNotFound):
                 definitive_miss = True
-            except serr.StorageError:
+            except (serr.StorageError, OSError):
                 continue
         if not definitive_miss:
             for d in quarantined:
                 try:
                     return read(d)
-                except serr.StorageError:
+                except (serr.StorageError, OSError):
                     continue
         return None
+
+    def _by_health(self) -> tuple[list, list]:
+        """(drives the monitor lets through, `faulty` ones)."""
+        from ..obs.drivemon import DRIVEMON, drive_key
+        healthy: list = []
+        quarantined: list = []
+        for d in self.disks:
+            (quarantined if DRIVEMON.is_quarantined(drive_key(d))
+             else healthy).append(d)
+        return healthy, quarantined
+
+    def _write_disks(self, op: str) -> list:
+        """Where a write or delete goes: every drive but the `faulty`
+        ones, which are not asked while the others keep a majority."""
+        healthy, quarantined = self._by_health()
+        if not quarantined or len(healthy) < len(self.disks) // 2 + 1:
+            return self.disks
+        from ..obs.metrics2 import METRICS2
+        METRICS2.inc("minio_tpu_v2_drive_legs_skipped_total", {"op": op},
+                     len(quarantined))
+        return healthy
 
     def save(self, path: str, doc: dict) -> None:
         raw = json.dumps(doc, sort_keys=True).encode()
         _, errs = parallel_map(
             [lambda d=d: d.write_all(MINIO_META_BUCKET, path, raw)
-             for d in self.disks])
+             for d in self._write_disks("write_all")])
         ok = sum(1 for e in errs if e is None)
         if ok < len(self.disks) // 2 + 1:
             raise serr.FaultyDisk(f"config write quorum failed: {path}")
@@ -113,7 +133,7 @@ class ConfigStore:
 
     def delete(self, path: str) -> None:
         parallel_map([lambda d=d: d.delete(MINIO_META_BUCKET, path)
-                      for d in self.disks])
+                      for d in self._write_disks("delete")])
 
     def list(self, prefix: str) -> list[str]:
         out = self._first_success(

@@ -23,7 +23,7 @@ import threading
 import time
 import uuid
 
-from ..parallel.quorum import parallel_map, read_quorum
+from ..parallel.quorum import read_quorum
 from ..storage.metadata import FileInfo
 from ..utils.compress import compress_stream, decompress_stream
 from .merge import merge_resolve
@@ -76,8 +76,8 @@ class MetacacheManager:
 
     def _scan(self, bucket: str, root: str) -> list[dict]:
         eng = self.engine
-        results, _errs = parallel_map(
-            [lambda d=d: d.walk_dir(bucket, root) for d in eng.disks])
+        results, _errs = eng.each_disk(
+            "walk_dir", lambda d: d.walk_dir(bucket, root))
         self.scans += 1
         return merge_resolve(list(results), read_quorum(eng.k))
 

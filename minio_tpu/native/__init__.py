@@ -316,6 +316,7 @@ def lzb_decompress_native(blob: bytes, out_size: int) -> bytes | None:
 FS_SRC_VOLUME_NOT_FOUND = -1
 FS_DST_VOLUME_NOT_FOUND = -2
 FS_STAGE_NOT_FOUND = -3
+FS_DRIVE_NOT_FOUND = -4   # the root is no directory: the drive is gone
 # fs_commit_stage's answer for an xl.meta larger than the buffer it
 # was handed: the caller reads the file itself.
 FS_META_TOO_BIG = object()

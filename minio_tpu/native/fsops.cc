@@ -38,6 +38,9 @@ namespace {
 constexpr int kSrcVolumeNotFound = -1;
 constexpr int kDstVolumeNotFound = -2;
 constexpr int kStageNotFound = -3;
+// The drive's ROOT is not a directory (replaced by a file, gone): not
+// "the volume is missing", which feeds the bucket-not-found quorum.
+constexpr int kDriveNotFound = -4;
 
 // The system calls the extern function running on this thread has made.
 thread_local long t_calls = 0;
@@ -72,13 +75,24 @@ int mkdir_below(const std::string& path, size_t floor) {
   return (e == EEXIST && is_dir(path.c_str())) ? 0 : e;
 }
 
+std::string parent_of(const char* path) {
+  std::string p(path);
+  size_t cut = p.find_last_of('/');
+  return cut == std::string::npos ? std::string(".") : p.substr(0, cut);
+}
+
 // XLStorage._check_vol: a volume is a directory; the system volume
 // (sys_tmp != NULL: its <root>/.minio.sys/tmp) self-creates, so that a
-// freshly swapped drive accepts writes at once.
+// freshly swapped drive accepts writes at once. A volume stands
+// directly under the drive's root: where it is no directory the root
+// is asked before the volume is blamed (failure path only), and the
+// system volume is made BELOW a root that stands, never the root.
 int check_vol(const char* vol, const char* sys_tmp, int missing) {
   if (is_dir(vol)) return 0;
+  const std::string root = parent_of(vol);
+  if (!is_dir(root.c_str())) return kDriveNotFound;
   if (sys_tmp == nullptr) return missing;
-  return mkdir_below(sys_tmp, 0);
+  return mkdir_below(sys_tmp, root.size());
 }
 
 // XLStorage._makedirs_for: the volume re-checked immediately before
@@ -119,12 +133,6 @@ int make_dir(const char* vol, const char* sys_tmp, int missing,
 
 long ns_between(const struct timespec& t0, const struct timespec& t1) {
   return (t1.tv_sec - t0.tv_sec) * 1000000000L + (t1.tv_nsec - t0.tv_nsec);
-}
-
-std::string parent_of(const char* path) {
-  std::string p(path);
-  size_t cut = p.find_last_of('/');
-  return cut == std::string::npos ? std::string(".") : p.substr(0, cut);
 }
 
 int write_all(int fd, const char* data, size_t len) {
@@ -320,8 +328,12 @@ int fs_commit_meta(const char* tmp, const char* xl_meta, const char* data,
   const int flags = O_WRONLY | O_CREAT | O_TRUNC | O_CLOEXEC;
   int fd = SYS(open(tmp, flags, 0666));
   if (fd < 0 && errno == ENOENT) {
-    // tmp dir wiped under us (drive swap mid-flight): it self-creates.
-    int r = mkdir_below(parent_of(tmp), 0);
+    // tmp dir wiped under us (drive swap mid-flight): it self-creates,
+    // below the root (<root>/.minio.sys/tmp/<uuid>), which does not:
+    // ENOENT then says the root is gone.
+    const std::string sys_tmp = parent_of(tmp);
+    const size_t root = parent_of(parent_of(sys_tmp.c_str()).c_str()).size();
+    int r = mkdir_below(sys_tmp, root);
     if (r != 0) return r;
     fd = SYS(open(tmp, flags, 0666));
   }

@@ -248,14 +248,22 @@ class DriveMonitor:
                 d.errs_total += 1
             if d.win_ops >= self.WINDOW_OPS:
                 transition = self._close_window(d)
-        if error:
+        if error or d.quarantined:
             from .metrics2 import METRICS2
-            # Metric labels use the redacted identity: the metrics
-            # pages are unauthenticated, and absolute disk paths must
-            # not leak there (admin /drive-health maps them back).
-            METRICS2.inc("minio_tpu_v2_drive_op_errors_total",
-                         {"disk": redacted_endpoint(endpoint),
-                          "op_class": cls})
+            if error:
+                # Metric labels use the redacted identity: the metrics
+                # pages are unauthenticated, and absolute disk paths
+                # must not leak there (admin /drive-health maps them
+                # back).
+                METRICS2.inc("minio_tpu_v2_drive_op_errors_total",
+                             {"disk": redacted_endpoint(endpoint),
+                              "op_class": cls})
+            if d.quarantined:
+                # A call ISSUED to a drive the data plane has already
+                # given up on: probation probes and whatever fan-out
+                # still does not leave it out (skip_faulty below).
+                METRICS2.inc("minio_tpu_v2_drive_faulty_calls_total",
+                             {"op": op})
         if transition is not None:
             self._announce(*transition)
 
@@ -354,6 +362,21 @@ class DriveMonitor:
         read/write selection paths call this per drive per request."""
         d = self._drives.get(endpoint)
         return d is not None and d.quarantined
+
+    def skip_faulty(self, endpoints: list[str], op: str) -> list[int]:
+        """Positions of `endpoints` a fan-out goes to: a `faulty`
+        (quarantined) drive is left out WITHOUT the call being issued,
+        and each leg so skipped is counted. For fan-outs that need no
+        answer from every drive (stats, listings, config, deletes);
+        the write and read paths keep their own rule, which lets a
+        quarantined drive back in when quorum is at stake."""
+        live = [i for i, ep in enumerate(endpoints)
+                if not self.is_quarantined(ep)]
+        if len(live) < len(endpoints):
+            from .metrics2 import METRICS2
+            METRICS2.inc("minio_tpu_v2_drive_legs_skipped_total",
+                         {"op": op}, len(endpoints) - len(live))
+        return live
 
     def quarantined_endpoints(self) -> list[str]:
         with self._mu:

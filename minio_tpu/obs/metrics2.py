@@ -544,6 +544,27 @@ METRICS2.register(
     "Drive op errors (real disk faults, not namespace misses), "
     "by disk endpoint and op class.")
 METRICS2.register(
+    "minio_tpu_v2_drive_faulty_calls_total", "counter",
+    "Storage calls ISSUED to a drive the monitor already holds faulty "
+    "(quarantined), by op: probation probes, and any fan-out that "
+    "does not leave the drive out.")
+METRICS2.register(
+    "minio_tpu_v2_drive_legs_skipped_total", "counter",
+    "Legs of a fan-out NOT issued because their drive is faulty "
+    "(quarantined), by op (write = a PUT's, part's or complete's "
+    "whole leg).")
+METRICS2.register(
+    "minio_tpu_v2_mrf_entries_total", "counter",
+    "Entries accepted into the most-recently-failed heal queue (a "
+    "key already queued or parked is not counted again).")
+METRICS2.register(
+    "minio_tpu_v2_heal_attempts_total", "counter",
+    "Background heal attempts, by who (mrf = the MRF healer took an "
+    "entry, newdisk = the new-disk monitor's sweep of a drive) and "
+    "outcome (started = survivors may be read from here on, "
+    "abandoned_offline = every target is a faulty drive: nothing "
+    "read, the debt kept).")
+METRICS2.register(
     "minio_tpu_v2_drive_quarantines_total", "counter",
     "Drives auto-quarantined by the health monitor, by disk endpoint.")
 METRICS2.register(
@@ -569,7 +590,8 @@ METRICS2.register(
     "Repair traffic moved by object heals, by mode (rs = conventional "
     "k-survivor decode, regen = minimum-bandwidth REGEN repair) and "
     "src (disk = bytes helpers read from media, net = bytes shipped "
-    "in helper responses) — the observable form of the regenerating "
+    "in helper responses, verify = part-file bytes a classification's "
+    "deep scan read) — the observable form of the regenerating "
     "code's repair-bandwidth claim.")
 METRICS2.register(
     "minio_tpu_v2_fault_injections_total", "counter",

@@ -51,22 +51,80 @@ CRASH_RENAME_MID = FAULTS.register_crash_point(
 CRASH_RENAME_POST = FAULTS.register_crash_point(
     "xl.rename_data.post_meta")
 from ..obs.metrics2 import METRICS2
-from ..obs.span import TRACER
+from ..obs.span import TRACER, current_span
 
 
-class _DiskOp:
+class _Seam:
+    """What a storage call does when it FAILS, and nothing when it
+    does not: no error of the operating system leaves XLStorage as it
+    came. An OSError that nothing inside the call resolved is typed
+    (`XLStorage._os_fault`): DiskNotFound where the drive's ROOT is
+    not a directory that answers (replaced by a file, gone, EIO), the
+    drive's own fault otherwise. A local DiskNotFound is evidence
+    against the DRIVE (for a remote drive it is the transport's, which
+    is why `is_drive_fault` leaves it out): the monitor hears of it as
+    an error, the request's tree gets a `drive.offline` event.
+
+    The calls the data plane times are `_DiskOp`s below; the others
+    (volumes, listings, renames of flat files) stand in this class
+    itself, which costs a healthy call one `with`."""
+
+    __slots__ = ("op", "_disk")
+
+    def __init__(self, op: str, disk: "XLStorage"):
+        self.op = op
+        self._disk = disk
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, et, exc, tb):
+        if et is not None:
+            typed, fault = self._settle(et, exc)
+            if fault:
+                DRIVEMON.record(self._disk.root, self.op, 0.0, error=True)
+            if typed is not exc:
+                raise typed from exc
+        return False
+
+    def _settle(self, et, exc) -> tuple[BaseException, bool]:
+        """(the exception as it leaves the seam, whether the drive
+        monitor counts the call against the drive)."""
+        raw = issubclass(et, OSError)
+        typed = self._disk._os_fault(exc) if raw else exc
+        if isinstance(typed, serr.DiskNotFound):
+            _note_offline(self._disk.root, self.op, typed)
+            return typed, True
+        # With the root standing the monitor judges what it judged
+        # before the seam typed it: the errno itself (the ENOENT family
+        # is a namespace miss), or the errno a typed error was raised
+        # `from`.
+        return typed, is_drive_fault(
+            exc if raw else typed.__cause__ or typed)
+
+
+def _note_offline(root: str, op: str, err: serr.DiskNotFound) -> None:
+    """A leg dropped because its drive is not there: the event on the
+    request's tree (no-op when untraced)."""
+    span = current_span()
+    if span is not None:
+        span.add_event("drive.offline", drive=root, op=op,
+                       errno=getattr(err, "errno", 0))
+
+
+class _DiskOp(_Seam):
     """Per-disk-call instrumentation: a child span on the active trace
     (no-op when untraced), the metrics-v2 disk-op histogram, AND the
     drive-health monitor's per-drive latency/error accounting — the
     per-disk attribution layer of the request trace (the reference's
     storage layer exports xl_storage api latencies the same way in
-    cmd/metrics-v2.go; per-drive health in pkg/smart / admin obd)."""
+    cmd/metrics-v2.go; per-drive health in pkg/smart / admin obd).
+    A failed call leaves it typed, as `_Seam` says."""
 
-    __slots__ = ("op", "_cm", "_t0", "_disk")
+    __slots__ = ("_cm", "_t0")
 
     def __init__(self, op: str, disk: "XLStorage"):
-        self.op = op
-        self._disk = disk
+        super().__init__(op, disk)
         self._cm = TRACER.span("disk." + op, disk=disk.root)
 
     def __enter__(self):
@@ -85,11 +143,13 @@ class _DiskOp:
             raise
         return self
 
-    def __exit__(self, *exc):
-        self._cm.__exit__(*exc)
+    def __exit__(self, et, exc, tb):
+        typed, fault = (exc, False) if et is None else self._settle(et, exc)
+        self._cm.__exit__(et, exc, tb)
         _account(self._disk.root, self.op,
-                 (time.perf_counter() - self._t0) * 1e3,
-                 error=bool(exc) and is_drive_fault(exc[0]))
+                 (time.perf_counter() - self._t0) * 1e3, error=fault)
+        if typed is not exc:
+            raise typed from exc
         return False
 
 
@@ -262,20 +322,62 @@ class XLStorage(StorageAPI):
     def _check_vol(self, volume: str) -> str:
         p = self._vol_path(volume)
         if not os.path.isdir(p):
+            # "The bucket is not there" and "the drive is not there" are
+            # different statements (the first feeds the bucket-not-found
+            # quorum): the root is asked before the volume is blamed.
+            gone = self._offline()
+            if gone is not None:
+                raise gone
             if volume == MINIO_META_BUCKET:
                 # The system volume self-creates (a freshly swapped disk
                 # must accept heal writes immediately).
-                os.makedirs(os.path.join(self.root, TMP_DIR),
-                            exist_ok=True)
+                self._make_sys_tmp()
                 return p
             raise serr.VolumeNotFound(volume)
         return p
+
+    def _make_sys_tmp(self) -> None:
+        """<root>/.minio.sys/tmp, made BELOW a root that stands: a root
+        that is gone is a drive that is gone and is not made here (the
+        mkdir fails ENOENT, which the seam types)."""
+        for d in (os.path.dirname(self._sys_tmp), self._sys_tmp):
+            try:
+                os.mkdir(d)
+            except FileExistsError:
+                pass
+
+    # --- a dead drive (failure paths only) ---
+
+    def _offline(self) -> serr.DiskNotFound | None:
+        """DiskNotFound where this drive's root is not a directory that
+        answers: replaced by a regular file (ENOTDIR), gone (ENOENT),
+        failing (EIO). One stat, made only after a call has FAILED in a
+        way that leaves it open: a healthy leg never asks."""
+        try:
+            if stat.S_ISDIR(os.stat(self.root).st_mode):
+                return None
+            code = errno.ENOTDIR
+        except OSError as e:
+            code = e.errno or errno.EIO
+        gone = serr.DiskNotFound(f"{self.root}: {os.strerror(code)}")
+        gone.errno = code
+        return gone
+
+    def _os_fault(self, e: OSError) -> serr.StorageError:
+        """The typed error for an OSError that no caller resolved into
+        a namespace condition: the drive gone, the drive full, or the
+        drive's fault."""
+        if e.errno == errno.ENOSPC:
+            return serr.DiskFull(str(e))
+        return self._offline() or serr.FaultyDisk(str(e))
 
     # --- identity / health ---
 
     def disk_info(self) -> dict:
         with _DiskOp("disk_info", self):
-            st = os.statvfs(self.root)
+            # Of the system volume, not of the root: a root replaced by
+            # a regular file answers statvfs itself.
+            st = os.statvfs(os.path.dirname(self._sys_tmp))
         return {
             "total": st.f_blocks * st.f_frsize,
             "free": st.f_bavail * st.f_frsize,
@@ -292,23 +394,22 @@ class XLStorage(StorageAPI):
     def make_volume(self, volume: str) -> None:
         if not _is_valid_volume(volume):
             raise serr.VolumeNotFound(volume)
-        p = os.path.join(self.root, volume)
-        if os.path.isdir(p):
-            raise serr.VolumeExists(volume)
-        try:
-            os.makedirs(p)
-        except FileExistsError:
-            # TOCTOU with a concurrent make_volume: same outcome as the
-            # isdir check above.
-            raise serr.VolumeExists(volume) from None
+        # mkdir, not makedirs: a volume stands directly under the root,
+        # and a root that is gone is not made here (ENOENT -> the seam).
+        with _Seam("make_volume", self):
+            try:
+                os.mkdir(os.path.join(self.root, volume))
+            except FileExistsError:
+                raise serr.VolumeExists(volume) from None
 
     def list_volumes(self) -> list[str]:
         out = []
-        for name in sorted(os.listdir(self.root)):
-            if name in _RESERVED_VOLUMES or name.startswith("."):
-                continue
-            if os.path.isdir(os.path.join(self.root, name)):
-                out.append(name)
+        with _Seam("list_volumes", self):
+            for name in sorted(os.listdir(self.root)):
+                if name in _RESERVED_VOLUMES or name.startswith("."):
+                    continue
+                if os.path.isdir(os.path.join(self.root, name)):
+                    out.append(name)
         return out
 
     def stat_volume(self, volume: str) -> dict:
@@ -328,16 +429,17 @@ class XLStorage(StorageAPI):
     def delete_volume(self, volume: str, force: bool = False) -> None:
         if volume in _RESERVED_VOLUMES:
             raise serr.VolumeNotFound(f"{volume} is reserved")
-        p = self._check_vol(volume)
-        try:
-            if force:
-                shutil.rmtree(p)
-            else:
-                os.rmdir(p)
-        except OSError as e:
-            if e.errno == errno.ENOTEMPTY:
-                raise serr.VolumeExists(f"{volume} not empty")
-            raise serr.FaultyDisk(str(e))
+        with _Seam("delete_volume", self):
+            p = self._check_vol(volume)
+            try:
+                if force:
+                    shutil.rmtree(p)
+                else:
+                    os.rmdir(p)
+            except OSError as e:
+                if e.errno == errno.ENOTEMPTY:
+                    raise serr.VolumeExists(f"{volume} not empty")
+                raise
 
     # --- flat files ---
 
@@ -385,7 +487,7 @@ class XLStorage(StorageAPI):
             except FileNotFoundError:
                 # tmp dir wiped under us (disk swap mid-flight): the
                 # system volume self-creates, then retry once.
-                os.makedirs(os.path.dirname(tmp), exist_ok=True)
+                self._make_sys_tmp()
                 f = open(tmp, "wb")
             with f:
                 f.write(data)
@@ -412,9 +514,7 @@ class XLStorage(StorageAPI):
         except serr.StorageError:
             raise
         except OSError as e:
-            if e.errno == errno.ENOSPC:
-                raise serr.DiskFull(str(e))
-            raise serr.FaultyDisk(str(e))
+            raise self._os_fault(e)
 
     def write_all(self, volume: str, path: str, data: bytes) -> None:
         # Volume check happens in _makedirs_for, adjacent to the mkdir.
@@ -430,38 +530,38 @@ class XLStorage(StorageAPI):
         and check on failure: no `stat` of the volume goes before the
         `open` (on a network mount: a round trip a read, ten to twelve
         a GET), and a path that does not resolve is told apart HERE
-        into the volume gone (VolumeNotFound, from which the engine
-        takes BucketNotFound) and the file gone."""
+        into the drive gone (DiskNotFound), the volume gone
+        (VolumeNotFound, from which the engine takes BucketNotFound)
+        and the file gone."""
         self._check_vol(volume)
         if isinstance(e, NotADirectoryError):
-            raise serr.FaultyDisk(str(e))
+            # A path THROUGH a file, the volume (and so the root)
+            # standing: a fault of the name, which `from e` tells
+            # _Seam._settle not to count against the drive.
+            raise serr.FaultyDisk(str(e)) from e
         raise serr.FileNotFound(f"{volume}/{path}")
 
     def read_all(self, volume: str, path: str) -> bytes:
         full = self._file_path(volume, path)
-        try:
-            with _DiskOp("read_all", self):
+        with _DiskOp("read_all", self):
+            try:
                 return FAULTS.filter_read(self.root, "read_all",
                                           _read_whole(full))
-        except (FileNotFoundError, NotADirectoryError) as e:
-            self._raise_read_miss(volume, path, e)
-        except IsADirectoryError:
-            raise serr.FileNotFound(f"{volume}/{path}")
-        except OSError as e:
-            raise serr.FaultyDisk(str(e))
+            except (FileNotFoundError, NotADirectoryError) as e:
+                self._raise_read_miss(volume, path, e)
+            except IsADirectoryError:
+                raise serr.FileNotFound(f"{volume}/{path}")
 
     def read_file(self, volume: str, path: str, offset: int,
                   length: int) -> bytes:
         full = self._file_path(volume, path)
-        try:
-            with _DiskOp("read_file", self):
+        with _DiskOp("read_file", self):
+            try:
                 return FAULTS.filter_read(
                     self.root, "read_file",
                     _read_range(full, offset, length))
-        except (FileNotFoundError, NotADirectoryError) as e:
-            self._raise_read_miss(volume, path, e)
-        except OSError as e:
-            raise serr.FaultyDisk(str(e))
+            except (FileNotFoundError, NotADirectoryError) as e:
+                self._raise_read_miss(volume, path, e)
 
     def create_file(self, volume: str, path: str, data) -> None:
         """bytes -> atomic write; iterable of chunks -> incremental
@@ -479,15 +579,11 @@ class XLStorage(StorageAPI):
                                         bytes(data)),
                     volume=volume)
             return
-        self._makedirs_for(volume, os.path.dirname(full))
-        try:
+        with _Seam("create_file", self):
+            self._makedirs_for(volume, os.path.dirname(full))
             with open(full, "wb") as f:
                 for chunk in data:
                     f.write(chunk)
-        except OSError as e:
-            if e.errno == errno.ENOSPC:
-                raise serr.DiskFull(str(e))
-            raise serr.FaultyDisk(str(e))
 
     def _native_vol(self, volume: str) -> tuple[bytes, bytes | None]:
         """A volume as native/fsops.cc takes it: its path, and for the
@@ -508,46 +604,43 @@ class XLStorage(StorageAPI):
             raise serr.VolumeNotFound(src_volume)
         if rc == native.FS_STAGE_NOT_FOUND:
             raise serr.FileNotFound(src)
+        if rc == native.FS_DRIVE_NOT_FOUND:
+            rc = errno.ENOENT      # the seam asks the root, as for any
         raise OSError(rc, os.strerror(rc), src)
 
     def append_file(self, volume: str, path: str, data: bytes) -> None:
         full = self._file_path(volume, path)
         data = FAULTS.filter_write(self.root, "append_file", data)
         lib = _fs_lane("append_file")
-        try:
-            with _DiskOp("append_file", self):
-                if lib is not None:
-                    rc, calls = native.fs_append(
-                        lib, os.fsencode(full), *self._native_vol(volume),
-                        data)
-                    _count_syscalls("append_file", calls)
-                    if rc != 0:
-                        self._raise_native(rc, f"{volume}/{path}", volume)
-                    return
-                try:
-                    f = open(full, "ab")
-                except FileNotFoundError:
-                    # First append of a staged stream: create the
-                    # directory (volume-guarded) and retry. Later
-                    # appends of the same stream skip the stat/mkdir
-                    # pair — on the pipelined PUT path that's one
-                    # fewer round of metadata syscalls per disk per
-                    # batch.
-                    self._makedirs_for(volume, os.path.dirname(full))
-                    f = open(full, "ab")
-                with f:
-                    f.write(data)
-        except OSError as e:
-            if e.errno == errno.ENOSPC:
-                raise serr.DiskFull(str(e))
-            raise serr.FaultyDisk(str(e))
+        with _DiskOp("append_file", self):
+            if lib is not None:
+                rc, calls = native.fs_append(
+                    lib, os.fsencode(full), *self._native_vol(volume),
+                    data)
+                _count_syscalls("append_file", calls)
+                if rc != 0:
+                    self._raise_native(rc, f"{volume}/{path}", volume)
+                return
+            try:
+                f = open(full, "ab")
+            except FileNotFoundError:
+                # First append of a staged stream: create the
+                # directory (volume-guarded) and retry. Later
+                # appends of the same stream skip the stat/mkdir
+                # pair — on the pipelined PUT path that's one
+                # fewer round of metadata syscalls per disk per
+                # batch.
+                self._makedirs_for(volume, os.path.dirname(full))
+                f = open(full, "ab")
+            with f:
+                f.write(data)
 
     def delete(self, volume: str, path: str, recursive: bool = False,
                ) -> None:
-        self._check_vol(volume)
-        full = self._file_path(volume, path)
-        try:
-            with _DiskOp("delete", self):
+        with _DiskOp("delete", self):
+            self._check_vol(volume)
+            full = self._file_path(volume, path)
+            try:
                 if os.path.isdir(full):
                     if recursive:
                         shutil.rmtree(full)
@@ -555,10 +648,8 @@ class XLStorage(StorageAPI):
                         os.rmdir(full)
                 else:
                     os.remove(full)
-        except FileNotFoundError:
-            raise serr.FileNotFound(f"{volume}/{path}")
-        except OSError as e:
-            raise serr.FaultyDisk(str(e))
+            except FileNotFoundError:
+                raise serr.FileNotFound(f"{volume}/{path}")
         # Prune now-empty parent dirs up to the volume root (the reference
         # deletes parent prefixes as they empty).
         parent = os.path.dirname(full)
@@ -580,58 +671,53 @@ class XLStorage(StorageAPI):
         only after commit). Storage backends without link support
         (remote RPC disks) simply don't expose this method; callers
         fall back to read+write copy."""
-        self._check_vol(src_volume)
-        src = self._file_path(src_volume, src_path)
-        dst = self._file_path(dst_volume, dst_path)
-        self._makedirs_for(dst_volume, os.path.dirname(dst))
+        with _Seam("link_file", self):
+            self._check_vol(src_volume)
+            src = self._file_path(src_volume, src_path)
+            dst = self._file_path(dst_volume, dst_path)
+            self._makedirs_for(dst_volume, os.path.dirname(dst))
         tmp = os.path.join(self.root, TMP_DIR, str(uuid.uuid4()))
-        try:
-            with _DiskOp("link_file", self):
+        with _DiskOp("link_file", self):
+            try:
                 # link to a tmp name then replace: os.link alone fails
                 # EEXIST on a dst left by a retried complete.
                 try:
                     os.link(src, tmp)
                 except FileNotFoundError:
-                    os.makedirs(os.path.dirname(tmp), exist_ok=True)
+                    self._make_sys_tmp()
                     os.link(src, tmp)
                 commit_replace(tmp, dst)
-        except FileNotFoundError:
-            raise serr.FileNotFound(f"{src_volume}/{src_path}")
-        except OSError as e:
-            if e.errno == errno.ENOSPC:
-                raise serr.DiskFull(str(e))
-            raise serr.FaultyDisk(str(e))
+            except FileNotFoundError:
+                # The volumes stood a moment ago: it is the file.
+                raise serr.FileNotFound(f"{src_volume}/{src_path}")
 
     def rename_file(self, src_volume: str, src_path: str, dst_volume: str,
                     dst_path: str) -> None:
-        self._check_vol(src_volume)
-        self._check_vol(dst_volume)
-        src = self._file_path(src_volume, src_path)
-        dst = self._file_path(dst_volume, dst_path)
-        if not os.path.exists(src):
-            raise serr.FileNotFound(f"{src_volume}/{src_path}")
-        self._makedirs_for(dst_volume, os.path.dirname(dst))
-        try:
+        with _Seam("rename_file", self):
+            self._check_vol(src_volume)
+            self._check_vol(dst_volume)
+            src = self._file_path(src_volume, src_path)
+            dst = self._file_path(dst_volume, dst_path)
+            if not os.path.exists(src):
+                raise serr.FileNotFound(f"{src_volume}/{src_path}")
+            self._makedirs_for(dst_volume, os.path.dirname(dst))
             commit_replace(src, dst)
-        except OSError as e:
-            raise serr.FaultyDisk(str(e))
 
     def list_dir(self, volume: str, path: str) -> list[str]:
-        self._check_vol(volume)
-        full = self._file_path(volume, path) if path else self._vol_path(
-            volume)
-        try:
-            out = []
-            for name in sorted(os.listdir(full)):
-                if os.path.isdir(os.path.join(full, name)):
-                    out.append(name + "/")
-                else:
-                    out.append(name)
-            return out
-        except FileNotFoundError:
-            raise serr.FileNotFound(f"{volume}/{path}")
-        except NotADirectoryError:
-            raise serr.FileNotFound(f"{volume}/{path}")
+        with _Seam("list_dir", self):
+            self._check_vol(volume)
+            full = self._file_path(volume, path) if path \
+                else self._vol_path(volume)
+            try:
+                out = []
+                for name in sorted(os.listdir(full)):
+                    if os.path.isdir(os.path.join(full, name)):
+                        out.append(name + "/")
+                    else:
+                        out.append(name)
+                return out
+            except (FileNotFoundError, NotADirectoryError):
+                raise serr.FileNotFound(f"{volume}/{path}")
 
     # --- object versions ---
 
@@ -657,21 +743,16 @@ class XLStorage(StorageAPI):
 
     def _rename_data(self, src_volume: str, src_path: str, fi: FileInfo,
                      dst_volume: str, dst_path: str) -> None:
+        # What neither lane resolves into a typed condition (a refused
+        # rename, EIO, a root that is gone) leaves rename_data's _DiskOp
+        # typed, as from append_file and _atomic_write.
         lib = _fs_lane("rename_data")
-        try:
-            if lib is None:
-                self._rename_data_py(src_volume, src_path, fi,
+        if lib is None:
+            self._rename_data_py(src_volume, src_path, fi,
+                                 dst_volume, dst_path)
+        else:
+            self._rename_data_native(lib, src_volume, src_path, fi,
                                      dst_volume, dst_path)
-            else:
-                self._rename_data_native(lib, src_volume, src_path, fi,
-                                         dst_volume, dst_path)
-        except OSError as e:
-            # What neither lane resolves into a typed condition (a
-            # refused rename, EIO, ...) is the drive's fault, as in
-            # append_file and _atomic_write.
-            if e.errno == errno.ENOSPC:
-                raise serr.DiskFull(str(e))
-            raise serr.FaultyDisk(str(e))
 
     def _rename_data_native(self, lib, src_volume: str, src_path: str,
                             fi: FileInfo, dst_volume: str,
@@ -867,19 +948,24 @@ class XLStorage(StorageAPI):
     def read_parts(self, volume: str, path: str, data_dir: str,
                    ) -> list[str]:
         full = self._file_path(volume, os.path.join(path, data_dir))
-        try:
-            return sorted(n for n in os.listdir(full)
-                          if n.startswith("part."))
-        except FileNotFoundError:
-            raise serr.FileNotFound(f"{volume}/{path}/{data_dir}")
+        with _Seam("read_parts", self):
+            try:
+                return sorted(n for n in os.listdir(full)
+                              if n.startswith("part."))
+            except FileNotFoundError:
+                self._check_vol(volume)
+                raise serr.FileNotFound(f"{volume}/{path}/{data_dir}")
 
-    def verify_file(self, volume: str, path: str, fi: FileInfo) -> None:
+    def verify_file(self, volume: str, path: str, fi: FileInfo) -> int:
         """Deep bitrot scan of every part shard on this disk
-        (ref cmd/xl-storage.go:2312,2380)."""
+        (ref cmd/xl-storage.go:2312,2380). Returns the bytes it read
+        (the heal's classification counts them)."""
         shard_size = fi.erasure.shard_size()
+        scanned = 0
         for part in fi.parts:
             rel = os.path.join(path, fi.data_dir, f"part.{part.number}")
             stream = self.read_all(volume, rel)
+            scanned += len(stream)
             algo = bitrot.DEFAULT_ALGORITHM
             for cs in fi.erasure.checksums:
                 if cs.get("part") == part.number:
@@ -894,3 +980,4 @@ class XLStorage(StorageAPI):
                         want = cs.get("hash", "")
                 if want and bitrot.digest(algo, stream).hex() != want:
                     raise serr.FileCorrupt(f"{path} part {part.number}")
+        return scanned

@@ -95,7 +95,10 @@ def test_rs256_verify_roundtrip():
     sig = base64.urlsafe_b64decode(s + "=" * (-len(s) % 4))
     assert rs256_verify(RSA_N, RSA_E, msg, sig)
     assert not rs256_verify(RSA_N, RSA_E, msg + b"x", sig)
-    assert not rs256_verify(RSA_N, RSA_E, msg, sig[:-1] + b"\x00")
+    # A flipped bit, so that the tampered signature always differs (a
+    # last byte forced to 0 IS the signature one time in 256).
+    assert not rs256_verify(RSA_N, RSA_E, msg,
+                            sig[:-1] + bytes([sig[-1] ^ 1]))
 
 
 def test_openid_validator_rs256(jwks_server):
