@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import contextlib
 import hashlib
-import time
 
 from ..native import hh256_chunks_native, hh256_native
 from ..ops.hh256 import MAGIC_KEY, HighwayHash256
@@ -101,28 +100,20 @@ def _device_hash_ok(algo: str, chunk_size: int, total_full_bytes: int,
     return batching.device_present()
 
 
-def _hash_rows_device(stacked, total_bytes: int, n_requests: int):
-    """One device dispatch over (B, L) uint8 rows -> (B, 32) digests or
-    None on device failure (callers fall back to the host). The batch
-    dim pads to the next power of two so jit shapes stay few; padded
-    rows' digests are discarded. HH_STATS counts the outcome either
-    way."""
-    import numpy as np
-
+def _hash_rows_device(rows, total_bytes: int, n_requests: int):
+    """One device dispatch over B equal-length rows -> (B, 32) digests
+    or None on device failure (callers fall back to the host). `rows`
+    is what `hh256_tpu.hash_rows` takes (a (B, L) array or a list of
+    buffers), handed over uncopied: its packer writes each row once
+    into the operand and pads the batch to its power-of-two bucket.
+    HH_STATS counts the outcome either way."""
     from ..obs.span import TRACER
     from ..ops import batching
-    B = stacked.shape[0]
     with TRACER.span("kernel.hh256", backend=batching.attempt_backend(),
-                     rows=B, bytes=total_bytes):
+                     rows=len(rows), bytes=total_bytes):
         try:
             from ..ops import hh256_tpu
-            t_prep = time.perf_counter()
-            cap = 1 << max(B - 1, 0).bit_length()
-            if cap != B:
-                stacked = np.concatenate(
-                    [stacked,
-                     np.zeros((cap - B, stacked.shape[1]), np.uint8)])
-            digs = hh256_tpu.hash_chunks(stacked, t_prep=t_prep)[:B]
+            digs = hh256_tpu.hash_rows(rows)
             batching.HH_STATS.add(True, total_bytes, n_requests)
             return digs
         except Exception as exc:  # noqa: BLE001 - degrade loudly, don't fail IO
@@ -181,9 +172,8 @@ def encode_stream_arrays(arrs, algo: str = DEFAULT_ALGORITHM):
     per_shard_digs = None
     total = sum(a.size for a in arrs)
     if arrs and _device_hash_ok(algo, arrs[0].shape[1], total):
-        stacked = (np.concatenate(arrs, axis=0) if len(arrs) > 1
-                   else arrs[0])
-        digs = _hash_rows_device(stacked, total, len(arrs))
+        digs = _hash_rows_device([row for a in arrs for row in a],
+                                 total, len(arrs))
         if digs is not None:
             digs = np.asarray(digs, dtype=np.uint8)
             per_shard_digs, row = [], 0
@@ -281,15 +271,13 @@ def digest_chunks_many(algo: str, streams: list[bytes], chunk_size: int,
         return _host_digest_many(algo, streams, chunk_size)
 
     import numpy as np
-    stacked = np.empty((sum(full_counts), chunk_size), dtype=np.uint8)
-    row = 0
+    rows = []
     for s, nf in zip(streams, full_counts):
         if nf:
-            stacked[row:row + nf] = np.frombuffer(
-                s, dtype=np.uint8, count=nf * chunk_size).reshape(
-                    nf, chunk_size)
-            row += nf
-    digs = _hash_rows_device(stacked, total_full, len(streams))
+            v = np.frombuffer(s, dtype=np.uint8, count=nf * chunk_size)
+            rows.extend(v[i * chunk_size:(i + 1) * chunk_size]
+                        for i in range(nf))
+    digs = _hash_rows_device(rows, total_full, len(streams))
     if digs is None:
         return _host_digest_many(algo, streams, chunk_size)
 
@@ -363,9 +351,10 @@ def verify_frames(datas: list, wants: list[bytes],
             if not isinstance(datas[i], np.ndarray) else datas[i]
             for i in idxs])
 
-    # Worth one (B, L) stack copy: enough same-length frames that a
-    # single rows dispatch beats a Python loop of per-frame calls
-    # (~2x on a degraded-GET read window's verify pass).
+    # The device lane takes the frames as they are. On the host, worth
+    # one (B, L) stack copy: enough same-length frames that a single
+    # rows dispatch beats a Python loop of per-frame calls (~2x on a
+    # degraded-GET read window's verify pass).
     HOST_ROWS_MIN_FRAMES = 5
     by_len: dict[int, list[int]] = {}
     for i, d in enumerate(datas):
@@ -374,7 +363,7 @@ def verify_frames(datas: list, wants: list[bytes],
     for length, idxs in by_len.items():
         total = length * len(idxs)
         if length and _device_hash_ok(algo, length, total):
-            digs = _hash_rows_device(stack_group(idxs), total,
+            digs = _hash_rows_device([datas[i] for i in idxs], total,
                                      len(idxs))
             if digs is not None:
                 for row, i in enumerate(idxs):
@@ -406,29 +395,33 @@ class BitrotMismatch(Exception):
     cmd/bitrot-streaming.go:30)."""
 
 
-def split_block(buf: bytes, block_idx: int, chunk: int, shard_size: int,
-                algo: str = DEFAULT_ALGORITHM) -> tuple[bytes, bytes]:
+def split_block(buf, block_idx: int, chunk: int, shard_size: int,
+                algo: str = DEFAULT_ALGORITHM) -> tuple[bytes, memoryview]:
     """(want, data) of one [hash][block] frame, NOT hashed — for
     callers that batch-verify many frames in one `verify_frames`
-    dispatch (heal). `want` is b"" for whole-file algorithms, whose
-    shard files carry no inline hashes."""
+    dispatch (heal). `data` is a memoryview into `buf` (no copy: a
+    survivor frame reaches the device operand, or the decode, straight
+    from the bytes the drive returned). `want` is b"" for whole-file
+    algorithms, whose shard files carry no inline hashes."""
+    view = memoryview(buf)
     if not is_streaming(algo):
-        return b"", buf[block_idx * shard_size:
-                        block_idx * shard_size + chunk]
+        return b"", view[block_idx * shard_size:
+                         block_idx * shard_size + chunk]
     hsz = hash_size(algo)
     base = block_idx * (hsz + shard_size)
-    want = buf[base:base + hsz]
-    data = buf[base + hsz:base + hsz + chunk]
+    want = bytes(view[base:base + hsz])
+    data = view[base + hsz:base + hsz + chunk]
     if len(want) < hsz or len(data) < chunk:
         raise BitrotMismatch("truncated shard stream")
     return want, data
 
 
 def extract_block(buf: bytes, block_idx: int, chunk: int, shard_size: int,
-                  algo: str = DEFAULT_ALGORITHM) -> bytes:
+                  algo: str = DEFAULT_ALGORITHM) -> memoryview:
     """Extract + verify one [hash][block] frame from a streaming shard
     buffer whose frame 0 starts at byte 0 (a whole file or a ranged
-    window). `chunk` is the expected block payload length."""
+    window). `chunk` is the expected block payload length; the block
+    comes back as `split_block`'s view into `buf`."""
     want, data = split_block(buf, block_idx, chunk, shard_size, algo)
     if want and digest(algo, data) != want:
         raise BitrotMismatch(f"content hash mismatch at block {block_idx}")

@@ -372,7 +372,7 @@ class Healer:
                     # dispatch — the read path's own entry — instead
                     # of one uncounted host hash per frame.
                     wants: list[bytes] = []
-                    datas: list[bytes] = []
+                    datas: list[memoryview] = []
                     for b in range(b0, min(b0 + group, n_blocks)):
                         blk_len = min(
                             fi.erasure.block_size,

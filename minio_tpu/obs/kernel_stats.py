@@ -68,6 +68,17 @@ class KernelStats:
                                  blocks)
 
     @staticmethod
+    def record_operand(kernel: str, copied: int, padded: int) -> None:
+        """How a device dispatch's operand was built: `copied` bytes the
+        host wrote into it from the caller's rows, `padded` bytes of
+        zero rows sent with them. For hh256 the two sum to what
+        ``kernel_bytes_total{device="tpu"}`` gains for the dispatch."""
+        lbl = {"kernel": kernel}
+        METRICS2.inc("minio_tpu_v2_kernel_host_copy_bytes_total", lbl,
+                     copied)
+        METRICS2.inc("minio_tpu_v2_kernel_pad_bytes_total", lbl, padded)
+
+    @staticmethod
     def record_coalesced(kernel: str, requests: int) -> None:
         METRICS2.inc("minio_tpu_v2_kernel_coalesced_requests_total",
                      {"kernel": kernel, "device": "tpu"}, requests)
@@ -126,17 +137,15 @@ class dispatch:
     TraceAnnotation on the calling thread, so a profiler session's host
     plane carries the program's own names on the device trace's clock.
     ``kernel_dispatch_depth`` is the number of this process's device
-    dispatches already in flight when this one entered. `t_prep` backdates
-    the start of prep to a caller's own packing (bitrot's row padding).
-    A dispatch that raises observes nothing."""
+    dispatches already in flight when this one entered. A dispatch that
+    raises observes nothing."""
 
     __slots__ = ("kernel", "tags", "depth", "_name", "_t", "_ms", "_ann")
 
-    def __init__(self, kernel: str, rows: int, nbytes: int,
-                 t_prep: float | None = None):
+    def __init__(self, kernel: str, rows: int, nbytes: int):
         self.kernel = kernel
         self.tags = {"kernel": kernel, "rows": rows, "bytes": nbytes}
-        self._t = time.perf_counter() if t_prep is None else t_prep
+        self._t = time.perf_counter()
         self._ms: dict[str, float] = {}
         self._name = ""
         self._ann = None

@@ -391,6 +391,14 @@ METRICS2.register(
     "Bytes encoded/decoded/verified by the kernels, "
     "by kernel and device.")
 METRICS2.register(
+    "minio_tpu_v2_kernel_host_copy_bytes_total", "counter",
+    "Bytes the host wrote into device dispatches' operands from the "
+    "callers' rows, by kernel (hh256: each hashed byte once).")
+METRICS2.register(
+    "minio_tpu_v2_kernel_pad_bytes_total", "counter",
+    "Bytes of zero padding rows sent with device dispatches, by kernel "
+    "(hh256: the rows up to the next power of two).")
+METRICS2.register(
     "minio_tpu_v2_kernel_batch_blocks_total", "counter",
     "Blocks carried by kernel batches (occupancy numerator).")
 METRICS2.register(

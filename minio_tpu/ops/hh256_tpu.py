@@ -61,6 +61,18 @@ ShardSize), so it is pre-packed on the host with static layout; it, the
 ten permute rounds and the modular reduction run once per dispatch in
 XLA after the kernel. Only the ragged FINAL sub-block of a stream
 differs per stream; it hashes on the host.
+
+The operand. Every device dispatch enters through `hash_rows`, which
+takes its callers' rows as they lie (shard arrays, survivor frames as
+memoryviews into the bytes a drive returned, offsets into streams) and
+`pack_rows` writes each row once into a fresh (cap, n_packets, 8)
+uint32 array; cap, the next power of two above the row count
+(`bucket_rows`), is decided there and nowhere else. The power-of-two
+ladder stays on purpose: a dispatch at the rows it has (12 for an 8+4
+PUT) builds programs the served cells never warmed, and a tree that
+did so lost 10-19% of their goodput to in-window compiles while the
+heal it sped up gained (PERF.md); the copies, not the padding rows,
+were the heal's cost. tests/test_hh_dispatch_shapes.py pins the ladder.
 """
 
 from __future__ import annotations
@@ -447,54 +459,96 @@ def _pack_remainder(tail: np.ndarray, rem: int) -> np.ndarray:
     return packet.view(np.uint32)
 
 
-def hash_chunks(chunks: np.ndarray, key: bytes = MAGIC_KEY,
-                t_prep: float | None = None) -> np.ndarray:
-    """Hash B equal-length chunks on the device.
+def bucket_rows(B: int) -> int:
+    """The batch dimension B rows are dispatched at: the next power of
+    two, so a process builds one program per (bucket, length) and every
+    bucket of four rows or more divides a 2x2 mesh. Computed here only
+    (see `pack_rows`)."""
+    return 1 << max(B - 1, 0).bit_length()
 
-    chunks: (B, L) uint8, L > 0 (any length — the remainder step is
-    in-kernel). Returns (B, 32) uint8 HighwayHash-256 digests,
-    byte-identical to ops/hh256.HighwayHash256. `t_prep`: when the
-    caller's own packing for this dispatch began (perf_counter), so the
-    dispatch's prep phase holds it too.
-    """
-    if chunks.ndim != 2:
-        raise ValueError("chunks must be (B, L)")
-    B, L = chunks.shape
+
+def _row_bytes(row) -> np.ndarray:
+    """A row as a uint8 array over the caller's memory: an ndarray (any
+    strides) as it is, any other buffer (bytes, memoryview) through
+    `np.frombuffer`. Nothing is copied."""
+    if isinstance(row, np.ndarray):
+        return row
+    return np.frombuffer(row, dtype=np.uint8)
+
+
+def pack_rows(rows, L: int) -> tuple[np.ndarray, np.ndarray]:
+    """The device operand of one HH256 dispatch, built in one place.
+
+    rows: B >= 1 rows of L bytes each: a (B, L) uint8 array or a
+    sequence of buffers (bytes, memoryview, numpy rows or views, any
+    strides). Returns (words, rem_packet): a fresh C-contiguous
+    (cap, L // 32, 8) uint32 array holding each row's full packets and
+    the (cap, 8) remainder packets, cap = `bucket_rows(B)`. Each row's
+    bytes are written once, straight from the caller's memory; the
+    cap - B padding rows are `np.zeros` pages the host never writes,
+    and their digests are the caller's to drop. Neither array is a view
+    of caller memory."""
+    B = len(rows)
+    cap = bucket_rows(B)
+    n_full, rem = divmod(L, 32)
+    words = np.zeros((cap, n_full, 8), dtype=np.uint32)
+    body = words.view(np.uint8).reshape(cap, n_full * 32)
+    tail = np.zeros((cap, rem), dtype=np.uint8)
+    for i, row in enumerate(rows):
+        a = _row_bytes(row)
+        if a.shape != (L,):
+            raise ValueError(
+                f"row {i} is {a.shape} bytes, the batch's rows ({L},)")
+        body[i] = a[:n_full * 32]
+        tail[i] = a[n_full * 32:]
+    if rem:
+        rem_packet = _pack_remainder(tail, rem)
+    else:
+        rem_packet = np.zeros((cap, 8), dtype=np.uint32)
+    return words, rem_packet
+
+
+def hash_rows(rows, key: bytes = MAGIC_KEY) -> np.ndarray:
+    """Hash B equal-length rows on the device: ONE dispatch at
+    `bucket_rows(B)` rows, its operand built by `pack_rows` inside the
+    dispatch's prep phase.
+
+    rows: what `pack_rows` takes, L > 0 (any length — the remainder
+    step is in-kernel). Returns (B, 32) uint8 HighwayHash-256 digests,
+    byte-identical to ops/hh256.HighwayHash256."""
+    B = len(rows)
+    if B == 0:
+        raise ValueError("no rows to hash")
+    L = _row_bytes(rows[0]).size
     if L == 0:
         raise ValueError("chunk length must be positive")
     from . import batching
     from ..obs.kernel_stats import HH256, KERNEL, dispatch, timed
-    with dispatch(HH256, rows=B, nbytes=chunks.nbytes,
-                  t_prep=t_prep) as ph:
+    cap = bucket_rows(B)
+    nbytes = cap * L
+    with dispatch(HH256, rows=cap, nbytes=nbytes) as ph:
+        words, rem_packet = pack_rows(rows, L)
         n_full, rem = divmod(L, 32)
-        chunks = np.ascontiguousarray(chunks)
-        words = chunks[:, :n_full * 32].copy().view(np.uint32).reshape(
-            B, n_full, 8)
-        if rem:
-            rem_packet = _pack_remainder(chunks[:, n_full * 32:], rem)
-        else:
-            rem_packet = np.zeros((B, 8), dtype=np.uint32)
         init = _init_state_np(key)
         ph.phase("enqueue")
         # Spread independent chunks across the serving mesh; the hash
         # chain is per-row, so no cross-device collectives.
         m = batching.serving_mesh()
-        sharded = m is not None and B % m.size == 0
+        sharded = m is not None and cap % m.size == 0
         if m is not None:
-            # Rows shard over every mesh device when B divides it; a
-            # batch that does not stays whole on the default device
+            # Rows shard over every mesh device when the bucket divides
+            # it; one that does not stays whole on the default device
             # (index 0). The census takes either as it is placed.
             from ..parallel.mesh import MESH_AFFINITY
             if sharded:
                 from ..parallel.mesh import rows_sharding
-                words = jax.device_put(words, rows_sharding(m, B, 3))
+                words = jax.device_put(words, rows_sharding(m, cap, 3))
                 rem_packet = jax.device_put(rem_packet,
-                                            rows_sharding(m, B, 2))
+                                            rows_sharding(m, cap, 2))
                 MESH_AFFINITY.record_dispatch(
-                    HH256, tuple(range(m.size)), chunks.nbytes,
-                    chunks.nbytes // m.size)
+                    HH256, tuple(range(m.size)), nbytes, nbytes // m.size)
             else:
-                MESH_AFFINITY.record_dispatch(HH256, (0,), chunks.nbytes)
+                MESH_AFFINITY.record_dispatch(HH256, (0,), nbytes)
         mesh = m if sharded else None
         _report_impl(mesh)
         with timed() as t:
@@ -505,6 +559,14 @@ def hash_chunks(chunks: np.ndarray, key: bytes = MAGIC_KEY,
             # own (column-major) layout, and the byte view below needs
             # the last axis contiguous. On the CPU it always was.
             out = np.ascontiguousarray(dev)
-    KERNEL.record(HH256, True, chunks.nbytes, t.s, blocks=B,
+    KERNEL.record(HH256, True, nbytes, t.s, blocks=cap,
                   backend=batching.attempt_backend())
-    return out.view(np.uint8).reshape(B, 32)
+    KERNEL.record_operand(HH256, copied=B * L, padded=(cap - B) * L)
+    return out.view(np.uint8).reshape(cap, 32)[:B]
+
+
+def hash_chunks(chunks: np.ndarray, key: bytes = MAGIC_KEY) -> np.ndarray:
+    """`hash_rows` over the rows of a (B, L) uint8 array."""
+    if chunks.ndim != 2:
+        raise ValueError("chunks must be (B, L)")
+    return hash_rows(chunks, key)

@@ -278,14 +278,28 @@ def test_hash_chunks_divisible_batch_is_a_quarter_on_each_device(mesh2x2):
 
 
 def test_hash_chunks_indivisible_batch_is_whole_on_one_device(mesh2x2):
-    rows = np.full((3, 4096), 7, np.uint8)
+    rows = np.full((2, 4096), 7, np.uint8)
     out = hh256_tpu.hash_chunks(rows)
-    assert out[2].tobytes() == hh256.hh256(rows[2].tobytes())
+    assert out[1].tobytes() == hh256.hh256(rows[1].tobytes())
     snap = MESH_AFFINITY.snapshot()["kernels"]["hh256"]
     assert snap["devices"] == {
         "0": {"dispatches": 1, "bytes": rows.nbytes}}
     assert snap["placements"] == {
         "pinned": {"dispatches": 1, "bytes": rows.nbytes}}
+
+
+def test_hash_chunks_shards_the_bucket_it_pads_to(mesh2x2):
+    """Three rows go out as a bucket of four, which divides the mesh:
+    each device holds one row, the padding row on the last."""
+    rows = np.full((3, 4096), 7, np.uint8)
+    out = hh256_tpu.hash_chunks(rows)
+    assert out.shape == (3, 32)
+    assert out[2].tobytes() == hh256.hh256(rows[2].tobytes())
+    snap = MESH_AFFINITY.snapshot()["kernels"]["hh256"]
+    assert snap["devices"] == {
+        str(i): {"dispatches": 1, "bytes": 4096} for i in range(4)}
+    assert snap["placements"] == {
+        "sharded": {"dispatches": 1, "bytes": 4 * 4096}}
 
 
 def test_replicated_axis_reads_the_redundancy_it_has(mesh2x2):
