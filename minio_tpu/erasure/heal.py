@@ -57,6 +57,22 @@ CRASH_HEAL_PRE_COMMIT = FAULTS.register_crash_point(
     "engine.heal.pre_commit")
 
 
+@contextlib.contextmanager
+def _waited(lock, root, mode: str):
+    """Hold the namespace lock context `lock`; the wait for it is a
+    `lock.wait` phase of `root` (two clock reads, as the PUT commit's),
+    a wait that timed out included."""
+    from ..obs.span import TRACER
+    t0 = time.perf_counter()
+    with contextlib.ExitStack() as held:
+        try:
+            held.enter_context(lock)
+        finally:
+            TRACER.record("lock.wait", root, t0, time.perf_counter(),
+                          mode=mode)
+        yield
+
+
 @dataclass
 class HealResult:
     bucket: str
@@ -152,11 +168,21 @@ class Healer:
         Dispatch priority: every heal entry point funnels here, so the
         whole operation runs in the BACKGROUND lane — its batched
         reconstructs yield the device/coalescing window to foreground
-        encode work (qos/scheduler.py), with aging against starvation."""
+        encode work (qos/scheduler.py), with aging against starvation.
+
+        Tracing: every heal is a trace of its own, whatever the
+        caller's context: a `heal-object` root (not mirrored on the
+        profiler's clock, so an idle gap reads the phase under it)
+        whose depth-1 phases fold into request_phase_ms{api=
+        "heal-object"} as a request's do."""
+        from ..obs.span import TRACER
         from ..qos.scheduler import background_lane
-        with background_lane():
-            with self.engine.ns_lock.read_locked(bucket, object_name,
-                                                 lock_timeout):
+        ns = self.engine.ns_lock
+        with background_lane(), \
+                TRACER.trace("heal-object", uuid.uuid4().hex,
+                             bucket=bucket, object=object_name) as root:
+            with _waited(ns.read_locked(bucket, object_name, lock_timeout),
+                         root, "read"):
                 res = self._heal_object_locked(bucket, object_name,
                                                dry_run=True)
             bad = set(res.corrupt_disks) | set(res.missing_disks)
@@ -166,8 +192,8 @@ class Healer:
             # debt (MRFQueue parks the entry).
             if dry_run or res.dangling or bad <= set(res.offline_disks):
                 return res
-            with self.engine.ns_lock.write_locked(bucket, object_name,
-                                                  lock_timeout):
+            with _waited(ns.write_locked(bucket, object_name,
+                                         lock_timeout), root, "write"):
                 return self._heal_object_locked(bucket, object_name,
                                                 dry_run=False)
 
@@ -192,12 +218,17 @@ class Healer:
 
     def _heal_object_locked(self, bucket: str, object_name: str,
                             dry_run: bool = False) -> HealResult:
+        from ..obs.span import TRACER
         from ..parallel.quorum import QuorumError
         eng = self.engine
         n_disks = len(eng.disks)
         from .engine import BucketNotFound, ObjectNotFound
+        # The heal's root (heal_object); the producer's phases name it
+        # as their parent, since they may run on the pipeline's worker.
+        root = TRACER.current()
         try:
-            fi, states = self._classify(bucket, object_name)
+            with TRACER.span("heal.classify", dry=int(dry_run)):
+                fi, states = self._classify(bucket, object_name)
         except QuorumError as exc:
             res = HealResult(bucket, object_name, total_disks=n_disks)
             # Dangling requires NOT-FOUND evidence (ref isObjectDangling:
@@ -246,19 +277,21 @@ class Healer:
         # disks still carry the bucket: healing must never resurrect a
         # bucket a racing delete_bucket(force=True) just removed (the
         # same invariant xl.py's _makedirs_for enforces on write paths).
-        if not eng.bucket_exists(bucket):
-            res.after_ok = res.before_ok
-            return res
-        for i in bad:
-            try:
-                eng.disks[i].stat_volume(bucket)
-            except serr.VolumeNotFound:
+        # (ec.meta: the bucket stat fan-out a PUT makes under that name.)
+        with TRACER.span("ec.meta", what="bucket"):
+            if not eng.bucket_exists(bucket):
+                res.after_ok = res.before_ok
+                return res
+            for i in bad:
                 try:
-                    eng.disks[i].make_volume(bucket)
+                    eng.disks[i].stat_volume(bucket)
+                except serr.VolumeNotFound:
+                    try:
+                        eng.disks[i].make_volume(bucket)
+                    except serr.StorageError:
+                        pass
                 except serr.StorageError:
                     pass
-            except serr.StorageError:
-                pass
 
         if fi.size == 0 or fi.deleted:
             res.healed_disks = self._rewrite_meta_only(fi, bad)
@@ -324,30 +357,37 @@ class Healer:
             """Yield (part_number, {shard_idx: framed bytes}) per block
             group, parts in order, groups in order — consecutive
             groups' frames concatenate into exactly the shard stream
-            the old whole-part encode produced."""
+            the old whole-part encode produced.
+
+            Its phases (ec.fetch, ec.verify, ec.decode, heal.frame)
+            name the heal's root as parent: with more than one group
+            this runs on the pipeline's worker, where no span is
+            current. Each closes before a yield."""
             for part in parts:
                 # Collect k survivor streams, tolerating read failures
                 # from disks that were "ok" at classify time but
                 # dropped since (a peer restarting mid-sweep): any k
                 # good shards decode; only fewer than k is fatal.
                 streams = {}
-                for i in read_order:
-                    if len(streams) == k:
-                        break
-                    try:
-                        data = eng.disks[i].read_all(
-                            bucket,
-                            f"{object_name}/{fi.data_dir}"
-                            f"/part.{part.number}")
-                    except serr.StorageError:
-                        continue
-                    # Repair-traffic ledger (the RS baseline the regen
-                    # path's 2x claim is measured against): a full
-                    # survivor chunk is read from media AND crosses the
-                    # wire in a distributed set.
-                    REPAIR_BYTES.add("rs", "disk", len(data))
-                    REPAIR_BYTES.add("rs", "net", len(data))
-                    streams[shard_of_disk[i]] = data
+                with TRACER.span("ec.fetch", parent=root,
+                                 part=part.number):
+                    for i in read_order:
+                        if len(streams) == k:
+                            break
+                        try:
+                            data = eng.disks[i].read_all(
+                                bucket,
+                                f"{object_name}/{fi.data_dir}"
+                                f"/part.{part.number}")
+                        except serr.StorageError:
+                            continue
+                        # Repair-traffic ledger (the RS baseline the
+                        # regen path's 2x claim is measured against): a
+                        # full survivor chunk is read from media AND
+                        # crosses the wire in a distributed set.
+                        REPAIR_BYTES.add("rs", "disk", len(data))
+                        REPAIR_BYTES.add("rs", "net", len(data))
+                        streams[shard_of_disk[i]] = data
                 if len(streams) < k:
                     raise serr.FaultyDisk(
                         f"heal {bucket}/{object_name}: only "
@@ -373,33 +413,37 @@ class Healer:
                     # of one uncounted host hash per frame.
                     wants: list[bytes] = []
                     datas: list[memoryview] = []
-                    for b in range(b0, min(b0 + group, n_blocks)):
-                        blk_len = min(
-                            fi.erasure.block_size,
-                            part.size - b * fi.erasure.block_size)
-                        chunk = ceil_frac(blk_len, k)
-                        shards: list[np.ndarray | None] = \
-                            [None] * (k + m)
-                        for j, stream in streams.items():
-                            want, data = bitrot.split_block(
-                                stream, b, chunk, shard_size, algo)
-                            if want:
-                                wants.append(want)
-                                datas.append(data)
-                            shards[j] = np.frombuffer(data,
-                                                      dtype=np.uint8)
-                        block_shards.append(shards)
-                    if datas and not all(
-                            bitrot.verify_frames(datas, wants, algo)):
-                        raise bitrot.BitrotMismatch(
-                            f"heal {bucket}/{object_name}: content "
-                            f"hash mismatch in a survivor shard "
-                            f"(blocks {b0}..)")
-                    acc = {j: bytearray() for j in missing_shards}
-                    for full in codec.decode_all_blocks_batch(
-                            block_shards):
-                        for j in missing_shards:
-                            acc[j] += full[j].tobytes()
+                    with TRACER.span("ec.verify", parent=root,
+                                     blocks=min(group, n_blocks - b0)):
+                        for b in range(b0, min(b0 + group, n_blocks)):
+                            blk_len = min(
+                                fi.erasure.block_size,
+                                part.size - b * fi.erasure.block_size)
+                            chunk = ceil_frac(blk_len, k)
+                            shards: list[np.ndarray | None] = \
+                                [None] * (k + m)
+                            for j, stream in streams.items():
+                                want, data = bitrot.split_block(
+                                    stream, b, chunk, shard_size, algo)
+                                if want:
+                                    wants.append(want)
+                                    datas.append(data)
+                                shards[j] = np.frombuffer(data,
+                                                          dtype=np.uint8)
+                            block_shards.append(shards)
+                        if datas and not all(
+                                bitrot.verify_frames(datas, wants, algo)):
+                            raise bitrot.BitrotMismatch(
+                                f"heal {bucket}/{object_name}: content "
+                                f"hash mismatch in a survivor shard "
+                                f"(blocks {b0}..)")
+                    with TRACER.span("ec.decode", parent=root,
+                                     blocks=len(block_shards)):
+                        acc = {j: bytearray() for j in missing_shards}
+                        for full in codec.decode_all_blocks_batch(
+                                block_shards):
+                            for j in missing_shards:
+                                acc[j] += full[j].tobytes()
                     # Group lengths are multiples of shard_size except
                     # the part's final group, so per-group framing
                     # concatenates byte-identically to whole-part
@@ -408,10 +452,13 @@ class Healer:
                     # dispatch over every rebuilt shard's sub-blocks,
                     # the same entry the PUT path frames through.)
                     rebuilt = list(missing_shards)
-                    yield part.number, dict(zip(
-                        rebuilt, bitrot.encode_streams(
-                            [bytes(acc[j]) for j in rebuilt],
-                            shard_size, algo)))
+                    with TRACER.span("heal.frame", parent=root,
+                                     shards=len(rebuilt)):
+                        frames = dict(zip(
+                            rebuilt, bitrot.encode_streams(
+                                [bytes(acc[j]) for j in rebuilt],
+                                shard_size, algo)))
+                    yield part.number, frames
 
         # Write regenerated shards to the bad disks group by group
         # (tmp append stream -> rename_data, same commit path as PUT;
@@ -426,13 +473,14 @@ class Healer:
         from .engine import _stage_intent_blob
         intent_blob = _stage_intent_blob(bucket, object_name,
                                          fi.version_id, fi.data_dir)
-        for i in bad:
-            try:
-                eng.disks[i].append_file(
-                    MINIO_META_BUCKET, f"{tmp_paths[i]}/{INTENT_FILE}",
-                    intent_blob)
-            except Exception:
-                pass  # best-effort; a dead disk fails its appends next
+        with TRACER.span("ec.write", what="intent"):
+            for i in bad:
+                try:
+                    eng.disks[i].append_file(
+                        MINIO_META_BUCKET,
+                        f"{tmp_paths[i]}/{INTENT_FILE}", intent_blob)
+                except Exception:
+                    pass  # best-effort; a dead disk fails its appends next
 
         def drop_disk(i: int, exc: BaseException) -> None:
             write_errs[i] = exc
@@ -477,13 +525,14 @@ class Healer:
                     # frames on the bad disks, object still serving
                     # from its k survivors.
                     FAULTS.crash_point(CRASH_HEAL_MID)
-                    _, errs = parallel_map(
-                        [lambda i=i: eng.disks[i].append_file(
-                            MINIO_META_BUCKET,
-                            f"{tmp_paths[i]}/{fi.data_dir}"
-                            f"/part.{part_number}",
-                            frames[shard_of_disk[i]])
-                         for i in live])
+                    with TRACER.span("ec.write", part=part_number):
+                        _, errs = parallel_map(
+                            [lambda i=i: eng.disks[i].append_file(
+                                MINIO_META_BUCKET,
+                                f"{tmp_paths[i]}/{fi.data_dir}"
+                                f"/part.{part_number}",
+                                frames[shard_of_disk[i]])
+                             for i in live])
                     for i, e in zip(live, errs):
                         if e is not None:
                             drop_disk(i, e)
@@ -523,8 +572,9 @@ class Healer:
         # fan-out not yet started.
         FAULTS.crash_point(CRASH_HEAL_PRE_COMMIT)
         alive_bad = [i for i in bad if i not in write_errs]
-        _, errs = parallel_map([lambda i=i: commit_one(i)
-                                for i in alive_bad])
+        with TRACER.span("ec.commit"):
+            _, errs = parallel_map([lambda i=i: commit_one(i)
+                                    for i in alive_bad])
         res.healed_disks = [i for i, e in zip(alive_bad, errs)
                             if e is None]
         res.after_ok = res.before_ok + len(res.healed_disks)

@@ -499,7 +499,9 @@ METRICS2.register(
     "Per-request time in each phase, by api and phase: the union of "
     "the request's depth-1 spans of that name (obs/span.py PHASES; "
     "any other name is phase=other), observed when the request's root "
-    "span finishes; phase=unattributed is what no depth-1 span covers.")
+    "span finishes; phase=unattributed is what no depth-1 span covers. "
+    "api=heal-object is one healed object, api=heal-list an admin "
+    "sweep's listing step.")
 METRICS2.register(
     "minio_tpu_v2_cluster_nodes", "gauge",
     "Nodes contributing to a cluster metrics scrape.")

@@ -51,11 +51,14 @@ MAX_EVENTS = 16
 # request's root, are reduced to minio_tpu_v2_request_phase_ms{api,
 # phase} when the root finishes (reduce_phases). A fixed tuple
 # keeps the label set bounded; any other depth-1 name folds into
-# "other", and what no depth-1 span covers is "unattributed".
+# "other", and what no depth-1 span covers is "unattributed". The
+# heal's own roots (erasure/heal.py heal-object, s3/admin.py heal-list)
+# reuse the GET / PUT names where they do that work; heal.* is theirs.
 PHASES = ("door.hop", "door.recv", "auth.sigv4", "qos.wait", "lock.wait",
           "ec.meta", "ec.fetch", "ec.verify", "ec.decode", "ec.join",
           "ec.encode", "ec.write", "ec.commit", "door.send",
-          "mpu.load", "mpu.list", "mpu.stage")
+          "mpu.load", "mpu.list", "mpu.stage",
+          "heal.classify", "heal.frame", "heal.bucket", "heal.list")
 _PHASE_SET = frozenset(PHASES)
 
 
@@ -297,6 +300,15 @@ class Tracer:
         root = Span(name, trace_id, tags=tags or None, tracer=self)
         root.root = weakref.ref(root)
         root._fold, root._fold_mu = {}, threading.Lock()
+        return root
+
+    def trace(self, name: str, trace_id: str, **tags):
+        """A ROOT as a context manager, for work no request carries (a
+        heal): entering makes it current, leaving finishes it; the
+        shared no-op, which enters as None, when tracing is disabled."""
+        root = self.begin(name, trace_id, **tags)
+        if root is None:
+            return _NOOP
         return root
 
     def span(self, name: str, parent: Span | None = None, **tags):

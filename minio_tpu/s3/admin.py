@@ -15,6 +15,7 @@ from __future__ import annotations
 import json
 import threading
 import time
+import uuid
 
 from .. import __version__
 
@@ -292,9 +293,18 @@ class AdminHandlers:
                 out["skipped"] = "lock timeout"
             return out
         if bucket:
-            layer.healer.heal_bucket(bucket)
-            for o in layer.list_objects(bucket, prefix=prefix,
-                                        max_keys=1_000_000):
+            # The listing step is a trace of its own (`heal-list`, the
+            # one part of a sweep outside every object's `heal-object`
+            # root); it closes before the first object is healed.
+            from ..obs.span import TRACER
+            with TRACER.trace("heal-list", uuid.uuid4().hex,
+                              bucket=bucket):
+                with TRACER.span("heal.bucket"):
+                    layer.healer.heal_bucket(bucket)
+                with TRACER.span("heal.list"):
+                    objs = layer.list_objects(bucket, prefix=prefix,
+                                              max_keys=1_000_000)
+            for o in objs:
                 yield as_dict(layer.healer.heal_object_or_queue(
                     bucket, o.name, dry_run=dry), o.name)
         else:

@@ -494,6 +494,34 @@ def test_dispatch_phases_add_up_to_the_dispatch(kernel):
         assert ph["prep"] > 0               # the .copy().view() pack
 
 
+def test_an_hh256_placement_over_the_mesh_is_prep(monkeypatch):
+    """The rows' placement over the serving mesh lies before `timed()`,
+    and so in `prep`: a slow placement moves neither `enqueue` nor
+    `kernel_dispatch_ms`, and those two still add up."""
+    import jax
+
+    from minio_tpu.ops import batching, hh256_tpu
+    rows = np.random.default_rng(8).integers(
+        0, 256, (8, 512 * 1024 + 22), dtype=np.uint8)
+    hh256_tpu.hash_chunks(rows)             # compile
+    m = batching.serving_mesh()
+    assert m is not None and hh256_tpu.bucket_rows(8) % m.size == 0
+    real = jax.device_put
+    placed = []
+
+    def slow_put(*a, **kw):
+        time.sleep(0.1)
+        placed.append(1)
+        return real(*a, **kw)
+    monkeypatch.setattr(hh256_tpu.jax, "device_put", slow_put)
+    ph, wall_ms, _ = _dispatch_deltas(
+        "hh256", lambda: hh256_tpu.hash_chunks(rows))
+    assert len(placed) == 2                 # words, remainder packets
+    assert ph["prep"] >= 200
+    assert ph["enqueue"] < 100
+    assert ph["enqueue"] + ph["wait"] == pytest.approx(wall_ms, rel=0.05)
+
+
 def test_dispatch_depth_counts_dispatches_in_flight():
     from minio_tpu.obs.kernel_stats import dispatch
     entered, release = threading.Event(), threading.Event()
