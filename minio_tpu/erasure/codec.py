@@ -204,12 +204,6 @@ class Erasure:
         (ref DecodeDataBlocks, cmd/erasure-coding.go:89)."""
         return self.decode_data_blocks_batch([shards])[0]
 
-    def decode_all_blocks(self, shards: list[np.ndarray | None],
-                          ) -> list[np.ndarray]:
-        """Reconstruct ALL missing shards (heal path; ref
-        DecodeDataAndParityBlocks, cmd/erasure-coding.go:106)."""
-        return self.decode_all_blocks_batch([shards])[0]
-
     def decode_data_blocks_batch(self, blocks: list,
                                  ) -> list[list[np.ndarray]]:
         """Mask-grouped batched data reconstruct: blocks sharing an
@@ -222,13 +216,16 @@ class Erasure:
             device_fallback=self.backend != "tpu",
             affinity=self.affinity)
 
-    def decode_all_blocks_batch(self, blocks: list,
-                                ) -> list[list[np.ndarray]]:
-        """Mask-grouped batched full reconstruct (heal): data and parity
-        rebuilt by a single combined matrix per mask group."""
-        return batching.reconstruct_blocks(
-            blocks, self.data_blocks, self.parity_blocks,
-            want_all=True, use_device=self._use_tpu_decode,
+    def rebuild_shards(self, blocks: list, wanted: tuple[int, ...],
+                       ) -> np.ndarray:
+        """Heal's reconstruct: shards `wanted` (data or parity) of every
+        block, one contiguous row each, data and parity rebuilt by a
+        single combined matrix (ref DecodeDataAndParityBlocks,
+        cmd/erasure-coding.go:106, which also solves shards it is not
+        asked for)."""
+        return batching.reconstruct_rows(
+            blocks, self.data_blocks, self.parity_blocks, tuple(wanted),
+            use_device=self._use_tpu_decode,
             device_fallback=self.backend != "tpu",
             affinity=self.affinity)
 

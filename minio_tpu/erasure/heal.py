@@ -10,8 +10,9 @@ heal_object classifies each disk for the latest quorum version —
 bad disks via the same tmp→rename_data commit as a PUT. Reconstruction is
 the best TPU batch source: all blocks of an object share one erasure
 mask, so each part's blocks coalesce into a single batched device
-dispatch via codec.decode_all_blocks_batch → ops/batching.py (SURVEY §7
-stage 5; one mask group per part, tail block forming its own group).
+dispatch via codec.rebuild_shards → ops/batching.py (SURVEY §7
+stage 5; one mask group per part, tail block forming its own group),
+which solves only the shards the heal writes.
 """
 
 from __future__ import annotations
@@ -34,8 +35,8 @@ from ..utils import ceil_frac
 from . import bitrot
 from .codec import codec_for_algorithm
 
-# Cap on stacked survivor bytes per coalesced heal dispatch: large
-# enough to saturate the device, small enough to bound heal memory.
+# Cap on survivor bytes per coalesced heal dispatch: large enough to
+# saturate the device, small enough to bound heal memory.
 HEAL_BATCH_BYTES = 64 * 1024 * 1024
 
 
@@ -401,7 +402,7 @@ class Healer:
                     continue
                 # All blocks share one erasure mask -> coalesced device
                 # dispatches (ops/batching.py), bounded to
-                # HEAL_BATCH_BYTES of stacked survivors so peak memory
+                # HEAL_BATCH_BYTES of survivor blocks so peak memory
                 # stays O(batch), not O(part).
                 group = max(1, HEAL_BATCH_BYTES
                             // max(fi.erasure.block_size, 1))
@@ -437,13 +438,13 @@ class Healer:
                                 f"heal {bucket}/{object_name}: content "
                                 f"hash mismatch in a survivor shard "
                                 f"(blocks {b0}..)")
+                    # Only the shards this heal writes are solved, each
+                    # into one row of the group's bytes, from the
+                    # survivors' sub-blocks where they lie in `streams`.
                     with TRACER.span("ec.decode", parent=root,
                                      blocks=len(block_shards)):
-                        acc = {j: bytearray() for j in missing_shards}
-                        for full in codec.decode_all_blocks_batch(
-                                block_shards):
-                            for j in missing_shards:
-                                acc[j] += full[j].tobytes()
+                        rows = codec.rebuild_shards(block_shards,
+                                                    missing_shards)
                     # Group lengths are multiples of shard_size except
                     # the part's final group, so per-group framing
                     # concatenates byte-identically to whole-part
@@ -451,12 +452,11 @@ class Healer:
                     # (encode_streams: ONE device-eligible hash
                     # dispatch over every rebuilt shard's sub-blocks,
                     # the same entry the PUT path frames through.)
-                    rebuilt = list(missing_shards)
                     with TRACER.span("heal.frame", parent=root,
-                                     shards=len(rebuilt)):
+                                     shards=len(missing_shards)):
                         frames = dict(zip(
-                            rebuilt, bitrot.encode_streams(
-                                [bytes(acc[j]) for j in rebuilt],
+                            missing_shards, bitrot.encode_streams(
+                                [bytes(row) for row in rows],
                                 shard_size, algo)))
                     yield part.number, frames
 

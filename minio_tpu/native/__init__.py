@@ -99,6 +99,11 @@ def _build_and_load() -> ctypes.CDLL | None:
                                        ctypes.c_size_t, ctypes.c_void_p,
                                        ctypes.c_size_t]
         lib.rs_gf_apply_mt.restype = None
+        lib.rs_gf_apply_blocks.argtypes = [
+            ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t]
+        lib.rs_gf_apply_blocks.restype = None
         path = ctypes.c_char_p
         out = ctypes.POINTER(ctypes.c_long)
         lib.fs_append.argtypes = [path, path, path, ctypes.c_void_p,
@@ -190,6 +195,18 @@ def probe() -> bool:
                                                        cols)).all():
             _disable_native("probe: rs_gf_apply known-answer mismatch")
             return False
+        # The row-pointer entry: two blocks whose rows lie apart, into
+        # rows wider than the blocks.
+        blocks = [[cols[0, :48].copy(), cols[1, :48].copy()],
+                  [cols[1, 16:64].copy(), cols[0, 16:64].copy()]]
+        out = np.zeros((2, 100), dtype=np.uint8)
+        want = np.concatenate(
+            [gf_mat_vec_apply(mat, np.stack(b)) for b in blocks], axis=1)
+        if rs_apply_blocks_native(mat, blocks, out) is None or not (
+                out[:, :96] == want).all() or out[:, 96:].any():
+            _disable_native(
+                "probe: rs_gf_apply_blocks known-answer mismatch")
+            return False
         return True
     except Exception as exc:  # noqa: BLE001 - a probe must not raise
         _disable_native(f"probe raised: {exc!r}")
@@ -280,6 +297,47 @@ def rs_apply_native(mat, data):
     else:
         lib.rs_gf_apply(mat.ctypes.data, r, k, data.ctypes.data, n,
                         out.ctypes.data)
+    return out
+
+
+def rs_apply_blocks_native(mat, blocks, out):
+    """(r, k) GF(2^8) matrix applied to each of B blocks, reading each
+    block's k rows where they lie (native/rs.cc rs_gf_apply_blocks):
+    `blocks` holds B lists of k C-contiguous uint8 rows of one length
+    S; block b's r output rows land in ``out[:, b*S:(b+1)*S]``, where
+    `out` is an (r, >= B*S) uint8 array whose columns are contiguous
+    (a column slice of wider rows will do). Returns `out`, or None when
+    the native lib is unavailable. Byte-identical to
+    gf256.gf_mat_vec_apply per block."""
+    lib = get_lib()
+    if lib is None:
+        return None
+    import numpy as np
+    mat = np.ascontiguousarray(mat, dtype=np.uint8)
+    r, k = mat.shape
+    B = len(blocks)
+    S = len(blocks[0][0]) if B else 0
+    if out.dtype != np.uint8 or out.ndim != 2 or out.shape[0] != r \
+            or out.shape[1] < B * S or out.strides[1] != 1 \
+            or not out.flags.writeable:
+        raise ValueError(f"out {out.shape} cannot take {r} rows of "
+                         f"{B} x {S} columns")
+    ptrs = np.empty(B * k, dtype=np.uintp)
+    for b, rows in enumerate(blocks):
+        if len(rows) != k:
+            raise ValueError(f"block {b}: {len(rows)} rows, k={k}")
+        for j, row in enumerate(rows):
+            if row.dtype != np.uint8 or row.shape != (S,) \
+                    or not row.flags.c_contiguous:
+                raise ValueError(f"block {b} row {j}: not {S} "
+                                 f"contiguous bytes")
+            ptrs[b * k + j] = row.ctypes.data
+    if B and S:
+        nthreads = (min(8, os.cpu_count() or 1)
+                    if B * k * S >= RS_MT_THRESHOLD else 1)
+        lib.rs_gf_apply_blocks(mat.ctypes.data, r, k, ptrs.ctypes.data,
+                               B, S, out.ctypes.data, out.strides[0],
+                               nthreads)
     return out
 
 

@@ -15,6 +15,13 @@
 //     mat:  r*k coefficient bytes (row-major)
 //     data: k rows of n bytes (row-major, contiguous)
 //     out:  r rows of n bytes (written)
+//   rs_gf_apply_mt(mat, r, k, data, n, out, nthreads)
+//     the same, columns split over threads
+//   rs_gf_apply_blocks(mat, r, k, rows, nblocks, s, out, ostride, nthreads)
+//     rows: nblocks*k pointers, block b's k input rows of s bytes each
+//           at rows[b*k .. b*k+k), read where they lie
+//     out:  r rows of ostride bytes; block b's s columns land at
+//           out + i*ostride + b*s of row i
 
 #include <cstdint>
 #include <cstring>
@@ -111,16 +118,27 @@ void axpy_gf(uint8_t c, const uint8_t* src, uint8_t* acc, size_t n) {
 
 namespace {
 
+// The one arithmetic loop: out row i's columns [col0, col1) are
+// mat[i, :] applied to the k input rows, each read in place.
 void apply_cols(const uint8_t* mat, size_t r, size_t k,
-                const uint8_t* data, size_t n,
-                size_t col0, size_t col1, uint8_t* out) {
+                const uint8_t* const* rows, size_t col0, size_t col1,
+                uint8_t* out, size_t ostride) {
     for (size_t i = 0; i < r; i++) {
-        uint8_t* acc = out + i * n + col0;
+        uint8_t* acc = out + i * ostride + col0;
         std::memset(acc, 0, col1 - col0);
         for (size_t j = 0; j < k; j++) {
-            axpy_gf(mat[i * k + j], data + j * n + col0, acc,
-                    col1 - col0);
+            axpy_gf(mat[i * k + j], rows[j] + col0, acc, col1 - col0);
         }
+    }
+}
+
+void apply_blocks_cols(const uint8_t* mat, size_t r, size_t k,
+                       const uint8_t* const* rows, size_t nblocks,
+                       size_t s, size_t col0, size_t col1, uint8_t* out,
+                       size_t ostride) {
+    for (size_t b = 0; b < nblocks; b++) {
+        apply_cols(mat, r, k, rows + b * k, col0, col1, out + b * s,
+                   ostride);
     }
 }
 
@@ -130,30 +148,41 @@ extern "C" {
 
 // nthreads <= 1: single-threaded. Column ranges are independent (GF
 // math is per-byte-column), so threads never share output bytes.
-void rs_gf_apply_mt(const uint8_t* mat, size_t r, size_t k,
-                    const uint8_t* data, size_t n, uint8_t* out,
-                    size_t nthreads) {
-    if (nthreads <= 1 || n < 2 * nthreads) {
-        apply_cols(mat, r, k, data, n, 0, n, out);
+void rs_gf_apply_blocks(const uint8_t* mat, size_t r, size_t k,
+                        const uint8_t* const* rows, size_t nblocks,
+                        size_t s, uint8_t* out, size_t ostride,
+                        size_t nthreads) {
+    if (nthreads <= 1 || s < 2 * nthreads) {
+        apply_blocks_cols(mat, r, k, rows, nblocks, s, 0, s, out,
+                          ostride);
         return;
     }
     std::vector<std::thread> ts;
     ts.reserve(nthreads);
     // 64-byte-aligned chunk boundaries keep SIMD lanes off seams.
-    // Ceiling division: nthreads * chunk must cover ALL n columns.
-    size_t chunk = (((n + nthreads - 1) / nthreads) + 63) & ~size_t(63);
+    // Ceiling division: nthreads * chunk must cover ALL s columns.
+    size_t chunk = (((s + nthreads - 1) / nthreads) + 63) & ~size_t(63);
     for (size_t t = 0; t < nthreads; t++) {
         size_t c0 = t * chunk;
-        if (c0 >= n) break;
-        size_t c1 = c0 + chunk < n ? c0 + chunk : n;
-        ts.emplace_back(apply_cols, mat, r, k, data, n, c0, c1, out);
+        if (c0 >= s) break;
+        size_t c1 = c0 + chunk < s ? c0 + chunk : s;
+        ts.emplace_back(apply_blocks_cols, mat, r, k, rows, nblocks, s,
+                        c0, c1, out, ostride);
     }
     for (auto& th : ts) th.join();
 }
 
+void rs_gf_apply_mt(const uint8_t* mat, size_t r, size_t k,
+                    const uint8_t* data, size_t n, uint8_t* out,
+                    size_t nthreads) {
+    std::vector<const uint8_t*> rows(k);
+    for (size_t j = 0; j < k; j++) rows[j] = data + j * n;
+    rs_gf_apply_blocks(mat, r, k, rows.data(), 1, n, out, n, nthreads);
+}
+
 void rs_gf_apply(const uint8_t* mat, size_t r, size_t k,
                  const uint8_t* data, size_t n, uint8_t* out) {
-    apply_cols(mat, r, k, data, n, 0, n, out);
+    rs_gf_apply_mt(mat, r, k, data, n, out, 1);
 }
 
 }  // extern "C"

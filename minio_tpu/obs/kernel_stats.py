@@ -69,10 +69,11 @@ class KernelStats:
 
     @staticmethod
     def record_operand(kernel: str, copied: int, padded: int) -> None:
-        """How a device dispatch's operand was built: `copied` bytes the
-        host wrote into it from the caller's rows, `padded` bytes of
-        zero rows sent with them. For hh256 the two sum to what
-        ``kernel_bytes_total{device="tpu"}`` gains for the dispatch."""
+        """How a dispatch's operand was built: `copied` bytes the host
+        wrote into it from the caller's rows, `padded` bytes of zero
+        rows sent with them. For hh256 the two sum to what
+        ``kernel_bytes_total{device="tpu"}`` gains for the dispatch;
+        an rs_decode that reads its survivors in place copies 0."""
         lbl = {"kernel": kernel}
         METRICS2.inc("minio_tpu_v2_kernel_host_copy_bytes_total", lbl,
                      copied)

@@ -392,8 +392,9 @@ METRICS2.register(
     "by kernel and device.")
 METRICS2.register(
     "minio_tpu_v2_kernel_host_copy_bytes_total", "counter",
-    "Bytes the host wrote into device dispatches' operands from the "
-    "callers' rows, by kernel (hh256: each hashed byte once).")
+    "Bytes the host wrote into dispatches' operands from the callers' "
+    "rows, by kernel (hh256: each hashed byte once; rs_decode: 0 where "
+    "the native kernel reads the survivors in place).")
 METRICS2.register(
     "minio_tpu_v2_kernel_pad_bytes_total", "counter",
     "Bytes of zero padding rows sent with device dispatches, by kernel "

@@ -15,6 +15,10 @@ that (SURVEY §7 hard parts c and f):
   off on the host: the batch folds into the columns of one table-gather
   apply instead of B separate ones.
 
+- ``reconstruct_rows``: the heal's variant. It solves only the shards
+  the heal writes, each into one contiguous row, and on the native lane
+  reads the survivors where they lie instead of stacking them.
+
 - ``EncodeCoalescer``: a cross-request window that merges concurrent
   PutObject encodes into one device batch. A lone small PUT falls back
   to the host codec with only the window's latency added; under
@@ -328,6 +332,7 @@ def reconstruct_blocks(blocks: list[list[np.ndarray | None]], k: int,
     # Priority lanes (qos/scheduler.py): a heal/crawler reconstruct
     # defers its dispatch while foreground GET/PUT work is busy; aging
     # promotes it after a bounded wait so background never starves.
+    from ..obs.kernel_stats import KERNEL, RS_DECODE
     from ..qos import scheduler as qos_sched
     lane = qos_sched.current_lane()
     for (avail, missing, S), idxs in groups.items():
@@ -339,6 +344,7 @@ def reconstruct_blocks(blocks: list[list[np.ndarray | None]], k: int,
             np.asarray(blocks[bi][j], dtype=np.uint8)
             for bi in idxs for j in used]).reshape(
                 len(idxs), len(used), S)
+        KERNEL.record_operand(RS_DECODE, copied=stack.nbytes, padded=0)
         with qos_sched.GATE.dispatch(lane):
             if use_device(stack.nbytes) and \
                     _device_allowed(device_fallback):
@@ -368,6 +374,111 @@ def reconstruct_blocks(blocks: list[list[np.ndarray | None]], k: int,
             for mi, j in enumerate(missing):
                 out[bi][j] = rebuilt[bn, mi]
     return out
+
+
+def reconstruct_rows(blocks: list[list[np.ndarray | None]], k: int,
+                     m: int, wanted: tuple[int, ...], *, use_device,
+                     device_fallback: bool = True,
+                     affinity: int | None = None) -> np.ndarray:
+    """Rebuild only the shards `wanted` of many blocks (heal), straight
+    into one contiguous row each: ``(len(wanted), total columns)``, row
+    i holding shard wanted[i]'s bytes of every block in block order.
+
+    blocks: k+m shard lists as for reconstruct_blocks; a None is a lost
+    shard or a survivor that was not read, and is never solved unless
+    wanted. Consecutive blocks with the same survivors and shard length
+    form one run and one dispatch, solved from the first k survivors
+    (any_decode_matrix): the native kernel reads them where they lie;
+    the device lane, the numpy lane and a missing native library gather
+    them with one copy instead. Byte-identical to the wanted rows of
+    reconstruct_blocks(want_all=True) (tests/test_heal_decode.py).
+    """
+    n = k + m
+    runs: list[tuple[tuple[int, ...], int, list[int]]] = []
+    for bi, shards in enumerate(blocks):
+        if len(shards) != n:
+            raise ValueError(f"block {bi}: expected {n} shard slots")
+        if any(shards[j] is not None for j in wanted):
+            raise ValueError(f"block {bi}: a wanted shard is present")
+        avail = tuple(i for i, s in enumerate(shards) if s is not None)
+        if len(avail) < k:
+            raise ReconstructError(
+                f"block {bi}: only {len(avail)}/{k} shards available")
+        S = len(shards[avail[0]])
+        if runs and runs[-1][:2] == (avail, S):
+            runs[-1][2].append(bi)
+        else:
+            runs.append((avail, S, [bi]))
+    out = np.empty((len(wanted), sum(S * len(idxs) for _, S, idxs in runs)),
+                   dtype=np.uint8)
+    from ..qos import scheduler as qos_sched
+    lane = qos_sched.current_lane()
+    col = 0
+    for avail, S, idxs in runs:
+        mat, used = any_decode_matrix(k, m, avail, wanted)
+        rows = [[np.asarray(blocks[bi][j], dtype=np.uint8) for j in used]
+                for bi in idxs]
+        dst = out[:, col:col + len(idxs) * S]
+        col += len(idxs) * S
+        nbytes = len(idxs) * len(used) * S
+        with qos_sched.GATE.dispatch(lane):
+            device = use_device(nbytes) and _device_allowed(device_fallback)
+            if device:
+                try:
+                    # Kernel-dispatch fault hook, as in reconstruct_blocks.
+                    from ..faultinject import FAULTS
+                    FAULTS.kernel("rs_decode")
+                    _device_rows(rows, k, m, avail, wanted, dst, affinity)
+                except Exception as exc:
+                    if not device_fallback:
+                        raise
+                    device_dispatch_failed(exc)
+                    device = False
+            if not device:
+                _host_rows(mat, rows, dst)
+            STATS.add(device, nbytes, len(idxs))
+    return out
+
+
+def _device_rows(rows: list[list[np.ndarray]], k: int, m: int,
+                 avail: tuple[int, ...], wanted: tuple[int, ...],
+                 dst: np.ndarray, affinity: int | None) -> None:
+    """One run on the device: its survivors gathered into the
+    (B, k, S) operand the device lane takes, the wanted rows solved."""
+    from ..obs.kernel_stats import KERNEL, RS_DECODE
+    S = len(rows[0][0])
+    stack = np.stack([r for blk in rows for r in blk]).reshape(
+        len(rows), len(rows[0]), S)
+    KERNEL.record_operand(RS_DECODE, copied=stack.nbytes, padded=0)
+    rebuilt = _device_reconstruct(stack, k, m, avail, wanted, affinity)
+    for bn in range(len(rows)):
+        dst[:, bn * S:(bn + 1) * S] = rebuilt[bn]
+
+
+def _host_rows(mat: np.ndarray, rows: list[list[np.ndarray]],
+               dst: np.ndarray) -> None:
+    """One run on the host: the native kernel reads each survivor row
+    in place and writes `dst` directly; the numpy lane (the plan's
+    choice, or no native library) solves one gathered (k, B*S) copy."""
+    from ..native import rs_apply_blocks_native
+    from ..obs.kernel_stats import KERNEL, RS_DECODE, timed
+    from ..obs.kernprof import HOST, NATIVE
+    from .autotune import AUTOTUNE
+    from .autotune import RS_DECODE as _RSD
+    B, n_used, S = len(rows), len(rows[0]), len(rows[0][0])
+    nbytes = B * n_used * S
+    with timed() as t:
+        backend, copied = NATIVE, 0
+        if AUTOTUNE.host_lane(_RSD, nbytes) == HOST or \
+                rs_apply_blocks_native(mat, rows, dst) is None:
+            backend, copied = HOST, nbytes
+            cols = np.empty((n_used, B, S), dtype=np.uint8)
+            for bn, blk in enumerate(rows):
+                for u, r in enumerate(blk):
+                    cols[u, bn] = r
+            dst[:] = gf_mat_vec_apply(mat, cols.reshape(n_used, B * S))
+    KERNEL.record_operand(RS_DECODE, copied=copied, padded=0)
+    KERNEL.record(RS_DECODE, False, nbytes, t.s, blocks=B, backend=backend)
 
 
 # --- cross-request encode coalescing -----------------------------------------

@@ -294,3 +294,86 @@ def test_monitor_restamps_format_on_hot_swap(tmp_path):
     assert fmt.sets == [row]
     assert os.path.exists(os.path.join(target.root, "fb", "obj",
                                        "xl.meta"))
+
+
+def test_heal_rebuilds_wiped_drive_in_place_on_background_lane(
+        tmp_path, monkeypatch):
+    """A 64 MiB 12+4 object with drive 7 wiped: the heal writes the part
+    file the PUT wrote, and the one the parent's arithmetic (every
+    missing slot rebuilt by reconstruct_blocks(want_all=True), the
+    wiped drive's row kept) writes. Its reconstruct copies no survivor
+    byte (kernel_host_copy_bytes_total{kernel="rs_decode"} gains 0 on
+    the native lane) and runs inside GATE.dispatch on the background
+    lane; a degraded GET's stacked reconstruct counts its copy."""
+    import contextlib
+
+    import numpy as np
+
+    from minio_tpu import native
+    from minio_tpu.erasure.codec import Erasure
+    from minio_tpu.obs.metrics2 import METRICS2
+    from minio_tpu.ops import batching
+    from minio_tpu.qos import scheduler as qos_sched
+
+    e = make_engine(tmp_path, n=16, k=12, m=4, block_size=10 << 20)
+    e.make_bucket("b")
+    payload = np.random.default_rng(7).integers(
+        0, 256, 64 << 20, dtype=np.uint8).tobytes()
+    e.put_object("b", "obj", payload)
+    part = _shard_file(e.disks[7].root, "b", "obj")
+    written = open(part, "rb").read()
+    obj_dir = os.path.join(e.disks[7].root, "b", "obj")
+
+    # The parent's arithmetic, for the comparison below.
+    def parent_rebuild(self, blocks, wanted):
+        full = batching.reconstruct_blocks(
+            blocks, self.data_blocks, self.parity_blocks, want_all=True,
+            use_device=lambda n: False)
+        return np.stack([np.concatenate([b[j] for b in full])
+                         for j in wanted])
+
+    with monkeypatch.context() as mp:
+        mp.setattr(Erasure, "rebuild_shards", parent_rebuild)
+        shutil.rmtree(obj_dir)
+        assert e.healer.heal_object("b", "obj").healed_disks == [7]
+    parent_healed = open(_shard_file(e.disks[7].root, "b", "obj"),
+                         "rb").read()
+
+    lanes, inside = [], []
+    real_dispatch = qos_sched.GATE.dispatch
+
+    @contextlib.contextmanager
+    def dispatch(lane):
+        lanes.append(lane)
+        try:
+            with real_dispatch(lane):
+                yield
+        finally:
+            lanes.pop()
+    real_host_rows = batching._host_rows
+
+    def host_rows(*a, **kw):
+        inside.append(list(lanes))
+        return real_host_rows(*a, **kw)
+    monkeypatch.setattr(qos_sched.GATE, "dispatch", dispatch)
+    monkeypatch.setattr(batching, "_host_rows", host_rows)
+    copy = ("minio_tpu_v2_kernel_host_copy_bytes_total",
+            {"kernel": "rs_decode"})
+    shutil.rmtree(obj_dir)
+    c0 = METRICS2.get(*copy)
+    assert e.healer.heal_object("b", "obj").healed_disks == [7]
+    healed = open(_shard_file(e.disks[7].root, "b", "obj"), "rb").read()
+    assert healed == written == parent_healed
+    # 7 blocks of 10 MiB: a 6-block run and the tail's run.
+    assert inside == [[qos_sched.BACKGROUND]] * 2
+    if native.get_lib() is not None:
+        assert METRICS2.get(*copy) == c0
+
+    fi = e.disks[0].read_version("b", "obj")
+    lost = next(i for i, s in enumerate(fi.erasure.distribution)
+                if s - 1 < 12 and i != 7)
+    shutil.rmtree(os.path.join(e.disks[lost].root, "b", "obj"))
+    c1 = METRICS2.get(*copy)
+    got, _ = e.get_object("b", "obj")
+    assert got == payload
+    assert METRICS2.get(*copy) > c1

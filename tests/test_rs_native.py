@@ -96,3 +96,83 @@ def test_codec_single_block_host_path():
     got = codec.encode_data(payload)
     want = rs_cpu.encode_data(payload, 4, 2)
     assert np.array_equal(got, np.asarray(want))
+
+
+def _scattered_blocks(rng, k, B, S):
+    """B blocks of k rows of S bytes, each row its own allocation (as a
+    heal's survivor sub-blocks lie in separate streams)."""
+    return [[rng.integers(0, 256, S, dtype=np.uint8) for _ in range(k)]
+            for _ in range(B)]
+
+
+@pytest.mark.parametrize("S", [1, 15, 33, 63, 64, 65, 97, 1000, 4099])
+@pytest.mark.parametrize("k,r", [(4, 1), (8, 3), (12, 4)])
+def test_native_blocks_matches_golden(lib, k, r, S):
+    """rs_gf_apply_blocks reads each row through its pointer and writes
+    block b at columns b*S of rows wider than B*S; odd lengths and
+    lengths under one 64-byte SIMD step."""
+    rng = np.random.default_rng(S * 31 + k)
+    mat = rng.integers(0, 256, (r, k), dtype=np.uint8)
+    B = 3
+    blocks = _scattered_blocks(rng, k, B, S)
+    out = np.full((r, B * S + 7), 0xAB, dtype=np.uint8)
+    assert native.rs_apply_blocks_native(mat, blocks, out) is out
+    for b, rows in enumerate(blocks):
+        np.testing.assert_array_equal(
+            out[:, b * S:(b + 1) * S],
+            gf_mat_vec_apply(mat, np.stack(rows)))
+    assert (out[:, B * S:] == 0xAB).all()  # nothing past the blocks
+
+
+@pytest.mark.parametrize("nthreads", [2, 3, 4, 8])
+@pytest.mark.parametrize("S", [2 * 64 * 8 - 1, 8 * 131072 + 3, 200_001])
+def test_native_blocks_thread_seams(lib, S, nthreads):
+    """Columns split over threads at 64-byte seams, the same split for
+    every block: byte-identical to the single-threaded call, into a
+    column slice of wider rows (the ostride the heal's later runs
+    write through)."""
+    rng = np.random.default_rng(nthreads)
+    k, r, B = 12, 2, 2
+    mat = rng.integers(0, 256, (r, k), dtype=np.uint8)
+    blocks = _scattered_blocks(rng, k, B, S)
+    ptrs = np.array([row.ctypes.data for blk in blocks for row in blk],
+                    dtype=np.uintp)
+    wide = np.zeros((r, B * S + 100), dtype=np.uint8)
+    dst = wide[:, 50:50 + B * S]
+    lib.rs_gf_apply_blocks(mat.ctypes.data, r, k, ptrs.ctypes.data, B, S,
+                           dst.ctypes.data, dst.strides[0], nthreads)
+    for b, rows in enumerate(blocks):
+        np.testing.assert_array_equal(
+            dst[:, b * S:(b + 1) * S],
+            gf_mat_vec_apply(mat, np.stack(rows)))
+    assert not wide[:, :50].any() and not wide[:, 50 + B * S:].any()
+
+
+def test_native_blocks_rejects_what_it_cannot_read(lib):
+    rng = np.random.default_rng(0)
+    mat = np.ones((1, 2), dtype=np.uint8)
+    good = _scattered_blocks(rng, 2, 1, 64)
+    with pytest.raises(ValueError):  # a strided row
+        native.rs_apply_blocks_native(
+            mat, [[good[0][0], np.zeros(128, np.uint8)[::2]]],
+            np.empty((1, 64), np.uint8))
+    with pytest.raises(ValueError):  # rows too narrow for the blocks
+        native.rs_apply_blocks_native(mat, good, np.empty((1, 63),
+                                                          np.uint8))
+
+
+def test_probe_checks_the_row_pointer_entry(lib, monkeypatch):
+    """native.probe() passes on a sound library and fails, naming the
+    row-pointer entry, when that entry answers wrong."""
+    assert native.probe()
+    real = native.rs_apply_blocks_native
+
+    def wrong(mat, blocks, out):
+        real(mat, blocks, out)
+        out[0, 0] ^= 1
+        return out
+    reasons = []
+    monkeypatch.setattr(native, "rs_apply_blocks_native", wrong)
+    monkeypatch.setattr(native, "_disable_native", reasons.append)
+    assert not native.probe()
+    assert reasons == ["probe: rs_gf_apply_blocks known-answer mismatch"]
