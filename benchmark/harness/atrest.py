@@ -16,7 +16,12 @@ that should not be (counted as missing, as a hole in its own set is).
 On a drive the traffic mix's fault took out (`lost`, positions in
 `drives`) a copy is expected absent: one found there is
 `lost_copies_present`, and the drive is left out of
-`shard_files_missing`."""
+`shard_files_missing`.
+
+A key whose last acknowledged write was a DELETE has to be gone from
+every drive: `deleted_present` counts those of which some drive still
+holds an xl.meta or a data directory (the S3 contract on an unversioned
+bucket; the reference's DeleteObject, cmd/erasure-object.go)."""
 
 from __future__ import annotations
 
@@ -51,6 +56,37 @@ def sample(objects: list[tuple[str, int, int]], n: int, seed: int,
     biggest = max(objects, key=lambda o: (o[1], o[0]))
     rest = [o for o in objects if o != biggest]
     return [biggest] + rng.sample(rest, n - 1)
+
+
+DELETED_SAMPLE = 48  # deleted keys checked gone in a run
+
+
+def sample_keys(keys, seed: int) -> list[str]:
+    """DELETED_SAMPLE of the keys, drawn from the seed; all of them if
+    there are fewer."""
+    keys = sorted(keys)
+    if len(keys) <= DELETED_SAMPLE:
+        return keys
+    return sorted(random.Random(sub_seed(seed, "deleted")).sample(
+        keys, DELETED_SAMPLE))
+
+
+def deleted_present(drives: list[str], bucket: str, keys: list[str]) -> int:
+    """How many of the keys some drive still holds an xl.meta or a data
+    directory of (each was acknowledged as deleted)."""
+    present = 0
+    for key in keys:
+        for d in drives:
+            base = os.path.join(d, bucket, key)
+            try:
+                names = os.listdir(base)
+            except OSError:
+                continue
+            if any(n == "xl.meta" or os.path.isdir(os.path.join(base, n))
+                   for n in names):
+                present += 1
+                break
+    return present
 
 
 def _placed(drive: str, bucket: str, key: str) -> tuple[int, str] | None:

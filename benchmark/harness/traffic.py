@@ -16,11 +16,15 @@ File (all sizes in bytes):
                   # > 0: this group offers that many operations a second
                   # whatever the loop (due times from the window's opening)
         "range_bytes": 65536, "part_size": 5242880}],
-     "preload": {"per_client": 0},       # operations of its stream a client
+     "preload": {"per_client": 0,        # operations of its stream a client
                                          # runs before the warm-up; a group
                                          # that never writes fills that many
                                          # ring slots with them
-     "verify": {"at_rest_sample": 6},
+                 "fill": 0},             # every client of every group PUTs
+                                         # ring slots 0..fill-1 first, sizes
+                                         # from its stream; its window PUTs
+                                         # then go on from slot `fill`
+     "verify": {"at_rest_sample": 6},    # window PUTs compared at rest
      "trace_slice_s": 0.5,               # the traced slice, the window's
                                          # last (harness/trace_reduce.py
                                          # says what a second of it costs)
@@ -98,6 +102,9 @@ class Traffic:
     at_rest_sample: int = 6
     trace_slice_s: float = 0.5
     faults: dict = field(default_factory=dict)
+    # Out of the repr, which the accepted mixes' parse is pinned by
+    # (tests/test_traffic.py): a mix that leaves it out is unchanged.
+    preload_fill: int = field(default=0, repr=False)
 
     @property
     def max_size(self) -> int:
@@ -175,13 +182,17 @@ def parse(name: str, doc: dict, rehearse: bool = False) -> Traffic:
               "faults.heal is {'poll_s': s, 'trace_start_s': s}")
         _need(isinstance(faults.get("wipe_drive"), int),
               "faults.heal heals the ONE drive faults.wipe_drive names")
+    preload, verify = doc.get("preload", {}), doc.get("verify", {})
+    fill = int(preload.get("fill", 0))
+    _need(all(0 <= fill <= g.ring for g in groups),
+          f"preload.fill {fill} is 0 ... every group's ring")
     return Traffic(
         name=name, loop=loop, timeout_s=float(doc.get("timeout_s", 120)),
         groups=groups,
-        preload_per_client=int(doc.get("preload", {}).get("per_client", 0)),
-        at_rest_sample=int(doc.get("verify", {}).get("at_rest_sample", 6)),
+        preload_per_client=int(preload.get("per_client", 0)),
+        at_rest_sample=int(verify.get("at_rest_sample", 6)),
         trace_slice_s=float(doc.get("trace_slice_s", 0.5)),
-        faults=faults)
+        faults=faults, preload_fill=fill)
 
 
 def load(path: str, name: str, rehearse: bool = False) -> Traffic:
@@ -259,6 +270,27 @@ class ClientStream:
             self._sizes = list(self.group.sizes)
             self.rng.shuffle(self._sizes)
         return self._sizes.pop()
+
+    def fill_ops(self, n: int) -> list[Op]:
+        """The PUTs of ring slots 0..n-1, in order, sizes and bodies
+        drawn as a window PUT draws them; the slot counter is left at n,
+        so that the window's PUTs write new names."""
+        ops = [Op("PUT", self.key(slot), self.next_size(),
+                  self.rng.randrange(OFFSET_SPAN)) for slot in range(n)]
+        self._slot = n
+        return ops
+
+    def warm_ops(self, size: int) -> list[Op]:
+        """A weighted group's warm-up: one operation of each kind it
+        sends, all on the next ring slot, written first at `size` bytes,
+        a DELETE last; so every size is written and read once whatever
+        the weights would have drawn."""
+        key = self.key(self._slot % self.group.ring)
+        self._slot += 1
+        kinds = sorted(self.group.kinds,
+                       key=lambda k: (k not in WRITES, k == "DELETE"))
+        return [Op(k, key, size, self.rng.randrange(OFFSET_SPAN)
+                   if k in WRITES else 0) for k in kinds]
 
     def _read_key(self) -> str | None:
         g = self.group

@@ -107,6 +107,8 @@ def summarize(log: list[Rec], timeout_s: float) -> dict:
             "p50_ms": percentile(sorted(v["lat"]), 50),
             "max_ms": max(v["lat"], default=None)}
         for k, v in sorted(table.items())}
+    kinds = [r.kind for r in log]
+    out["by_kind"] = {k: kinds.count(k) for k in sorted(set(kinds))}
     return out
 
 
@@ -115,12 +117,14 @@ def summarize(log: list[Rec], timeout_s: float) -> dict:
 
 class Expect:
     """What each key has to hold: (size, off) of its last acknowledged
-    write, None while a failed write leaves it unknown."""
+    write, None while a failed write leaves it unknown; `deleted`, the
+    keys whose last acknowledged write was a DELETE (nothing)."""
 
     def __init__(self, base: bytes):
         self.base = memoryview(base)
         self.last: dict[str, tuple[int, int] | None] = {}
         self.in_window: set[str] = set()
+        self.deleted: set[str] = set()
 
     def body(self, size: int, off: int) -> memoryview:
         return self.base[off:off + size]
@@ -177,6 +181,7 @@ class Client:
             if op.kind in WRITES:
                 body = ex.body(op.size, op.off)
                 ex.last[op.key] = None
+                ex.deleted.discard(op.key)
                 if op.kind == "PUT":
                     r = self.s3.request("PUT", path, body=body)
                 else:
@@ -215,6 +220,7 @@ class Client:
                 if ok:
                     ex.last.pop(op.key, None)
                     ex.in_window.discard(op.key)
+                    ex.deleted.add(op.key)
                     self.stream.written.pop(op.key, None)
                     if self.stream.last_put == op.key:
                         self.stream.last_put = None
@@ -255,6 +261,10 @@ class Client:
                 op.size = sizes[i % len(sizes)]
             out.append(self.execute(op, time.monotonic(), False))
         return out
+
+    def run_list(self, ops: list[Op]) -> list[Rec]:
+        """These operations, in order, outside any window."""
+        return [self.execute(op, time.monotonic(), False) for op in ops]
 
 
 def make_clients(streams: list[ClientStream], traffic: Traffic, host: str,

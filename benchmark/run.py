@@ -10,7 +10,10 @@ bucket, preloads and warms up (set-up), opens the window for --seconds,
 lets the operations in flight finish, reads the child's counters, stops
 the child (rc 0), compares what the window's PUTs (the preload's, where
 the window writes nothing) left on the drives with the plain reference,
-and prints one JSON object as its last line. A mix whose faults ask
+and prints one JSON object as its last line. A seeded sample of the keys
+whose last acknowledged write was a DELETE is asked for once more by a
+HEAD after the drain, and looked for on the drives once the child has
+stopped: each has to be gone. A mix whose faults ask
 for a timed heal (harness/heal.py) has another window: one drive wiped,
 ONE admin heal of the bucket from its request to `done`, no client
 operation meanwhile; `--seconds` does not bound it, the mix's
@@ -32,6 +35,7 @@ import time
 _T_PROCESS = time.monotonic()
 
 import argparse  # noqa: E402
+import http.client  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -155,6 +159,18 @@ def apply_faults(faults: dict, srv: Server) -> None:
         heal_mod.wipe(srv.drive(int(faults["wipe_drive"])), BUCKET)
 
 
+def deleted_readable(s3, keys: list[str]) -> int:
+    """How many of the deleted keys a HEAD answers otherwise than 404."""
+    readable = 0
+    for key in keys:
+        try:
+            readable += s3.request("HEAD", s3.key_path(BUCKET, key)).status \
+                != 404
+        except (OSError, http.client.HTTPException):  # no answer: not a 404
+            readable += 1
+    return readable
+
+
 def degraded_reads(log, drives: list[str], k: int,
                    lost: frozenset[int]) -> int:
     """The window's successful GETs of objects that lost a data shard
@@ -255,6 +271,8 @@ def run(args, child: str = CHILD) -> int:
     heal = mix.faults.get("heal")
     wiped = int(mix.faults["wipe_drive"]) if heal else 0
     healing: dict = {}
+    gone: list[str] = []
+    readable = 0
     fault = ""
     try:
         # -- set-up ------------------------------------------------------
@@ -283,6 +301,14 @@ def run(args, child: str = CHILD) -> int:
         clients = window.make_clients(
             traffic_mod.streams(args.seed, mix), mix, "127.0.0.1", srv.port,
             srv.access, srv.secret, BUCKET, expect)
+        if mix.preload_fill:
+            pre = window.in_threads(clients, lambda c: c.run_list(
+                c.stream.fill_ops(mix.preload_fill)))
+            bad = [r for rs in pre for r in rs if not r.ok]
+            if bad:
+                raise BootFailure(f"preload fill failed: {bad[0]}")
+            say(f"preload: {mix.preload_fill} objects a client, "
+                f"{sum(r.nbytes for rs in pre for r in rs) / GiB:.3f} GiB")
         if mix.preload_per_client:
             pre = window.in_threads(
                 clients, lambda c: c.run_ops(mix.preload_per_client))
@@ -291,9 +317,10 @@ def run(args, child: str = CHILD) -> int:
                 raise BootFailure(f"preload failed: {bad[0]}")
         apply_faults(mix.faults, srv)
         # Warm-up: every client at once (the window's concurrency, so the
-        # coalescer's batch shapes too) runs its sequence once; client i
-        # takes the i-th size of its group, so every size and kind the
-        # window will send is sent here first.
+        # coalescer's batch shapes too) runs its sequence once (a weighted
+        # group: one operation of each kind on a key it writes first);
+        # client i takes the i-th size of its group, so every size and
+        # kind the window will send is sent here first.
         t0 = time.monotonic()
         distinct = {g.name: sorted(set(g.sizes)) for g in mix.groups}
         passes = max(-(-len(distinct[g.name]) // g.clients)
@@ -301,9 +328,11 @@ def run(args, child: str = CHILD) -> int:
 
         def warm_one(c, p):
             g = c.stream.group
-            sizes = distinct[g.name]
-            return c.run_ops(len(g.kinds),
-                             sizes=[sizes[(c.stream.client + p) % len(sizes)]])
+            size = distinct[g.name][(c.stream.client + p)
+                                    % len(distinct[g.name])]
+            if g.weights:
+                return c.run_list(c.stream.warm_ops(size))
+            return c.run_ops(len(g.kinds), sizes=[size])
 
         for p in range(passes):
             warm = window.in_threads(clients, lambda c: warm_one(c, p))
@@ -355,6 +384,8 @@ def run(args, child: str = CHILD) -> int:
         summary["setup_s"] = t_open - _T_PROCESS
         for c in clients:
             c.s3.close()
+        gone = atrest.sample_keys(expect.deleted, args.seed)
+        readable = deleted_readable(admin, gone)
         mem = srv.ask("mem", 30.0)
         plan_after = srv.admin(admin, "codec-plan")["plan"]
         if args.trace:
@@ -421,6 +452,10 @@ def run(args, child: str = CHILD) -> int:
                                                lost)
     at_rest["reads_decoded"] = int(prom.delta(
         ctx["before"], ctx["settled"], PHASE_COUNT, DECODED))
+    at_rest.update(deleted_keys_checked=len(gone),
+                   deleted_keys_readable=readable,
+                   deleted_keys_present=atrest.deleted_present(
+                       drives, BUCKET, gone))
     at_rest["seconds"] = time.monotonic() - t0
     at_rest["bytes"] = sum(o[1] for o in chosen)
     say(f"at rest: {json.dumps(at_rest)}")
@@ -496,6 +531,8 @@ def run(args, child: str = CHILD) -> int:
         "at_rest_objects_unchecked": [
             max(0, wanted - at_rest["objects_checked"])
             + (0 if wanted else 1), 0],
+        "deleted_keys_readable": [at_rest["deleted_keys_readable"], 0],
+        "deleted_keys_present": [at_rest["deleted_keys_present"], 0],
         "server_exit_code": [rc if rc is not None else -1, 0],
     }
     if heal:
@@ -527,13 +564,14 @@ def run(args, child: str = CHILD) -> int:
         summary=summary, at_rest=at_rest, result=result, healing=healing,
         notes=ctx.get("notes"), run=ctx["run"],
         failures=[vars(r) for r in log if not r.ok][:50],
-        counters={"before": prom.series(ctx["before"], "minio_tpu_v2_kernel")
-                  | prom.series(ctx["before"], "minio_tpu_v2_jit"),
-                  "after": prom.series(ctx["after"], "minio_tpu_v2_kernel")
-                  | prom.series(ctx["after"], "minio_tpu_v2_jit")},
+        counters={when: prom.series(ctx[when], "minio_tpu_v2_kernel")
+                  | prom.series(ctx[when], "minio_tpu_v2_jit")
+                  | prom.series(ctx[when], "minio_tpu_v2_hedged")
+                  for when in ("before", "after")},
         boot_lines=srv.boot_lines)
     if not heal:
         say(f"per-size latency: {json.dumps(summary['by_size'])}")
+        say(f"operations by kind: {json.dumps(summary['by_kind'])}")
         say(f"p95 of each group alone: {json.dumps(summary['by_group'])}")
     say(f"completions [t, ops, bytes] per 5 s: "
         f"{json.dumps(summary['per_5s'])}")

@@ -17,6 +17,9 @@ it and has to come out `correct: false`.
     heal_raced  the new-disk monitor's tick falls between the wipe and
                 the admin sweep's first step: its own sweep of the drive
                 runs, here to its end, before the admin's goes on
+    delete_noop    a DELETE answers 204 and removes nothing
+    delete_orphan  a DELETE removes each drive's xl.meta and leaves the
+                   object's data directory behind
 """
 
 from __future__ import annotations
@@ -123,6 +126,25 @@ def plant(fault: str) -> None:
                 self.engine.new_disk_monitor.tick()
             return real_bucket(self, bucket)
         Healer.heal_bucket = heal_bucket
+    elif fault == "delete_noop":
+        from minio_tpu.erasure.engine import ObjectInfo
+        real_delete = ErasureObjects.delete_object
+
+        def delete_object(self, bucket, object_name, *a, **kw):
+            if bucket == "bench":
+                return ObjectInfo(bucket=bucket, name=object_name)
+            return real_delete(self, bucket, object_name, *a, **kw)
+        ErasureObjects.delete_object = delete_object
+    elif fault == "delete_orphan":
+        real_version = XLStorage.delete_version
+
+        def delete_version(self, volume, path, fi):
+            if volume == "bench":
+                os.remove(os.path.join(self._file_path(volume, path),
+                                       "xl.meta"))
+                return None
+            return real_version(self, volume, path, fi)
+        XLStorage.delete_version = delete_version
     else:
         raise SystemExit(f"unknown BENCH_FAULT {fault!r}")
 

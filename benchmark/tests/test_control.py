@@ -204,6 +204,11 @@ if __name__ == "__main__":
              "ec8p4_get_2lost": dict(
                  k=8, m=4, block=10 << 20, sizes=[26214400] * 6,
                  lost=(2, 5)),
+             # The 15 sizes of warp's mix, one object each (a run
+             # samples 12 of its window's PUTs, the largest among them).
+             "ec8p4_warp_mixed": dict(
+                 k=8, m=4, block=10 << 20,
+                 sizes=[1 << i for i in range(10, 24)] + [10 << 20]),
              # The whole tree made by the broken reference; a REBUILD
              # alone broken is tests/test_heal_cell.py's control.
              "ec12p4_heal": dict(

@@ -21,6 +21,9 @@ import test_control  # noqa: E402
 from harness import atrest, server, traffic  # noqa: E402
 
 NEW_CHECKS = ("lost_copies_present", "degraded_reads_not_decoded")
+# Every cell prints them since the DELETE check; a mix that deletes
+# nothing reads 0 there (nothing is checked).
+DELETE_CHECKS = ("deleted_keys_readable", "deleted_keys_present")
 
 
 def _run(capfd, monkeypatch, cell, trace=0, seconds="2"):
@@ -185,7 +188,8 @@ def test_committed_cell_reads_what_it_read(capfd, monkeypatch, cell):
     assert result["correct"] is True
     assert set(result["metrics"]) == METRICS_BEFORE[cell]
     assert sorted(result["checks"]) == sorted(CHECKS_BEFORE
-                                              + list(NEW_CHECKS))
+                                              + list(NEW_CHECKS)
+                                              + list(DELETE_CHECKS))
     assert set(_values(result).values()) == {0}
 
 

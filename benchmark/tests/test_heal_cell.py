@@ -46,7 +46,10 @@ HEAL_METRICS = {
     "codec.dispatch_wall_s_per_gib.heal",
     "storage.append_ms.heal", "storage.rename_ms.heal",
     "server.cpu_cores.heal", "compile.in_window.heal",
-    "heal.survivor_bytes_per_user_byte", "heal.object_ms"}
+    "heal.survivor_bytes_per_user_byte", "heal.object_ms",
+    "heal.classify_ms", "heal.fetch_ms", "heal.verify_ms", "heal.decode_ms",
+    "heal.frame_ms", "heal.write_commit_ms", "heal.unattributed_ms",
+    "heal.list_ms"}
 
 
 def _run(capfd, monkeypatch, trace=0, fault=None):

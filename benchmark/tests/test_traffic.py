@@ -16,7 +16,7 @@ MIXES = sorted(glob.glob(os.path.join(os.path.dirname(HERE), "traffic",
                                       "*.json")))
 
 
-def ops(seed, mix, n=60):
+def ops(seed, mix, n=60, due=False):
     out = []
     for s in traffic.streams(seed, mix):
         for _ in range(n):
@@ -25,7 +25,7 @@ def ops(seed, mix, n=60):
                 s.written[op.key] = op.size
                 s.last_put = op.key
             out.append((s.group.name, s.client, op.kind, op.key, op.size,
-                        op.off))
+                        op.off) + ((op.due_s,) if due else ()))
     return out
 
 
@@ -42,7 +42,18 @@ def test_committed_mixes(path, rehearse):
                    if o[0] == g.name and o[2] == "PUT")
         b = sorted(o[4] for o in ops(2, mix, 2 * len(g.sizes) * 4)
                    if o[0] == g.name and o[2] == "PUT")
-        assert a == b                      # same sizes, another order
+        if g.sequence:
+            assert a == b                  # same sizes, another order
+            continue
+        # Weights draw another number of PUTs from each seed: every
+        # whole cycle of a client's PUT sizes is the group's multiset.
+        for seed in (1, 2):
+            for s in traffic.streams(seed, mix):
+                if s.group is g:
+                    got = [s.next_size() for _ in range(3 * len(g.sizes))]
+                    for i in range(0, len(got), len(g.sizes)):
+                        assert sorted(got[i:i + len(g.sizes)]) == \
+                            sorted(g.sizes)
 
 
 # sha256 (first 16 hex) of repr(Traffic) of the five accepted mixes,
@@ -65,6 +76,96 @@ def test_accepted_mixes_parse_to_the_same_traffic(name):
                 .hexdigest()[:16] for r in (False, True))
     assert got == PARSED_AT_PR33[name]
     assert "heal" not in traffic.load(path, name).faults
+
+
+# sha256 (first 16 hex) of the first 200 operations of every client of
+# each accepted mix, plain and --rehearse, seed 3,000,000,019, as the
+# parent of the preload `fill` (commit ecbc0ee) drew them: a mix without
+# `fill` draws exactly the random numbers it drew before.
+STREAMS_BEFORE_FILL = {
+    "get_2lost": ("ed09c9b8827ad74f", "9e8a41b703a12304"),
+    "heal_one_drive": ("48b141e2d99136c8", "c4e8d7cceeb43c9a"),
+    "large_put_get": ("c946201d8d3f4827", "895c8935948c54d6"),
+    "large_put_get_16c": ("7abf94536047b76f", "c86ad4db6c5843e5"),
+    "large_put_get_1dead": ("c946201d8d3f4827", "895c8935948c54d6"),
+    "multipart_put_get": ("dd7ea83a771eb86c", "b00645b4cc4365e8"),
+    "small_put_get": ("f98016df6fbe3b50", "94e2e70c22bf82e9"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STREAMS_BEFORE_FILL))
+def test_a_mix_without_fill_draws_the_stream_it_drew(name):
+    import hashlib
+    path = os.path.join(os.path.dirname(HERE), "traffic", name + ".json")
+    got = []
+    for r in (False, True):
+        mix = traffic.load(path, name, r)
+        assert mix.preload_fill == 0
+        seq = ops(3_000_000_019, mix, 200, due=True)
+        got.append(hashlib.sha256(repr(seq).encode()).hexdigest()[:16])
+    assert tuple(got) == STREAMS_BEFORE_FILL[name]
+
+
+WARP = os.path.join(os.path.dirname(HERE), "traffic", "warp_mixed.json")
+
+
+def test_preload_fill_gives_n_live_keys_a_client_and_leaves_the_slot_at_n():
+    for rehearse, n in ((False, 125), (True, 8)):
+        mix = traffic.load(WARP, "warp_mixed", rehearse)
+        assert mix.preload_fill == n
+        for s in traffic.streams(5_000_000_011, mix):
+            fill = s.fill_ops(mix.preload_fill)
+            assert [o.kind for o in fill] == ["PUT"] * n
+            assert [o.key for o in fill] == [s.key(i) for i in range(n)]
+            for o in fill:                 # as if acknowledged
+                s.written[o.key] = o.size
+            assert len(s.written) == n and s._slot == n
+            # The window's PUTs write new names, from slot n on.
+            puts = []
+            while len(puts) < 3:
+                op = s.next()
+                if op.kind == "PUT":
+                    puts.append(op.key)
+                    s.written[op.key] = op.size
+            assert puts == [s.key(i) for i in range(n, n + 3)]
+    # Sizes and bodies are drawn as a window PUT draws them.
+    g = traffic.load(WARP, "warp_mixed").groups[0]
+    a, b = traffic.ClientStream(9, g, 0), traffic.ClientStream(9, g, 0)
+    fill = a.fill_ops(4)
+    assert [(o.size, o.off) for o in fill] == [
+        (b.next_size(), b.rng.randrange(traffic.OFFSET_SPAN))
+        for _ in range(4)]
+    with pytest.raises(traffic.TrafficError, match="fill"):
+        traffic.parse("m", {"groups": [{
+            "clients": 1, "sequence": ["PUT"], "sizes": {"cycle": [1]},
+            "keys": {"ring": 4}}], "preload": {"fill": 5}})
+
+
+def test_warp_mixed_is_warps_mixed_workload():
+    mix = traffic.load(WARP, "warp_mixed")
+    (g,) = mix.groups
+    assert (mix.loop, g.clients, g.ring, g.read, g.rate_per_s) == (
+        "closed", 20, 250, "ring", 0)
+    assert g.weights == {"GET": 45, "HEAD": 30, "PUT": 15, "DELETE": 10}
+    assert g.sizes == [1 << i for i in range(10, 24)] + [10 << 20]
+    assert sum(g.sizes) / len(g.sizes) == pytest.approx(1.733 * (1 << 20),
+                                                        rel=1e-3)
+    assert (mix.preload_fill, mix.at_rest_sample) == (125, 12)
+    assert mix.preload_fill * g.clients == 2500 and mix.faults == {}
+    assert mix.writes and mix.timeout_s == 60
+    # The stream's mix, over 20 clients x 500 operations.
+    kinds = [o[2] for o in ops(7, mix, 500)]
+    for k, w in g.weights.items():
+        assert 100 * kinds.count(k) / len(kinds) == pytest.approx(w, abs=1.5)
+    # Warm-up: one operation of each kind on one new key, written first
+    # at the size given, deleted last.
+    s = traffic.ClientStream(7, g, 3)
+    s.fill_ops(mix.preload_fill)
+    warm = s.warm_ops(4 << 20)
+    assert [o.kind for o in warm] == ["PUT", "GET", "HEAD", "DELETE"]
+    assert {o.key for o in warm} == {s.key(125)}
+    assert {o.size for o in warm} == {4 << 20}
+    assert s._slot == 126
 
 
 def test_large_mix_is_the_cell_the_issue_names():

@@ -83,3 +83,72 @@ def test_a_group_alone_and_completions_per_five_seconds_are_beside_it():
     assert s["by_group"]["main"] == {"put_p95_ms": None,
                                      "get_p95_ms": pytest.approx(100.0)}
     assert [b[:2] for b in s["per_5s"]] == [[0, 49], [5, 51]]
+
+
+class _Store:
+    """An S3 answer from a dict: PUT 200, DELETE 204, GET / HEAD the
+    object or 404; `fail` answers the next request 500 instead."""
+
+    def __init__(self):
+        self.objects: dict[str, bytes] = {}
+        self.fail = False
+
+    @staticmethod
+    def key_path(bucket, key):
+        return f"/{bucket}/{key}"
+
+    def request(self, method, path, body=b"", **_):
+        from harness.s3client import Response
+        if self.fail:
+            self.fail = False
+            return Response(500, {}, b"")
+        if method == "PUT":
+            self.objects[path] = bytes(body)
+            return Response(200, {}, b"")
+        if method == "DELETE":
+            self.objects.pop(path, None)
+            return Response(204, {}, b"")
+        if path not in self.objects:
+            return Response(404, {}, b"")
+        data = self.objects[path]
+        return Response(200, {"content-length": str(len(data))},
+                        b"" if method == "HEAD" else data)
+
+
+def test_expect_deleted_follows_delete_then_put():
+    from harness import traffic, window
+    g = traffic.Group("g", 1, [], {"PUT": 1.0}, [4096], 4, "ring")
+    ex = window.Expect(bytes(range(256)) * 64)
+    c = window.Client.__new__(window.Client)
+    c.stream, c.bucket, c.expect, c.s3 = traffic.ClientStream(1, g, 0), \
+        "b", ex, _Store()
+
+    def do(kind, key, size=4096, off=7):
+        return c.execute(traffic.Op(kind, key, size, off), 0.0, True)
+
+    assert do("PUT", "k1").ok and do("PUT", "k2").ok
+    assert ex.deleted == set()
+    assert do("DELETE", "k1").ok
+    assert ex.deleted == {"k1"} and "k1" not in ex.last
+    assert "k1" not in c.stream.written and "k1" not in ex.in_window
+    assert do("HEAD", "k2").ok and do("GET", "k2").ok
+    # An acknowledged PUT of the key takes it out; so does one that
+    # failed (what it holds is then unknown, not known to be nothing).
+    assert do("PUT", "k1", off=9).ok
+    assert ex.deleted == set() and ex.last["k1"] == (4096, 9)
+    assert do("DELETE", "k1").ok and do("DELETE", "k2").ok
+    assert ex.deleted == {"k1", "k2"}
+    c.s3.fail = True
+    assert not do("PUT", "k2").ok
+    assert ex.deleted == {"k1"} and ex.last["k2"] is None
+    # A DELETE that was not acknowledged records nothing.
+    c.s3.fail = True
+    assert not do("DELETE", "k3").ok
+    assert ex.deleted == {"k1"}
+
+
+def test_the_realised_mix_is_counted_by_kind():
+    log = [rec("GET", 0, 1.0), rec("HEAD", 0, 0.1), rec("GET", 1, 2),
+           rec("DELETE", 2, 2.1, ok=False, why="status:500")]
+    assert summarize(log, 30.0)["by_kind"] == {"DELETE": 1, "GET": 2,
+                                               "HEAD": 1}
